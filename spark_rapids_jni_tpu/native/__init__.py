@@ -21,17 +21,32 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "libsrjt.so")
 _lock = sanitize.tracked_lock("native.load")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+build_error: Optional[str] = None   # why the last make failed, if it did
 
 _c = ctypes
 
 
 def _build() -> bool:
+    """``make`` the library, one builder at a time: test workers and serving
+    processes start together on a fresh checkout, and concurrent makes in
+    one directory link each other's half-written objects.  The lock is an
+    flock on the Makefile itself, so nothing new appears in the tree."""
+    global build_error
+    import fcntl
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
-                       capture_output=True, timeout=300)
+        with open(os.path.join(_NATIVE_DIR, "Makefile")) as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
+                           capture_output=True, timeout=300)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError):
-        return False
+    except subprocess.CalledProcessError as e:
+        build_error = e.stderr.decode(errors="replace")[-2000:]
+    except (subprocess.SubprocessError, OSError) as e:
+        build_error = repr(e)
+    import warnings
+    warnings.warn(f"libsrjt.so build failed, native paths degrade to "
+                  f"Python: {build_error}", RuntimeWarning)
+    return False
 
 
 def _sig(lib, name, restype, argtypes):
@@ -129,7 +144,14 @@ def load() -> Optional[ctypes.CDLL]:
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError:
-            return None
+            # another process may have been mid-link: wait for its make
+            # (the lock in _build) and open what it finished
+            if not _build():
+                return None
+            try:
+                lib = ctypes.CDLL(_LIB_PATH)
+            except OSError:
+                return None
         try:
             _bind(lib)
         except AttributeError:

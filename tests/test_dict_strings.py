@@ -190,7 +190,7 @@ def test_sort_permutation_bit_identical(raw, monkeypatch):
         np.testing.assert_array_equal(pd_, pm)
     perm = SORT.order_by(td, [0], [True])
     got = F.gather(td, perm)[0].to_pylist()
-    nn = sorted(v for v in _df(raw)["s"].tolist() if v is not None)
+    nn = sorted(_df(raw)["s"].dropna().tolist())
     assert [v for v in got if v is not None] == nn
 
 
