@@ -1,8 +1,11 @@
-"""Mini TPC-H lineitem generator for the Q1 pricing-summary query.
+"""Mini TPC-H lineitem generators.
 
-Decimal measures are written as parquet DECIMAL (FLBA) so the framework's
-decimal decode path feeds the query; flags are low-cardinality strings like
-the spec's returnflag/linestatus.
+``generate`` feeds the Q1 pricing-summary query: decimal measures are
+written as parquet DECIMAL (FLBA) so the framework's decimal decode path
+feeds the query; flags are low-cardinality strings like the spec's
+returnflag/linestatus.  ``generate_q6`` is the SF-scale q6 scan input: the
+four q6 columns as PLAIN doubles/ints, built with array ops only so 6M
+rows take seconds.
 """
 
 from __future__ import annotations
@@ -48,3 +51,33 @@ def generate(n: int = 50_000, seed: int = 21) -> tuple[bytes, dict]:
            "price_c": price_c, "disc_c": disc_c, "tax_c": tax_c,
            "ship": ship}
     return buf.getvalue(), raw
+
+
+def generate_q6(n: int = 6_000_000, seed: int = 3) -> tuple[bytes, tuple]:
+    """Snappy parquet of the four TPC-H q6 columns (``models.q6.COLUMNS``)
+    in 1M-row groups, plus the raw generator arrays
+    ``(qty, price, disc, ship)`` for the NumPy reference."""
+    rng = np.random.default_rng(seed)
+    epoch94 = 8766     # days 1970 → 1994-01-01
+    qty = rng.integers(1, 51, n).astype(np.int64)
+    price = (rng.random(n) * 100000).round(2)
+    disc = rng.integers(0, 11, n).astype(np.float64) / 100.0
+    ship = rng.integers(epoch94 - 400, epoch94 + 800, n).astype(np.int32)
+    t = pa.table({
+        "l_quantity": pa.array(qty),
+        "l_extendedprice": pa.array(price),
+        "l_discount": pa.array(disc),
+        "l_shipdate": pa.array(ship, pa.int32()),
+    })
+    buf = io.BytesIO()
+    pq.write_table(t, buf, compression="SNAPPY", use_dictionary=False,
+                   row_group_size=1 << 20)
+    return buf.getvalue(), (qty, price, disc, ship)
+
+
+def q6_reference(raw: tuple, date_lo: int, date_hi: int) -> tuple[float, int]:
+    """NumPy q6 over the generator arrays: (revenue, matched rows)."""
+    qty, price, disc, ship = raw
+    mask = ((ship >= date_lo) & (ship < date_hi)
+            & (disc >= 0.05 - 1e-9) & (disc <= 0.07 + 1e-9) & (qty < 24))
+    return float(np.sum(np.where(mask, price * disc, 0.0))), int(mask.sum())

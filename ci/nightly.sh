@@ -32,10 +32,11 @@ if [[ "${1:-}" != "--no-tpu" ]]; then
     echo "== SF1 scan benchmark =="
     python tools/scan_bench.py 6000000 "$OUT/scan_bench.json" || true
 
-    echo "== SF1 query benchmark (persistent compile cache in .jax_cache) =="
-    # query_bench.py enables jax_compilation_cache_dir=.jax_cache, so this
-    # nightly's compiles seed the cache and the next process's cold run
-    # reuses every executable (VERDICT r3 next-step #3)
+    echo "== SF1 query benchmark (persistent compile cache) =="
+    # query_bench.py applies utils/compile_cache.py's rule
+    # (JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache), so
+    # this nightly's compiles seed the cache and the next process's cold
+    # run reuses every executable (VERDICT r3 next-step #3)
     python tools/query_bench.py 10000000 "$OUT/query_bench.json" || true
 fi
 

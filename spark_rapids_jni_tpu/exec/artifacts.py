@@ -19,10 +19,10 @@ equivalent, split across the two halves of our compile cost:
   stale artifact is never wrong, only slower.
 * **the XLA executable** — JAX's persistent compilation cache already
   deserializes compiled programs from disk, keyed on HLO.  The store
-  points ``jax_compilation_cache_dir`` at ``<SRJT_AOT_DIR>/xla`` (unless
-  one is already configured — ``tests/conftest.py`` shares the same
-  layout), so the re-trace of a rehydrated plan loads its executable
-  instead of compiling it.
+  turns it on under the one directory rule (``utils/compile_cache.py``:
+  ``JAX_COMPILATION_CACHE_DIR`` if set, else ``<checkout>/.jax_cache``),
+  so the re-trace of a rehydrated plan loads its executable instead of
+  compiling it.
 
 **Geometry bucketing** (``SRJT_AOT_GEOM_BUCKETS``, default on): artifact
 keys bucket every input dimension up to the next power of two, so nearby
@@ -135,8 +135,8 @@ def geometry_key(tables, buckets: Optional[bool] = None) -> Optional[str]:
 
 class ArtifactStore:
     """One on-disk artifact root: ``plans/<digest>.json`` documents, a
-    ``manifest.json`` ranked by compile cost, and the XLA executable
-    cache under ``xla/``.  Thread-safe; every disk write is atomic;
+    ``manifest.json`` ranked by compile cost.  Thread-safe; every disk
+    write is atomic;
     every read failure degrades to a miss."""
 
     def __init__(self, root: str):
@@ -336,31 +336,21 @@ _stores_mu = sanitize.tracked_lock("exec.artifacts.stores")
 _xla_wired = False
 
 
-def _init_xla_cache(root: str) -> None:
-    """Point JAX's persistent compilation cache at ``<root>/xla`` so the
-    XLA executables of rehydrated plans come from disk too.  Respects an
-    already-configured cache dir (tests/conftest.py, operator config);
-    ``SRJT_AOT_XLA_CACHE=0`` leaves the JAX config untouched entirely."""
+def _init_xla_cache() -> None:
+    """Turn JAX's persistent compilation cache on so the XLA executables
+    of rehydrated plans come from disk too.  The directory follows the
+    one rule in ``utils/compile_cache.py`` — never the store's own root,
+    which may be a temporary directory; ``SRJT_AOT_XLA_CACHE=0`` leaves
+    the JAX config untouched entirely."""
     global _xla_wired
     if _xla_wired or not knobs.get("SRJT_AOT_XLA_CACHE"):
         return
     _xla_wired = True
-    try:
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(root, "xla"))
-        # cold start is death by a thousand small compiles: cache them all
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # jax latches the persistent cache ON or OFF at the first compile
-        # of the process — any jit dispatched before this point (table
-        # loading, warm-up probes) leaves it latched OFF and the config
-        # update above silently ignored.  Drop the latched state so the
-        # next compile re-initialises against the new directory.
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:                           # pragma: no cover
-        pass                # cache wiring is advisory, never fatal
+    from ..utils import compile_cache
+    # cold start is death by a thousand small compiles: cache them all.
+    # Any jit dispatched before this point (table loading, warm-up
+    # probes) has latched the cache state, hence the re-initialisation.
+    compile_cache.reconfigure_after_first_compile(0.0)
 
 
 def get_store() -> Optional[ArtifactStore]:
@@ -375,5 +365,5 @@ def get_store() -> Optional[ArtifactStore]:
         st = _stores.get(root)
         if st is None:
             st = _stores[root] = ArtifactStore(root)
-    _init_xla_cache(root)
+    _init_xla_cache()
     return st

@@ -49,10 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:                                    # jax ≥ 0.5 top-level name
-    _shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
+_shard_map = jax.shard_map
 
 from ..ops.hashing import murmur3_32, hash_partition
 from ..rowconv.convert import (_to_rows_fixed_words, _from_rows_fixed_words)

@@ -293,6 +293,16 @@ def _u8_to_u32_flat(raw: jnp.ndarray) -> jnp.ndarray:
     return w.reshape(-1)[:k]
 
 
+def _word_pairs(words: jnp.ndarray) -> jnp.ndarray:
+    """u32 [2k] → u32 [k, 2] (lo, hi) — the 8-byte value split.
+
+    Two strided slices stacked, NOT ``reshape(-1, 2)``: the TPU compiler
+    stages that reshape through a [k, 2]-minor temporary padded 64x, and
+    with two or more of them in one program its compile time grows with
+    k (12 minutes for the three 8-byte q6 columns at 6M rows)."""
+    return jnp.stack([words[0::2], words[1::2]], axis=1)
+
+
 @functools.partial(jax.jit, static_argnums=0)
 def _device_plain_w(phys: int, words: jnp.ndarray,
                     valid: Optional[jnp.ndarray]):
@@ -302,14 +312,14 @@ def _device_plain_w(phys: int, words: jnp.ndarray,
     device decode collapses to bitcasts/reshapes (round 5 — the strided
     u8 lane extraction was the round-4 scan's cost center at ~9 GB/s)."""
     if phys == D.PT_DOUBLE:
-        typed = words.reshape(-1, 2)       # IS the f64 bit-pair storage
+        typed = _word_pairs(words)         # IS the f64 bit-pair storage
     elif phys == D.PT_FLOAT:
         typed = jax.lax.bitcast_convert_type(words, jnp.float32)
     elif phys == D.PT_INT64:
         # bitcast packs the last axis LSW-first on the little-endian
         # backends — 2x the u64 shift/or assembly on chip (33.8 vs 18.4
         # GB/s measured round 5)
-        typed = jax.lax.bitcast_convert_type(words.reshape(-1, 2),
+        typed = jax.lax.bitcast_convert_type(_word_pairs(words),
                                              jnp.int64)
     else:
         typed = jax.lax.bitcast_convert_type(words, jnp.int32)
@@ -336,12 +346,12 @@ def _device_plain(phys: int, raw: jnp.ndarray,
     FLOAT64 lands as u32 [n, 2] bit pairs (the Column invariant) — the
     decode is pure byte movement, exact on every backend."""
     if phys == D.PT_DOUBLE:
-        typed = _u8_to_u32_flat(raw).reshape(-1, 2)         # [k, 2]
+        typed = _word_pairs(_u8_to_u32_flat(raw))           # [k, 2]
     elif phys == D.PT_FLOAT:
         typed = jax.lax.bitcast_convert_type(_u8_to_u32_flat(raw),
                                              jnp.float32)
     elif phys == D.PT_INT64:
-        w = _u8_to_u32_flat(raw).reshape(-1, 2)
+        w = _word_pairs(_u8_to_u32_flat(raw))
         typed = (w[:, 0].astype(jnp.uint64)
                  | (w[:, 1].astype(jnp.uint64) << 32)).astype(jnp.int64)
     else:

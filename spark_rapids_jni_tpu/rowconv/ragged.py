@@ -63,12 +63,8 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _x64_off():
-    """``jax.enable_x64(False)`` context across jax versions (0.4.x ships
-    it as ``jax.experimental.disable_x64``)."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import disable_x64
-    return disable_x64()
+    """The kernels' 32-bit tracing context (see :func:`pack_rows`)."""
+    return jax.enable_x64(False)
 
 
 def _pow2_bucket(x: int, lo: int = 8) -> int:

@@ -83,19 +83,6 @@ def _tally(hit: bool) -> None:
         metrics.count(f"rowconv.pallas.{key}")
 
 
-def _side_effect_params(pltpu):
-    """``has_side_effects`` compiler params across jax versions (0.4.x
-    names the class ``TPUCompilerParams`` and has no side-effect field —
-    there the default params suffice: every kernel output here is consumed,
-    so the DMAs are not dead code)."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    try:
-        return cls(has_side_effects=True)
-    except TypeError:
-        return cls()
-
-
 # ---------------------------------------------------------------------------
 # pack_windows: padded rows [n, Mw] u32 + device dst offsets → flat words
 #
@@ -238,7 +225,7 @@ def _packwin_call(nblocks, MwS, NR, KOFF, interpret):
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, interpret=interpret,
         out_shape=jax.ShapeDtypeStruct((nblocks, SB, LANE), jnp.uint32),
-        compiler_params=_side_effect_params(pltpu))
+        compiler_params=pltpu.CompilerParams(has_side_effects=True))
 
 
 # ---------------------------------------------------------------------------
@@ -351,7 +338,7 @@ def _extract_call(nblocks, RB, MwS, KS, KOFF, interpret):
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, interpret=interpret,
         out_shape=jax.ShapeDtypeStruct((nblocks, RB, MwS, LANE), jnp.uint32),
-        compiler_params=_side_effect_params(pltpu))
+        compiler_params=pltpu.CompilerParams(has_side_effects=True))
 
 
 # ---------------------------------------------------------------------------
@@ -437,7 +424,7 @@ def _gather_call(nblocks, RB, MwS, interpret):
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, interpret=interpret,
         out_shape=jax.ShapeDtypeStruct((nblocks, RB, MwS, LANE), jnp.uint32),
-        compiler_params=_side_effect_params(pltpu))
+        compiler_params=pltpu.CompilerParams(has_side_effects=True))
 
 
 # ---------------------------------------------------------------------------

@@ -211,9 +211,9 @@ register("SRJT_AOT_WARMUP", "8", _int,
          "pre-hydrates in the background at startup; `0` disables the "
          "warm-up thread", "aot")
 register("SRJT_AOT_XLA_CACHE", "1", _on_unless_off,
-         "point JAX's persistent compilation cache at `<SRJT_AOT_DIR>/"
-         "xla` (skipped when a cache dir is already configured); `0` "
-         "leaves the JAX config untouched", "aot")
+         "turn JAX's persistent compilation cache on for serving (the "
+         "directory follows `utils/compile_cache.py`); `0` leaves the "
+         "JAX config untouched", "aot")
 
 # SLO watchdog (exec/slo.py)
 register("SRJT_SLO_P50_MS", None, _opt_float,

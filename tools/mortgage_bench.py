@@ -33,8 +33,9 @@ sys.path.insert(0, ".")
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", ".jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+from spark_rapids_jni_tpu.utils import compile_cache
+
+compile_cache.configure(min_compile_secs=0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 

@@ -34,24 +34,8 @@ RESULTS = {}
 
 
 def make_lineitem_sf(n: int, seed: int = 3):
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-    rng = np.random.default_rng(seed)
-    epoch94 = 8766
-    qty = rng.integers(1, 51, n).astype(np.int64)
-    price = (rng.random(n) * 100000).round(2)
-    disc = rng.integers(0, 11, n).astype(np.float64) / 100.0
-    ship = rng.integers(epoch94 - 400, epoch94 + 800, n).astype(np.int32)
-    t = pa.table({
-        "l_quantity": pa.array(qty),
-        "l_extendedprice": pa.array(price),
-        "l_discount": pa.array(disc),
-        "l_shipdate": pa.array(ship, pa.int32()),
-    })
-    buf = io.BytesIO()
-    pq.write_table(t, buf, compression="SNAPPY", use_dictionary=False,
-                   row_group_size=1 << 20)
-    return buf.getvalue(), (qty, price, disc, ship)
+    from benchmarks.tpch_data import generate_q6
+    return generate_q6(n, seed)
 
 
 def main():

@@ -39,7 +39,9 @@ sys.path.insert(0, ".")
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", ".jax_cache")
+from spark_rapids_jni_tpu.utils import compile_cache
+
+compile_cache.configure()
 
 
 def canon(table):
