@@ -7,12 +7,15 @@ result back through the same C ABI — completing the JNI→device path the
 reference gets from ``RowConversionJni.cpp:24-45`` driving CUDA directly.
 
 Every function returns a raw handle as ``int`` (0 = failure); exceptions
-never cross the C boundary.
+never cross the C boundary.  On 0 the C++ caller takes the host engine, so
+the exception is logged here first: a device path that silently stopped
+serving must be visible to whoever reads the process's stderr.
 """
 
 from __future__ import annotations
 
 import ctypes as C
+import logging
 
 import numpy as np
 
@@ -20,6 +23,9 @@ from . import types as T
 from .column import Column, Table
 from .rowconv import convert_from_rows, convert_to_rows
 from .rowconv.convert import RowBatch
+
+_log = logging.getLogger(__name__)
+
 
 def _load() -> C.CDLL:
     # single shared binding site for the whole libsrjt C ABI
@@ -101,6 +107,8 @@ def to_rows_from_handle(table_handle: int) -> int:
         result, out = int(out or 0), None    # ownership passes to caller
         return result
     except Exception:
+        _log.exception("to_rows_from_handle: device engine failed; the "
+                       "caller falls back to the host engine")
         if out is not None and lib is not None:
             lib.srjt_rows_free(out)          # don't leak a partial import
         return 0
@@ -168,6 +176,8 @@ def from_rows_from_handle(rows_handle: int, type_ids_ptr: int,
             lib.srjt_column_free(hh)
         return int(out or 0)
     except Exception:
+        _log.exception("from_rows_from_handle: device engine failed; the "
+                       "caller falls back to the host engine")
         # free any column handles created before the failure (the to-rows
         # path has the same partial-cleanup contract)
         if lib is not None:

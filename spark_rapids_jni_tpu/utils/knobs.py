@@ -444,8 +444,6 @@ register("SRJT_QB_PROFILE", "0", _is_1,
 register("SRJT_QB_SQL", "0", _is_1,
          "query_bench compiles the TPC-DS mix from `models/tpcds_sql.py` "
          "SQL text (`--sql`) instead of prebuilt plan trees", "tools")
-register("SRJT_BENCH_TRIES", "0", _int,
-         "bench.py crash-resume attempt counter", "tools")
 register("SRJT_BENCH_BUDGET_S", "1200", _float,
          "bench.py total wall-clock budget (s)", "tools")
 

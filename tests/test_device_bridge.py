@@ -116,3 +116,18 @@ def test_srjt_device_kill_switch(monkeypatch):
     assert rows
     lib.srjt_rows_free(rows)
     lib.srjt_table_free(t)
+
+
+def test_bridge_failure_is_logged_before_null(monkeypatch, caplog):
+    # a null handle sends the C++ caller to the host engine; the Python
+    # exception behind it must reach the log, not vanish
+    from spark_rapids_jni_tpu import bridge
+
+    def boom(_table):
+        raise RuntimeError("engine down")
+    monkeypatch.setattr(bridge, "convert_to_rows", boom)
+    t, _ = _mixed_table(16)
+    with caplog.at_level("ERROR"):
+        assert not lib.srjt_to_rows_device(t)
+    assert "engine down" in caplog.text
+    lib.srjt_table_free(t)

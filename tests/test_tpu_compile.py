@@ -1,0 +1,218 @@
+"""Ask the TPU's compiler, without a TPU: the main path's kernels and
+programs at real widths, compiled for a described (not attached) v5e.
+
+Interpret mode and the CPU backend cannot see what the chip's compiler
+refuses — a Mosaic slice off its tiling, a program that does not fit 16 GB,
+an op whose TPU lowering blows up.  These compiles guard every later PR at
+no chip time.  Nothing runs, so they say nothing about results or speed.
+
+The topology is described only inside the module-scoped fixture below:
+never at import, never in conftest, never autouse — one process at a time
+may load the TPU library, and only the xdist worker that is handed this
+file may try.  Everything is in this one file for the same reason.
+
+Code that asks ``jax.default_backend()`` sees the CPU here; the tests steer
+it with monkeypatch where the TPU branch is the one that must compile.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import spark_rapids_jni_tpu as sr
+from spark_rapids_jni_tpu.rowconv import convert, ragged, xpack
+from spark_rapids_jni_tpu.rowconv.layout import (
+    MAX_BATCH_BYTES, build_batches, compute_row_layout,
+    row_sizes_with_strings)
+
+HBM_BYTES = 16 << 30          # one v5e chip
+CYCLE = [sr.int8, sr.int16, sr.int32, sr.int64, sr.float32, sr.float64,
+         sr.bool8]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Take the repo's TPU branches (f64 bits arithmetic, donation)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(one_chip, jitted, *args, statics=()):
+    """Compile ``jitted`` for the described chip from shapes alone; fails
+    when the compiler refuses or the program cannot fit the chip."""
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    shaped = jax.tree_util.tree_map(sds, args)
+    compiled = jitted.lower(*statics, *shaped).compile()
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert need < HBM_BYTES, f"program needs {need >> 20} MiB of HBM"
+    return compiled
+
+
+def _s(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# --- ragged.py: the three Mosaic DMA kernels (never run in interpret mode) ---
+# geometries: tools/tpu_check.py's sweep, and the strings_mixed12 x 1M axis
+# of chip_smoke.py (M=176-byte rows, 122.7 MB of row bytes)
+
+@pytest.mark.parametrize("statics,n_pad,offs_rows", [
+    ((80, 16, 1, 128, 4, 8192), 5120, 48),               # n=4097 M=300
+    ((16384, 16, 1, 128, 4, 8192), 1048576, 10240),      # strings 1M
+])
+def test_ragged_pack_kernel(one_chip, statics, n_pad, offs_rows):
+    nblocks, _sb, mws = statics[:3]
+    with jax.enable_x64(False):
+        c = _compile(one_chip, ragged._pack_call(*statics),
+                     _s((nblocks,), jnp.int32), _s((nblocks,), jnp.int32),
+                     _s((nblocks,), jnp.int32),
+                     _s((offs_rows, 128), jnp.int32),
+                     _s((n_pad, mws, 128), jnp.uint32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("statics,flat_rows,offs_rows", [
+    ((640, 8, 1, 8, 2), 1280, 48),                       # n=4097 M=300
+    ((131072, 8, 1, 8, 2), 262144, 10240),               # strings 1M, fpv=74
+])
+def test_ragged_unpack_kernel(one_chip, statics, flat_rows, offs_rows):
+    nblocks = statics[0]
+    with jax.enable_x64(False):
+        c = _compile(one_chip, ragged._unpack_call(*statics),
+                     _s((nblocks,), jnp.int32),
+                     _s((offs_rows, 128), jnp.int32),
+                     _s((flat_rows, 128), jnp.uint32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_ragged_segmented_copy_kernel(one_chip):
+    statics = (6, 16, 8192, 128, 2)      # tools/tpu_check.py's gappy copy
+    with jax.enable_x64(False):
+        c = _compile(one_chip, ragged._segcopy_call(*statics),
+                     _s((6,), jnp.int32), _s((6,), jnp.int32),
+                     _s((6,), jnp.int32), _s((8, 128), jnp.int32),
+                     _s((8, 128), jnp.int32), _s((8, 128), jnp.int32),
+                     _s((1280, 128), jnp.uint32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+# --- fixed-width transcode at the nvbench axes --------------------------------
+
+def _fixed_axis(n_cols, n=1_000_000):
+    schema = [CYCLE[i % len(CYCLE)] for i in range(n_cols)]
+    datas = tuple(
+        _s((n, 2), jnp.uint32) if dt == sr.float64 else
+        _s((n,), jnp.uint8 if dt == sr.bool8 else dt.storage)
+        for dt in schema)
+    has_valid = tuple(i % 3 == 0 for i in range(n_cols))
+    valids = tuple(_s((n,), jnp.bool_) for hv in has_valid if hv)
+    return compute_row_layout(schema), has_valid, datas, valids, n
+
+
+@pytest.mark.parametrize("n_cols", [12, 212])
+def test_fixed_to_rows_program(one_chip, n_cols):
+    layout, has_valid, datas, valids, _ = _fixed_axis(n_cols)
+    _compile(one_chip, convert._to_rows_fixed_full, datas, valids,
+             statics=(layout, has_valid, convert._fixed_engine("to")))
+
+
+@pytest.mark.parametrize("n_cols", [12, 212])
+def test_fixed_from_rows_program(one_chip, n_cols):
+    # 212 columns is the HBM-hungriest program of the smoke (~10 GB of
+    # temporaries): the fit check in _compile is the point
+    layout, _, _, _, n = _fixed_axis(n_cols)
+    words = _s((n * layout.fixed_row_size // 4,), jnp.uint32)
+    _compile(one_chip, convert._from_rows_fixed_full, words,
+             statics=(layout, convert._fixed_engine("from")))
+
+
+# --- xpack: the strings engine, strings_mixed12 schema --------------------------
+
+def test_xpack_to_rows_program(one_chip):
+    # 64K rows, not the smoke's 1M: the program is the same shape-generic
+    # slab/roll tree (same layout, same per-column windows) and compiles
+    # in seconds instead of a minute
+    import chip_smoke
+    from spark_rapids_jni_tpu.utils import hostcache
+    n = 1 << 16
+    table = chip_smoke.build_table(n, 12, 3, 7)
+    layout = compute_row_layout(table.schema)
+    var_idx = layout.variable_column_indices
+    col_offs = [hostcache.host_i64(table[ci].offsets) for ci in var_idx]
+    lens = np.zeros(n, np.int64)
+    for o in col_offs:
+        lens += o[1:] - o[:-1]
+    batches = build_batches(row_sizes_with_strings(layout, lens),
+                            MAX_BATCH_BYTES)
+    geom = xpack._plan_geometry(layout, n,
+                                batches.row_offsets_within_batch[0],
+                                col_offs)
+    assert geom is not None, xpack.fallback_counts
+    _compile(one_chip, xpack._to_rows_x_jit,
+             tuple(c.data for c in table.columns),
+             tuple(table[ci].offsets for ci in var_idx),
+             tuple(c.validity for c in table.columns),
+             statics=(layout, geom))
+
+
+# --- scan: the q6 columns at 6M rows, and the f64 bits boundary -----------------
+
+def test_scan_decode_q6_columns(one_chip):
+    # three 8-byte PLAIN columns in ONE program: with reshape(-1, 2) in
+    # _device_plain_w this compile took 12 minutes and 3 GB of temporaries
+    from spark_rapids_jni_tpu.parquet import decode as D
+    from spark_rapids_jni_tpu.parquet import device_scan as DS
+    n = 6_000_000
+    plan = (("plain", (D.PT_INT64, sr.int64, False), 1),
+            ("plain", (D.PT_DOUBLE, sr.float64, False), 1),
+            ("plain", (D.PT_DOUBLE, sr.float64, False), 1),
+            ("plain", (D.PT_INT32, sr.int32, False), 1))
+    flat = (_s((2 * n,), jnp.uint32), _s((2 * n,), jnp.uint32),
+            _s((2 * n,), jnp.uint32), _s((n,), jnp.uint32))
+    c = _compile(one_chip, DS._decode_file_jit, flat, statics=(plan,))
+    assert c.memory_analysis().temp_size_in_bytes < (512 << 20)
+
+
+def test_f64_bits_to_values_and_q6(one_chip, as_tpu):
+    from spark_rapids_jni_tpu.models import q6
+    from spark_rapids_jni_tpu.utils import f64bits
+    n = 6_000_000
+    assert not f64bits.backend_has_f64_bitcast()
+    c = _compile(one_chip, jax.jit(f64bits.from_bits),
+                 _s((n, 2), jnp.uint32))
+    assert "bitcast-convert" not in c.as_text()    # the arithmetic path
+    _compile(one_chip, jax.jit(f64bits.to_bits), _s((n,), jnp.float64))
+    _compile(one_chip, q6.q6_kernel, _s((n,), jnp.int64),
+             _s((n,), jnp.float64), _s((n,), jnp.float64),
+             _s((n,), jnp.int32), _s((), jnp.int32), _s((), jnp.int32))

@@ -77,11 +77,14 @@ import numpy as np
 
 sys.path.insert(0, ".")
 
-import jax
+# jax is imported by the functions that run on the device, not here: the
+# ``--cold-start`` parent only starts children, and a chip belongs to one
+# process at a time — a parent holding the backend would starve them
 
 
 def canon(result):
     """A result pytree as host arrays (forces lazy columns)."""
+    import jax
     return [np.asarray(l) for l in jax.tree_util.tree_leaves(result)]
 
 
@@ -130,6 +133,7 @@ def cold_child(n_sales: int, qnames: list, out_path: str) -> None:
     ONCE through a real QueryScheduler, and report first-request wall
     times, result hashes, and the compile-ledger counters.  The parent
     decides what the numbers mean (baseline vs populate vs warm)."""
+    import jax
     from benchmarks import tpcds_data
     from spark_rapids_jni_tpu import exec as xc
     from spark_rapids_jni_tpu.models import tpcds
@@ -179,6 +183,11 @@ def cold_start_main(argv: list) -> None:
         env.pop("SRJT_AOT_DIR", None)
         if aot_dir:
             env["SRJT_AOT_DIR"] = aot_dir
+            # each store gets its own XLA cache, said through the one
+            # variable utils/compile_cache.py honours: an "empty store"
+            # trial that found yesterday's executables in the checkout's
+            # cache would not be a cold start
+            env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(aot_dir, "xla")
         fd, res = tempfile.mkstemp(suffix=".json")
         os.close(fd)
         try:
@@ -193,10 +202,6 @@ def cold_start_main(argv: list) -> None:
 
     print(f"cold-start bench: n_sales={n_sales} mix={qnames} "
           f"trials={trials}", flush=True)
-    # decode once so every child rides the memoized dataset files
-    from benchmarks import tpcds_data
-    tpcds_data.generate(n_sales=n_sales, n_items=2000, n_stores=12, seed=5)
-
     with tempfile.TemporaryDirectory(prefix="srjt_aot_") as root:
         # baseline: every trial a FRESH empty store — each process pays
         # the full capture→trace→compile tax (plus store writes, honestly
@@ -269,7 +274,10 @@ def main():
         return
     if argv and argv[0] == "--cold-start":
         cold_start_main(argv[1:])
+        assert "jax" not in sys.modules, \
+            "the cold-start parent must stay off JAX (one process per chip)"
         return
+    import jax
     n_devices = 1
     if "--devices" in argv:
         i = argv.index("--devices")

@@ -14,6 +14,8 @@ chip:
 4. the arithmetic f64 bits<->values path (``utils.f64bits``) round-trips
    normals/inf/nan exactly on the emulated-f64 backend.
 
+Exits non-zero when the backend is not a TPU or any check fails.
+
 Usage: python tools/tpu_check.py [out.json]
 """
 
@@ -32,7 +34,9 @@ import spark_rapids_jni_tpu as sr
 from spark_rapids_jni_tpu import Table, Column, convert_to_rows, convert_from_rows
 from spark_rapids_jni_tpu.rowconv import ragged, reference
 from spark_rapids_jni_tpu.rowconv.layout import compute_row_layout
-from spark_rapids_jni_tpu.utils import f64bits
+from spark_rapids_jni_tpu.utils import compile_cache, f64bits
+
+compile_cache.configure()
 
 RESULTS = {"backend": None, "checks": [], "ok": True}
 
@@ -555,41 +559,47 @@ def check_composite_pack():
            f"pairs={li.shape[0]}")
 
 
-def main():
+GROUPS = [
+    ("ragged engine", "check_ragged"),
+    ("strings transcode", "check_strings_transcode"),
+    ("strings large-n branch", "check_strings_large_n"),
+    ("xpack engines (round 5)", "check_xpack_engines"),
+    ("dict strings", "check_dict_strings"),
+    ("dict fast path (codes + predicates)", "check_dict_fast_path"),
+    ("fixed-width u32-words transcode", "check_fixed_words"),
+    ("f64 bits<->values", "check_f64bits"),
+    ("chip-killer query ops (rollup/window/string-compare)",
+     "check_query_ops"),
+    ("composite-key pack/unpack lowering", "check_composite_pack"),
+]
+
+
+def main() -> int:
     t0 = time.time()
-    RESULTS["backend"] = jax.default_backend()
-    if RESULTS["backend"] != "tpu":
+    dev = jax.devices()[0]
+    RESULTS["backend"] = dev.platform
+    RESULTS["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                         "count": len(jax.devices())}
+    if dev.platform != "tpu":
         RESULTS["ok"] = False
         RESULTS["error"] = "not running on a TPU backend"
     else:
-        print("ragged engine:", flush=True)
-        check_ragged()
-        print("strings transcode:", flush=True)
-        check_strings_transcode()
-        print("strings large-n branch:", flush=True)
-        check_strings_large_n()
-        print("xpack engines (round 5):", flush=True)
-        check_xpack_engines()
-        print("dict strings:", flush=True)
-        check_dict_strings()
-        print("dict fast path (codes + predicates):", flush=True)
-        check_dict_fast_path()
-        print("fixed-width u32-words transcode:", flush=True)
-        check_fixed_words()
-        print("f64 bits<->values:", flush=True)
-        check_f64bits()
-        print("chip-killer query ops (rollup/window/string-compare):",
-              flush=True)
-        check_query_ops()
-        print("composite-key pack/unpack lowering:", flush=True)
-        check_composite_pack()
+        for title, fn in GROUPS:
+            print(f"{title}:", flush=True)
+            try:
+                globals()[fn]()
+            except Exception as e:  # noqa: BLE001 — a group that raises is a FAIL, and the sweep goes on
+                record(f"{fn} raised", False, repr(e)[:400])
     RESULTS["seconds"] = round(time.time() - t0, 1)
     out = sys.argv[1] if len(sys.argv) > 1 else "PALLAS_TPU_CHECK.json"
     with open(out, "w") as f:
         json.dump(RESULTS, f, indent=1)
-    print(json.dumps({"ok": RESULTS["ok"], "checks": len(RESULTS["checks"]),
+    failed = [c["name"] for c in RESULTS["checks"] if not c["ok"]]
+    print(json.dumps({"ok": RESULTS["ok"], "device": RESULTS["device"],
+                      "checks": len(RESULTS["checks"]), "failed": failed,
                       "seconds": RESULTS["seconds"]}), flush=True)
+    return 0 if RESULTS["ok"] else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
