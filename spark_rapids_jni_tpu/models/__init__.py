@@ -1,8 +1,8 @@
 """Query pipelines — the framework's "model zoo".
 
 The reference framework's unit of deployment is a Spark query plan; these
-modules are end-to-end pipelines matching BASELINE.md's staged configs
-(q6 = config #2), each a jittable scan→filter→aggregate program over the
+modules are end-to-end pipelines (q6 = the TPC-H scan config, tpcds = the
+served-SQL config), each a jittable scan→filter→aggregate program over the
 columnar op library.
 """
 

@@ -4,7 +4,8 @@ This is the TPU-native answer to the reference's variable-width CUDA kernels
 (``copy_strings_to_rows`` warp-per-row ``memcpy_async``,
 ``row_conversion.cu:827-875``, and ``copy_strings_from_rows``,
 ``:1131-1174``).  Three facts about the hardware/toolchain dictate the
-design (all measured on v5e, see BASELINE.md):
+design (all measured on v5e in round 3; the record was deleted in PR 23
+and the numbers have not been re-measured since):
 
 * XLA's 1D gather scalarizes (~0.1 Gelem/s) — per-element indexing is not a
   usable primitive for byte movement;
@@ -73,8 +74,8 @@ def _pow2_bucket(x: int, lo: int = 8) -> int:
     Every data-dependent static the kernels take (block counts, window
     sublanes, metadata rows, padded segment counts) is bucketed so that
     calls with nearby geometry share one compiled kernel — each unique
-    static tuple costs a ~35 s Mosaic compile through the remote helper,
-    and e.g. a 50-string-column table would otherwise compile ~50 variants.
+    static tuple costs a full Mosaic compile, and e.g. a 50-string-column
+    table would otherwise compile ~50 variants.
     """
     v = lo
     while v < x:
@@ -108,8 +109,8 @@ def u8_to_u32(x: jnp.ndarray) -> jnp.ndarray:
     """u8 [4N] → u32 [N] (little-endian), N multiple of 128.
 
     Jitted: these helpers run between pallas_call invocations in otherwise
-    eager host orchestration, and each eager jnp op costs a full dispatch
-    round-trip on remote backends.
+    eager host orchestration, and each eager jnp op costs a dispatch of
+    its own.
     """
     x2 = x.reshape(-1, 4 * LANE)
     parts = [x2[:, k::4].astype(jnp.uint32) for k in range(4)]

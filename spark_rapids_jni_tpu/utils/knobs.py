@@ -201,8 +201,7 @@ register("SRJT_EXEC_RELOCATE_MAX", None, _opt_int,
 # AOT plan-artifact store (exec/artifacts.py)
 register("SRJT_AOT_DIR", None, _opt_str,
          "root of the persistent plan-artifact store (capture tapes + "
-         "warm-up manifest + the XLA executable cache under `<dir>/xla`); "
-         "unset disables AOT persistence", "aot")
+         "warm-up manifest); unset disables AOT persistence", "aot")
 register("SRJT_AOT_GEOM_BUCKETS", "1", _on_unless_off,
          "pow2-bucket input geometry in artifact keys so nearby dataset "
          "sizes share one artifact; `0` keys on exact shapes", "aot")
