@@ -370,6 +370,7 @@ def phase_sql(n_sales: int = SALES_ROWS, n_items: int = N_ITEMS,
         with xc.QueryScheduler(workers=4) as sched:
             for q in queries:
                 params = TS.PARAMS.get(q, {})
+                expect = getattr(pandas_queries, q)(dfs, **params)
                 c0 = {k: metrics.counter_value(k) for k in
                       ("compiled.capture", "exec.plan_cache.hit",
                        "exec.plan_cache.miss")}
@@ -381,8 +382,7 @@ def phase_sql(n_sales: int = SALES_ROWS, n_items: int = N_ITEMS,
                                            params=params).result()
                     _block(res)
                     walls.append(round(time.perf_counter() - t0, 3))
-                    rows = _compare_with_pandas(
-                        q, res, getattr(pandas_queries, q)(dfs, **params))
+                    rows = _compare_with_pandas(q, res, expect)
                 delta = {k: int(metrics.counter_value(k) - v)
                          for k, v in c0.items()}
                 out[q] = {"rows": rows, "cold_s": walls[0],
@@ -434,10 +434,18 @@ def phase_replicas(n_dev: int = 4, requests: int = 16,
     was = metrics.enabled()
     metrics.set_enabled(True)
     try:
-        names = [f"exec.device.{i}.completed" for i in range(n_dev)]
-        c0 = [metrics.counter_value(k) for k in names]
         t0 = time.perf_counter()
-        with xc.QueryScheduler(workers=2 * n_dev, devices=n_dev) as sched:
+        # max_batch=1: identical requests otherwise coalesce into ONE
+        # launch on whichever replica dequeues first, and the other
+        # replicas would rightly serve nothing
+        with xc.QueryScheduler(workers=2 * n_dev, devices=n_dev,
+                               max_batch=1) as sched:
+            # exec.device.<platform><id>.completed, one per replica
+            names = ["exec.device." + rep.name.replace(":", "")
+                     + ".completed" for rep in sched.replicas]
+            check(len(set(names)) == n_dev,
+                  f"replicas share devices: {names}")
+            c0 = [metrics.counter_value(k) for k in names]
             tickets = [sched.submit_sql(TS.SQL["q3"], tables,
                                         schemas=TS.TABLE_SCHEMAS,
                                         params=params)
@@ -449,8 +457,8 @@ def phase_replicas(n_dev: int = 4, requests: int = 16,
                   for k, v in zip(names, c0)]
     finally:
         metrics.set_enabled(was)
-    out = {"devices": n_dev, "requests": requests, "served": served,
-           "wall_s": wall}
+    out = {"devices": n_dev, "requests": requests,
+           "served": dict(zip(names, served)), "wall_s": wall}
     say("M.replicas", **out)
     check(all(s > 0 for s in served),
           f"a replica served nothing: completed per device = {served}")
@@ -478,8 +486,10 @@ def _native_stamp() -> dict:
     try:
         runpy.run_path(os.path.join(ROOT, "ci", "build_info.py"),
                        run_name="__main__")
-        from spark_rapids_jni_tpu import version_info as vi
-        stamp.update(version=vi.version, revision=vi.revision, built=vi.date)
+        vi = runpy.run_path(os.path.join(ROOT, "spark_rapids_jni_tpu",
+                                         "version_info.py"))
+        stamp.update(version=vi["version"], revision=vi["revision"],
+                     built=vi["date"])
     except (Exception, SystemExit) as e:   # the stamp is informative only
         stamp["stamp_error"] = repr(e)
     return stamp

@@ -122,3 +122,32 @@ def test_cache_rule_honours_the_environment(tmp_path, monkeypatch):
     before = jax.config.jax_compilation_cache_dir
     assert compile_cache.configure() == mine
     assert jax.config.jax_compilation_cache_dir == before
+
+
+# --- no device benchmark reports success off a TPU ------------------------------
+
+@pytest.mark.parametrize("script", ["bench.py", "tools/tpu_check.py",
+                                    "chip_smoke.py"])
+def test_device_scripts_exit_nonzero_without_a_tpu(script, tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    args = [sys.executable, os.path.join(REPO, script)]
+    if script == "tools/tpu_check.py":
+        args.append(str(tmp_path / "check.json"))
+    out = subprocess.run(args, cwd=REPO, env=env, capture_output=True,
+                         text=True)
+    assert out.returncode not in (0, None), out.stdout[-500:]
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+
+
+def test_smoke_alone_in_a_directory_fails(tmp_path):
+    # the driver also runs the script with nothing else of the repo
+    # around it: that must fail, not pass vacuously
+    import shutil
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path,
+                         env=env, capture_output=True, text=True)
+    assert out.returncode != 0
+    assert json.loads(out.stdout.strip().splitlines()[-1])["ok"] is False

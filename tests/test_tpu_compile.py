@@ -84,11 +84,10 @@ def _s(shape, dtype):
 
 
 # --- ragged.py: the three Mosaic DMA kernels (never run in interpret mode) ---
-# geometries: tools/tpu_check.py's sweep, and the strings_mixed12 x 1M axis
-# of chip_smoke.py (M=176-byte rows, 122.7 MB of row bytes)
+# geometries: the strings_mixed12 x 1M axis of chip_smoke.py (M=176-byte
+# rows, 122.7 MB of row bytes); the segmented copy at tools/tpu_check.py's
 
 @pytest.mark.parametrize("statics,n_pad,offs_rows", [
-    ((80, 16, 1, 128, 4, 8192), 5120, 48),               # n=4097 M=300
     ((16384, 16, 1, 128, 4, 8192), 1048576, 10240),      # strings 1M
 ])
 def test_ragged_pack_kernel(one_chip, statics, n_pad, offs_rows):
@@ -103,7 +102,6 @@ def test_ragged_pack_kernel(one_chip, statics, n_pad, offs_rows):
 
 
 @pytest.mark.parametrize("statics,flat_rows,offs_rows", [
-    ((640, 8, 1, 8, 2), 1280, 48),                       # n=4097 M=300
     ((131072, 8, 1, 8, 2), 262144, 10240),               # strings 1M, fpv=74
 ])
 def test_ragged_unpack_kernel(one_chip, statics, flat_rows, offs_rows):
@@ -209,10 +207,29 @@ def test_f64_bits_to_values_and_q6(one_chip, as_tpu):
     from spark_rapids_jni_tpu.utils import f64bits
     n = 6_000_000
     assert not f64bits.backend_has_f64_bitcast()
-    c = _compile(one_chip, jax.jit(f64bits.from_bits),
-                 _s((n, 2), jnp.uint32))
-    assert "bitcast-convert" not in c.as_text()    # the arithmetic path
+    # the arithmetic path: an f64 bitcast is what this compiler refuses
+    _compile(one_chip, jax.jit(f64bits.from_bits), _s((n, 2), jnp.uint32))
     _compile(one_chip, jax.jit(f64bits.to_bits), _s((n,), jnp.float64))
     _compile(one_chip, q6.q6_kernel, _s((n,), jnp.int64),
              _s((n,), jnp.float64), _s((n,), jnp.float64),
              _s((n,), jnp.int32), _s((), jnp.int32), _s((), jnp.int32))
+
+
+# --- four chips: the shuffle step as ONE program across the 2x2 mesh ------------
+
+def test_mesh_shuffle_program_four_chips(topo):
+    # what chip_smoke.py --chips 4 runs: shard_map + all_to_all + psum at
+    # 64K rows/device, compiled for the four described chips
+    import __graft_entry__ as G
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    rows = 1 << 16
+    n = rows * 4
+    sh = NamedSharding(mesh, P("data"))
+    datas = tuple(jax.ShapeDtypeStruct((n,), dt.storage, sharding=sh)
+                  for dt in G.FLAGSHIP_SCHEMA)
+    valid = jax.ShapeDtypeStruct((n, len(G.FLAGSHIP_SCHEMA)), jnp.bool_,
+                                 sharding=sh)
+    compiled = G.make_shuffle_step(mesh, rows).lower(datas, valid).compile()
+    text = compiled.as_text()
+    assert "all-to-all" in text and "all-reduce" in text

@@ -170,9 +170,6 @@ def main():
     def _crashed(exc_repr: str) -> bool:
         return "UNAVAILABLE" in exc_repr or "crashed" in exc_repr
 
-    def _transient(exc_repr: str) -> bool:
-        return "RESOURCE_EXHAUSTED" in exc_repr
-
     def _reexec() -> bool:
         """Re-exec for a fresh backend; False = budget exhausted (the
         caller must STOP — the poisoned backend fails every dispatch)."""
@@ -205,8 +202,7 @@ def main():
             struck_out = (prev.get("crashes", 0) >= 2
                           or prev.get("attempts", 0) >= 4)
             gave_up = ("gave_up" in prev or struck_out
-                       or ("error" in prev and not _crashed(prev["error"])
-                           and not _transient(prev["error"])))
+                       or ("error" in prev and not _crashed(prev["error"])))
             if struck_out and "gave_up" not in prev:
                 RESULTS["queries"][name] = {
                     **prev, "gave_up": "attempt budget (hang/crash?)"}
@@ -228,11 +224,6 @@ def main():
             json.dump(RESULTS, f, indent=1)
         entry = {"crashes": (prev or {}).get("crashes", 0),
                  "attempts": attempts}
-        # transient compile failures retry in-process;
-        # an entry whose only error is transient is also retried on resume
-        if prev and "error" in prev and _transient(prev["error"]):
-            entry = {k: v for k, v in prev.items() if k != "error"}
-            entry["attempts"] = attempts   # keep the pre-run increment
         try:
             # cold: eager capture (compiles + size syncs, tape recorded).
             # The capture run is the INSTRUMENTED one — metrics are on for
