@@ -350,7 +350,7 @@ def _init_xla_cache() -> None:
     # cold start is death by a thousand small compiles: cache them all.
     # Any jit dispatched before this point (table loading, warm-up
     # probes) has latched the cache state, hence the re-initialisation.
-    compile_cache.reconfigure_after_first_compile(0.0)
+    compile_cache.configure(0.0, already_compiled=True)
 
 
 def get_store() -> Optional[ArtifactStore]:

@@ -27,25 +27,23 @@ def cache_dir() -> str:
             or os.path.join(_CHECKOUT, ".jax_cache"))
 
 
-def configure(min_compile_secs: float = 0.5) -> str:
+def configure(min_compile_secs: float = 0.5, *,
+              already_compiled: bool = False) -> str:
     """Apply the rule; returns the cache directory in use.
 
     ``min_compile_secs`` is the smallest compile worth persisting (serving
-    cold starts pass 0: they are death by a thousand small compiles)."""
+    cold starts pass 0: they are death by a thousand small compiles).
+    ``already_compiled``: the process may have compiled before this call —
+    JAX latches the cache on or off at its first compile, so the latched
+    state is dropped and the next compile initialises against the
+    directory."""
     path = cache_dir()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_secs))
-    return path
-
-
-def reconfigure_after_first_compile(min_compile_secs: float = 0.0) -> str:
-    """:func:`configure` for a process that may already have compiled: JAX
-    latches the cache on or off at its first compile, so the latched state
-    is dropped and the next compile initialises against the directory."""
-    path = configure(min_compile_secs)
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    cc.reset_cache()
+    if already_compiled:
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
     return path

@@ -560,17 +560,17 @@ def check_composite_pack():
 
 
 GROUPS = [
-    ("ragged engine", "check_ragged"),
-    ("strings transcode", "check_strings_transcode"),
-    ("strings large-n branch", "check_strings_large_n"),
-    ("xpack engines (round 5)", "check_xpack_engines"),
-    ("dict strings", "check_dict_strings"),
-    ("dict fast path (codes + predicates)", "check_dict_fast_path"),
-    ("fixed-width u32-words transcode", "check_fixed_words"),
-    ("f64 bits<->values", "check_f64bits"),
+    ("ragged engine", check_ragged),
+    ("strings transcode", check_strings_transcode),
+    ("strings large-n branch", check_strings_large_n),
+    ("xpack engines (round 5)", check_xpack_engines),
+    ("dict strings", check_dict_strings),
+    ("dict fast path (codes + predicates)", check_dict_fast_path),
+    ("fixed-width u32-words transcode", check_fixed_words),
+    ("f64 bits<->values", check_f64bits),
     ("chip-killer query ops (rollup/window/string-compare)",
-     "check_query_ops"),
-    ("composite-key pack/unpack lowering", "check_composite_pack"),
+     check_query_ops),
+    ("composite-key pack/unpack lowering", check_composite_pack),
 ]
 
 
@@ -587,9 +587,9 @@ def main() -> int:
         for title, fn in GROUPS:
             print(f"{title}:", flush=True)
             try:
-                globals()[fn]()
+                fn()
             except Exception as e:  # noqa: BLE001 — a group that raises is a FAIL, and the sweep goes on
-                record(f"{fn} raised", False, repr(e)[:400])
+                record(f"{fn.__name__} raised", False, repr(e)[:400])
     RESULTS["seconds"] = round(time.time() - t0, 1)
     out = sys.argv[1] if len(sys.argv) > 1 else "PALLAS_TPU_CHECK.json"
     with open(out, "w") as f:
