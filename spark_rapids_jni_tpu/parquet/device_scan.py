@@ -296,11 +296,18 @@ def _u8_to_u32_flat(raw: jnp.ndarray) -> jnp.ndarray:
 def _word_pairs(words: jnp.ndarray) -> jnp.ndarray:
     """u32 [2k] → u32 [k, 2] (lo, hi) — the 8-byte value split.
 
-    Two strided slices stacked, NOT ``reshape(-1, 2)``: the TPU compiler
-    stages that reshape through a [k, 2]-minor temporary padded 64x, and
-    with two or more of them in one program its compile time grows with
-    k (12 minutes for the three 8-byte q6 columns at 6M rows)."""
-    return jnp.stack([words[0::2], words[1::2]], axis=1)
+    A lane-strided de-interleave on a [rows, 256] view, NOT
+    ``reshape(-1, 2)``: the TPU compiler stages that reshape through a
+    [k, 2]-minor temporary padded 64x, and with two or more of them in one
+    program its compile time grows with k (12 minutes for the three 8-byte
+    q6 columns at 6M rows).  This form compiles in seconds and is the
+    fastest of the forms timed on a v5e (PERF.md, PR 23)."""
+    k = words.shape[0] // 2
+    rows = -(-words.shape[0] // 256)
+    w2 = jnp.pad(words, (0, rows * 256 - words.shape[0])).reshape(rows, 256)
+    lo = w2[:, 0::2].reshape(-1)[:k]
+    hi = w2[:, 1::2].reshape(-1)[:k]
+    return jnp.stack([lo, hi], axis=1)
 
 
 @functools.partial(jax.jit, static_argnums=0)
