@@ -1,0 +1,82 @@
+"""Closed-loop JCUDF round trips: ``convert_to_rows(table)`` then
+``convert_from_rows(batch, schema)``, every leaf blocked, on a table that
+stays resident on the chip.  Work is counted in JCUDF row bytes: produced
+by the one direction plus consumed by the other."""
+
+from __future__ import annotations
+
+import types
+
+import numpy as np
+
+from .. import datagen, references
+
+
+def _block(tree):
+    import jax
+    jax.block_until_ready(jax.tree_util.tree_leaves(tree))
+
+
+def setup(config: dict, traffic: dict, seed: int, rec):
+    import spark_rapids_jni_tpu as sr
+    from spark_rapids_jni_tpu import Column, Table
+    columns = datagen.nvbench_columns(
+        config["rows"], config["columns"], seed,
+        config["null_every"], config["valid_share"], config["type_cycle"])
+    table = Table([Column.from_numpy(values, getattr(sr, name), valid)
+                   for name, values, valid in columns])
+    _block(table)
+    state = types.SimpleNamespace(
+        table=table, schema=table.schema, columns=columns, last=None,
+        facts={"row_bytes": 0})
+    for i in range(int(traffic.get("warmup_calls", 2))):
+        call(state, 0, i, rec)
+    state.facts["row_bytes"] = state.last[0].num_bytes
+    return state
+
+
+def call(state, caller: int, i: int, rec) -> float:
+    from spark_rapids_jni_tpu import convert_from_rows, convert_to_rows
+    state.last = None                 # a caller drops its last answer first
+    with rec.span("to_rows"):
+        batches = convert_to_rows(state.table)
+        _block(batches)
+    if len(batches) != 1:
+        raise RuntimeError(f"{len(batches)} batches: the cell's table has "
+                           f"to fit one")
+    with rec.span("from_rows"):
+        back = convert_from_rows(batches[0], state.schema)
+        _block(back)
+    state.last = (batches[0], back)
+    return 2.0 * batches[0].num_bytes
+
+
+def check(state, control: bool = False) -> dict:
+    """The window's last round trip against the plain packer and the input:
+    row bytes that differ, and payload/validity bits of the table that came
+    back that differ from the table that went in.  Both exact."""
+    batch, back = state.last
+    if control:
+        got = references.pack_rows_fixed(state.columns, ignore_nulls=True)
+    else:
+        got = batch.host_bytes()
+    returned = [(np.ascontiguousarray(np.asarray(c.data)),
+                 np.asarray(c.validity_or_true())) for c in back.columns]
+    state.last = state.table = None          # the program's state is freed
+    want = references.pack_rows_fixed(state.columns)
+    got = got.reshape(-1)
+    want = want.reshape(-1)
+    row_diff = (int(np.count_nonzero(got != want))
+                if got.shape == want.shape else max(got.size, want.size))
+    back_diff = 0
+    for (name, values, valid), (data, validity) in zip(state.columns,
+                                                       returned):
+        n = values.shape[0]
+        sent = np.ascontiguousarray(values).view(np.uint8).reshape(n, -1)
+        came = data.view(np.uint8).reshape(n, -1)
+        back_diff += int(np.count_nonzero((sent != came).any(axis=1)))
+        sent_valid = np.ones(n, bool) if valid is None else valid
+        back_diff += int(np.count_nonzero(sent_valid != validity))
+    back_diff += abs(len(returned) - len(state.columns))
+    return {"row_byte_mismatches": {"value": row_diff, "limit": 0},
+            "roundtrip_mismatches": {"value": back_diff, "limit": 0}}

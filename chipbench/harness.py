@@ -1,0 +1,216 @@
+"""One run of one cell: find its files, set up, warm up, drive the closed
+loop for the window, read the metrics, decide ``correct``.
+
+Driven by data: ``BENCHMARK.json`` names the cell's configuration and
+traffic; ``configs/<config>.json`` holds the deployment's sizes,
+``traffic/<traffic>.json`` the driver, its callers and the rate metric;
+a per-layer metric is ``metrics/<name>.json`` naming a reader in
+``readers/``.  A cell reports a per-layer metric when the metric's file
+lists the cell or ``workloads/<cell>.json`` lists the metric.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import importlib
+import json
+import os
+import statistics
+import threading
+import time
+
+from . import guards, trace
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+TRACE_DIR = os.path.join(ROOT, "chipbench_out", "trace")
+P95_METRIC = {"name": "call_p95_ms", "unit": "ms"}
+SETUP_METRIC = {"name": "setup_s", "unit": "s"}
+
+
+def _json(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+class Cell:
+    """A cell's files, resolved by the names in ``BENCHMARK.json``."""
+
+    def __init__(self, name: str, root: str = ROOT):
+        bench = _json(root, "BENCHMARK.json")
+        entry = next((w for w in bench["workloads"] if w["name"] == name),
+                     None)
+        if entry is None:
+            raise SystemExit(f"no cell {name!r} in BENCHMARK.json")
+        here = os.path.join(root, "chipbench")
+        self.name, self.chips = name, entry["chips"]
+        conf = next(c for c in bench["configs"]
+                    if c["name"] == entry["config"])
+        self.config = _json(root, conf["file"])
+        self.traffic = _json(here, "traffic", entry["traffic"] + ".json")
+        cell_file = os.path.join(here, "workloads", name + ".json")
+        named = (_json(cell_file).get("metrics", [])
+                 if os.path.exists(cell_file) else [])
+        self.per_layer = {}
+        for fn in sorted(os.listdir(os.path.join(here, "metrics"))):
+            metric = _json(here, "metrics", fn)
+            mname = fn[:-len(".json")]
+            if name in metric.get("workloads", []) or mname in named:
+                self.per_layer[mname] = metric
+        self.driver = importlib.import_module(
+            "chipbench.drivers." + self.traffic["driver"])
+
+
+class Recorder:
+    """chipbench's own spans: host clock, and in a traced run also a
+    ``TraceAnnotation`` so the span sits on the profiler's clock."""
+
+    def __init__(self, traced: bool = False):
+        self.traced = traced
+        self.spans: dict[str, list[float]] = {}
+        self._lock = threading.Lock()
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        note = contextlib.nullcontext()
+        if self.traced:
+            import jax
+            note = jax.profiler.TraceAnnotation(trace.PREFIX + name)
+        t0 = time.perf_counter()
+        with note:
+            yield
+        dt = time.perf_counter() - t0
+        with self._lock:
+            self.spans.setdefault(name, []).append(dt)
+
+    def clear(self):
+        self.spans = {}
+
+
+def percentile(values, q: float) -> float:
+    """Nearest-rank percentile of all the values."""
+    ordered = sorted(values)
+    rank = max(-(-len(ordered) * q // 100), 1)
+    return float(ordered[int(min(rank, len(ordered))) - 1])
+
+
+def drive(cell: Cell, state, rec: Recorder, seconds: float):
+    """The closed loop: every caller sends its next call when the last one
+    has answered, until the window's end; a call in flight then is waited
+    for and counts.  Returns ``(latencies, work, failed, elapsed)``: the
+    rate is all the work over all the time to the last answer."""
+    callers = int(cell.traffic.get("callers", 1))
+    lat, work, failed, ends = [], [0.0], [0], []
+    lock = threading.Lock()
+    t0 = time.perf_counter()
+    deadline = t0 + seconds
+
+    def loop(caller: int):
+        i = 0
+        while True:
+            a = time.perf_counter()
+            if a >= deadline:
+                break
+            try:
+                done = cell.driver.call(state, caller, i, rec)
+            except Exception as e:  # noqa: BLE001 — a failed call is counted, and fails the run
+                import traceback
+                traceback.print_exc()
+                with lock:
+                    failed[0] += 1
+                    state.errors.append(repr(e))
+                break
+            b = time.perf_counter()
+            with lock:
+                lat.append(b - a)
+                work[0] += done
+            i += 1
+        with lock:
+            ends.append(time.perf_counter())
+
+    threads = [threading.Thread(target=loop, args=(c,), name=f"caller{c}")
+               for c in range(callers)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return lat, work[0], failed[0], max(ends) - t0
+
+
+def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
+             t_start: float, device: dict | None = None,
+             config: dict | None = None, control: bool = False) -> dict:
+    """Everything of a run after the look for a chip.  ``config`` overrides
+    the cell's sizes (tests run a tiny copy); ``control=True`` puts the
+    configuration's control in the program's place at the comparison."""
+    import jax
+    from spark_rapids_jni_tpu.utils import metrics
+    metrics.set_enabled(True)
+    config = dict(cell.config if config is None else config)
+    rec = Recorder(traced)
+    fb0 = guards.fallbacks()
+    state = cell.driver.setup(config, cell.traffic, seed, rec)
+    state.errors = []
+    rec.clear()
+    setup_s = time.time() - t_start
+
+    comp0 = guards.compiles()
+    mono0 = time.monotonic()
+    prof = trace.capture(TRACE_DIR) if traced else contextlib.nullcontext()
+    with prof:
+        with rec.span("window"):
+            lat, work, failed, elapsed = drive(cell, state, rec, seconds)
+    window_mono = time.monotonic() - mono0
+    comp1, fb1 = guards.compiles(), guards.fallbacks()
+
+    peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                     for d in jax.local_devices())
+    rate = cell.traffic["rate"]
+    end_to_end = {
+        rate["metric"]: {"value": work / rate["per"] / elapsed,
+                         "unit": rate["unit"]},
+        P95_METRIC["name"]: {"value": percentile(lat, 95) * 1e3
+                             if lat else None,
+                             "unit": P95_METRIC["unit"]},
+        SETUP_METRIC["name"]: {"value": setup_s,
+                               "unit": SETUP_METRIC["unit"]},
+    }
+    out_device = dict(device or {})
+    out_device["memory_peak_bytes"] = int(peak_bytes)
+    result = {"correct": False, "attempted": len(lat) + failed,
+              "failed": failed, "metrics": end_to_end, "device": out_device}
+
+    if traced:
+        path = trace.find_xplane(TRACE_DIR)
+        reduced = trace.reduce_trace(path) if path else None
+        ctx = {"config": config, "facts": state.facts, "spans": rec.spans,
+               "calls": len(lat), "window_s": window_mono,
+               "trace": reduced, "device_kind": out_device.get("kind"),
+               "program_metrics": metrics}
+        per_layer = {}
+        for name, metric in cell.per_layer.items():
+            reader = importlib.import_module(
+                "chipbench.readers." + metric["reader"])
+            value = reader.read(ctx, metric.get("params", {}))
+            if value is not None:
+                per_layer[name] = {"value": value, "unit": metric["unit"]}
+        result["metrics"] = per_layer
+        result["end_to_end_traced"] = end_to_end
+        if reduced and reduced["busy_s"]:
+            out_device["busy_s"] = reduced["busy_s"]
+            out_device["window_s"] = reduced["window_s"]
+            result["breakdown"] = {"device_ops": reduced["device_ops"],
+                                   "idle_gaps": reduced["idle_gaps"]}
+
+    compared = cell.driver.check(state, control=control)
+    compared["fallbacks_moved"] = {"value": guards.moved(fb0, fb1),
+                                   "limit": 0}
+    compared["compiles_in_window"] = {"value": guards.moved(comp0, comp1),
+                                      "limit": 0}
+    compared["failed_calls"] = {"value": failed, "limit": 0}
+    result["correct"] = bool(lat) and all(
+        c["value"] <= c["limit"] for c in compared.values())
+    result["calls"] = len(lat)
+    result["median_call_ms"] = statistics.median(lat) * 1e3 if lat else None
+    result["compared"] = compared          # comes last on the line
+    return result
