@@ -43,21 +43,9 @@ def main(argv=None) -> int:
     from chipbench import harness
     cell = harness.Cell(args.workload)
 
-    import jax
-    devs = jax.devices()
-    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs)}
-    if device["platform"] != "tpu" or device["count"] < cell.chips:
-        print(f"chipbench: cell {cell.name} needs {cell.chips} TPU chip(s); "
-              f"JAX reports {device}", file=sys.stderr)
+    device = harness.find_chip(cell)
+    if device is None:
         return 1
-    from spark_rapids_jni_tpu import native
-    from spark_rapids_jni_tpu.utils import compile_cache
-    if native.load() is None:
-        print(f"chipbench: libsrjt.so did not build or load: "
-              f"{native.build_error}", file=sys.stderr)
-        return 1
-    compile_cache.configure(min_compile_secs=0.0)
 
     result = harness.run_cell(cell, args.seed, args.seconds,
                               bool(args.trace), t_start, device)

@@ -51,24 +51,33 @@ def call(state, caller: int, i: int, rec) -> float:
     return 2.0 * batches[0].num_bytes
 
 
-def check(state, control: bool = False) -> dict:
-    """The window's last round trip against the plain packer and the input:
-    row bytes that differ, and payload/validity bits of the table that came
-    back that differ from the table that went in.  Both exact."""
+def answers(state):
+    """The window's last round trip, on the host: the batch's bytes and the
+    payload and validity of the table that came back.  Frees the device."""
     batch, back = state.last
-    if control:
-        got = references.pack_rows_fixed(state.columns, ignore_nulls=True)
-    else:
-        got = batch.host_bytes()
+    rows = batch.host_bytes()
     returned = [(np.ascontiguousarray(np.asarray(c.data)),
                  np.asarray(c.validity_or_true())) for c in back.columns]
-    state.last = state.table = None          # the program's state is freed
-    want = references.pack_rows_fixed(state.columns)
-    got = got.reshape(-1)
-    want = want.reshape(-1)
-    row_diff = (int(np.count_nonzero(got != want))
-                if got.shape == want.shape else max(got.size, want.size))
-    back_diff = 0
+    state.last = state.table = None
+    return rows, returned
+
+
+def control_answers(state, got):
+    """The packer that ignores nulls, in the program's place."""
+    return references.pack_rows_fixed(state.columns, ignore_nulls=True), \
+        got[1]
+
+
+def compare(state, got) -> dict:
+    """Against the plain packer and the input: row bytes that differ, and
+    rows of the table that came back whose payload or validity bits differ
+    from the table that went in.  Both exact."""
+    rows, returned = got
+    want = references.pack_rows_fixed(state.columns).reshape(-1)
+    rows = rows.reshape(-1)
+    row_diff = (int(np.count_nonzero(rows != want))
+                if rows.shape == want.shape else max(rows.size, want.size))
+    back_diff = abs(len(returned) - len(state.columns))
     for (name, values, valid), (data, validity) in zip(state.columns,
                                                        returned):
         n = values.shape[0]
@@ -77,6 +86,5 @@ def check(state, control: bool = False) -> dict:
         back_diff += int(np.count_nonzero((sent != came).any(axis=1)))
         sent_valid = np.ones(n, bool) if valid is None else valid
         back_diff += int(np.count_nonzero(sent_valid != validity))
-    back_diff += abs(len(returned) - len(state.columns))
     return {"row_byte_mismatches": {"value": row_diff, "limit": 0},
             "roundtrip_mismatches": {"value": back_diff, "limit": 0}}

@@ -137,6 +137,30 @@ def drive(cell: Cell, state, rec: Recorder, seconds: float):
     return lat, work[0], failed[0], max(ends) - t0
 
 
+def find_chip(cell: Cell) -> dict | None:
+    """The look for a chip: the device as JAX reports it, or ``None`` (said
+    on stderr) without the TPU chips the cell asks for or without the
+    native library.  Points the compile cache where the program's one rule
+    puts it."""
+    import sys
+    import jax
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if device["platform"] != "tpu" or device["count"] < cell.chips:
+        print(f"chipbench: cell {cell.name} needs {cell.chips} TPU chip(s); "
+              f"JAX reports {device}", file=sys.stderr)
+        return None
+    from spark_rapids_jni_tpu import native
+    from spark_rapids_jni_tpu.utils import compile_cache
+    if native.load() is None:
+        print(f"chipbench: libsrjt.so did not build or load: "
+              f"{native.build_error}", file=sys.stderr)
+        return None
+    compile_cache.configure(min_compile_secs=0.0)
+    return device
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
              t_start: float, device: dict | None = None,
              config: dict | None = None, control: bool = False) -> dict:
@@ -202,7 +226,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
             result["breakdown"] = {"device_ops": reduced["device_ops"],
                                    "idle_gaps": reduced["idle_gaps"]}
 
-    compared = cell.driver.check(state, control=control)
+    got = cell.driver.answers(state)
+    if control:
+        got = cell.driver.control_answers(state, got)
+    compared = cell.driver.compare(state, got)
     compared["fallbacks_moved"] = {"value": guards.moved(fb0, fb1),
                                    "limit": 0}
     compared["compiles_in_window"] = {"value": guards.moved(comp0, comp1),

@@ -26,6 +26,7 @@ _OPS_LINE = "XLA Ops"
 _NOT_OPS = ("Steps", "XLA Modules", "XLA TraceMe", "Framework Ops",
             "Framework Name Scope", "Source code")
 TOP = 10
+NAME_CHARS = 120            # of an operation's name as the profiler has it
 
 
 @contextlib.contextmanager
@@ -137,5 +138,5 @@ def reduce_trace(path: str) -> dict:
     total_busy = sum(busy) / n / 1e9
     return {"busy_s": total_busy if planes and total_busy > 0 else None,
             "window_s": (hi - lo) / 1e9, "devices": len(planes),
-            "device_ops": [[k, v / 1e9 / n] for k, v in ops],
+            "device_ops": [[k[:NAME_CHARS], v / 1e9 / n] for k, v in ops],
             "idle_gaps": idle}
