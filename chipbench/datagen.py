@@ -90,10 +90,12 @@ def _parquet(table) -> bytes:
 
 
 def tpcds_star_parquet(n_sales: int, n_items: int, n_stores: int, seed: int,
-                       n_dates: int = 366 * 3) -> dict[str, bytes]:
+                       n_dates: int, order_seed: int) -> dict[str, bytes]:
     """``store_sales`` + ``item`` / ``date_dim`` / ``store`` as Snappy
     Parquet: uniform surrogate keys, low-cardinality string dimensions,
-    decimal measures as scaled int64 cents."""
+    decimal measures as scaled int64 cents.  ``order_seed`` shuffles the rows
+    of ``store_sales``: the same rows, so every join and group has the same
+    size, in another order."""
     import decimal
     import pyarrow as pa
     rng = np.random.default_rng(seed)
@@ -134,18 +136,18 @@ def tpcds_star_parquet(n_sales: int, n_items: int, n_stores: int, seed: int,
     price_cents = rng.integers(100, 300_00, n_sales).astype(np.int64)
     list_cents = price_cents + rng.integers(0, 50_00, n_sales)
     qty = rng.integers(1, 100, n_sales).astype(np.int32)
-    store_sales = pa.table({
-        "ss_sold_date_sk": pa.array(
-            rng.integers(1, n_dates + 1, n_sales).astype(np.int32)),
-        "ss_item_sk": pa.array(
-            rng.integers(1, n_items + 1, n_sales).astype(np.int32)),
-        "ss_store_sk": pa.array(
-            rng.integers(1, max(n_stores, 2), n_sales).astype(np.int32)),
-        "ss_quantity": pa.array(qty),
-        "ss_sales_price_cents": pa.array(price_cents),
-        "ss_list_price_cents": pa.array(list_cents),
-        "ss_ext_sales_price": pa.array(
-            (price_cents * qty).astype(np.float64) / 100.0),
-    })
+    order = np.random.default_rng(order_seed).permutation(n_sales)
+    store_sales = pa.table({name: pa.array(values[order]) for name, values in {
+        "ss_sold_date_sk":
+            rng.integers(1, n_dates + 1, n_sales).astype(np.int32),
+        "ss_item_sk":
+            rng.integers(1, n_items + 1, n_sales).astype(np.int32),
+        "ss_store_sk":
+            rng.integers(1, max(n_stores, 2), n_sales).astype(np.int32),
+        "ss_quantity": qty,
+        "ss_sales_price_cents": price_cents,
+        "ss_list_price_cents": list_cents,
+        "ss_ext_sales_price": (price_cents * qty).astype(np.float64) / 100.0,
+    }.items()})
     return {"store_sales": _parquet(store_sales), "item": _parquet(item),
             "date_dim": _parquet(date_dim), "store": _parquet(store)}

@@ -7,7 +7,9 @@ Work is counted in queries answered."""
 from __future__ import annotations
 
 import io
+import sys
 import threading
+import time
 import types
 
 import numpy as np
@@ -22,20 +24,36 @@ def _block(tree):
     jax.block_until_ready(jax.tree_util.tree_leaves(tree))
 
 
+def _note(what: str, t0: float):
+    print(f"sql setup: {what} {time.perf_counter() - t0:.1f} s",
+          file=sys.stderr, flush=True)
+
+
 def setup(config: dict, traffic: dict, seed: int, rec):
     from spark_rapids_jni_tpu import exec as xc
     from spark_rapids_jni_tpu.models import tpcds
     from spark_rapids_jni_tpu.utils import metrics
+    t0 = time.perf_counter()
+    # the data set is the configuration's (as dbgen's is at a scale factor):
+    # join and group sizes, and with them every compiled shape, are the same
+    # in every run; the seed orders the fact's rows and places the streams
     files = datagen.tpcds_star_parquet(
-        config["sales_rows"], config["items"], config["stores"], seed,
-        config["dates"])
+        config["sales_rows"], config["items"], config["stores"],
+        config["data_seed"], config["dates"], order_seed=seed)
+    _note("generated", t0)
     tables = tpcds.load_tables(files)
     _block(tables)
+    _note("tables on the chip", t0)
     queries = list(config["queries"])
     callers = int(traffic["callers"])
     first = np.zeros(callers, int)
     first[np.random.default_rng(seed).permutation(callers)[:callers // 2]] = 1
-    sched = xc.QueryScheduler(workers=int(traffic["workers"]))
+    # max_batch 1: every request is a launch of its own.  Streams of the
+    # spec's throughput test differ in their substitution parameters, so no
+    # two of their requests are one request; four streams sending the same
+    # two texts would otherwise coalesce, and lock into phase (PERF.md).
+    sched = xc.QueryScheduler(workers=int(traffic["workers"]),
+                              max_batch=int(traffic["max_batch"]))
     sched.__enter__()
     state = types.SimpleNamespace(
         files=files, tables=tables, sched=sched, queries=queries,
@@ -45,6 +63,7 @@ def setup(config: dict, traffic: dict, seed: int, rec):
         for _ in range(int(traffic["warmup_max_submits"])):
             hits = metrics.counter_value("exec.plan_cache.hit")
             _submit(state, q, rec)
+            _note(f"{q} warm-up call", t0)
             if metrics.counter_value("exec.plan_cache.hit") > hits:
                 break
         else:

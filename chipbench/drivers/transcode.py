@@ -26,12 +26,18 @@ def setup(config: dict, traffic: dict, seed: int, rec):
     table = Table([Column.from_numpy(values, getattr(sr, name), valid)
                    for name, values, valid in columns])
     _block(table)
+    # the answers that are compared: the calls of these numbers, drawn from
+    # the seed, and the window's last
+    sample = config["check_sample"]
+    keep = np.random.default_rng(seed).choice(
+        int(sample["of_first"]), int(sample["calls"]), replace=False)
     state = types.SimpleNamespace(
         table=table, schema=table.schema, columns=columns, last=None,
-        facts={"row_bytes": 0})
+        keep=frozenset(), kept=[], facts={"row_bytes": 0})
     for i in range(int(traffic.get("warmup_calls", 2))):
         call(state, 0, i, rec)
     state.facts["row_bytes"] = state.last[0].num_bytes
+    state.keep = frozenset(keep.tolist())
     return state
 
 
@@ -48,43 +54,55 @@ def call(state, caller: int, i: int, rec) -> float:
         back = convert_from_rows(batches[0], state.schema)
         _block(back)
     state.last = (batches[0], back)
+    if i in state.keep:
+        state.kept.append(state.last)
     return 2.0 * batches[0].num_bytes
 
 
 def answers(state):
-    """The window's last round trip, on the host: the batch's bytes and the
-    payload and validity of the table that came back.  Frees the device."""
-    batch, back = state.last
-    rows = batch.host_bytes()
-    returned = [(np.ascontiguousarray(np.asarray(c.data)),
-                 np.asarray(c.validity_or_true())) for c in back.columns]
+    """The sampled round trips and the window's last, on the host: each the
+    batch's bytes and the payload and validity of the table that came back.
+    Frees the device."""
+    trips = state.kept + [t for t in [state.last] if t is not None
+                          and not any(t is k for k in state.kept)]
     state.last = state.table = None
-    return rows, returned
+    state.kept = []
+    got = []
+    while trips:
+        batch, back = trips.pop(0)
+        got.append((batch.host_bytes(),
+                    [(np.ascontiguousarray(np.asarray(c.data)),
+                      np.asarray(c.validity_or_true()))
+                     for c in back.columns]))
+    return got
 
 
 def control_answers(state, got):
     """The packer that ignores nulls, in the program's place."""
-    return references.pack_rows_fixed(state.columns, ignore_nulls=True), \
-        got[1]
+    low = references.pack_rows_fixed(state.columns, ignore_nulls=True)
+    return [(low, returned) for _, returned in got]
 
 
 def compare(state, got) -> dict:
     """Against the plain packer and the input: row bytes that differ, and
     rows of the table that came back whose payload or validity bits differ
-    from the table that went in.  Both exact."""
-    rows, returned = got
+    from the table that went in, summed over the round trips compared.
+    Both exact."""
     want = references.pack_rows_fixed(state.columns).reshape(-1)
-    rows = rows.reshape(-1)
-    row_diff = (int(np.count_nonzero(rows != want))
-                if rows.shape == want.shape else max(rows.size, want.size))
-    back_diff = abs(len(returned) - len(state.columns))
-    for (name, values, valid), (data, validity) in zip(state.columns,
-                                                       returned):
-        n = values.shape[0]
-        sent = np.ascontiguousarray(values).view(np.uint8).reshape(n, -1)
-        came = data.view(np.uint8).reshape(n, -1)
-        back_diff += int(np.count_nonzero((sent != came).any(axis=1)))
-        sent_valid = np.ones(n, bool) if valid is None else valid
-        back_diff += int(np.count_nonzero(sent_valid != validity))
+    row_diff = back_diff = 0
+    for rows, returned in got:
+        rows = rows.reshape(-1)
+        row_diff += (int(np.count_nonzero(rows != want))
+                     if rows.shape == want.shape
+                     else max(rows.size, want.size))
+        back_diff += abs(len(returned) - len(state.columns))
+        for (name, values, valid), (data, validity) in zip(state.columns,
+                                                           returned):
+            n = values.shape[0]
+            sent = np.ascontiguousarray(values).view(np.uint8).reshape(n, -1)
+            came = data.view(np.uint8).reshape(n, -1)
+            back_diff += int(np.count_nonzero((sent != came).any(axis=1)))
+            sent_valid = np.ones(n, bool) if valid is None else valid
+            back_diff += int(np.count_nonzero(sent_valid != validity))
     return {"row_byte_mismatches": {"value": row_diff, "limit": 0},
             "roundtrip_mismatches": {"value": back_diff, "limit": 0}}

@@ -5,6 +5,21 @@ where chipbench reads program internals other than ``utils/metrics.py``."""
 from __future__ import annotations
 
 WINDOW_COUNTERS = ("compiled.capture", "exec.plan_cache.miss")
+XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_xla_compiles = []          # one entry a program; the listener once a process
+
+
+def watch_xla_compiles():
+    """Count, from here on, every program that JAX hands to the backend's
+    compiler or fetches from its persistent cache."""
+    import jax.monitoring
+
+    def listen(event, seconds, **_):
+        if event == XLA_COMPILE_EVENT:
+            _xla_compiles[0] += 1
+    if not _xla_compiles:
+        _xla_compiles.append(0)
+        jax.monitoring.register_event_duration_secs_listener(listen)
 
 
 def fallbacks() -> dict[str, float]:
@@ -22,7 +37,8 @@ def fallbacks() -> dict[str, float]:
 
 def compiles() -> dict[str, float]:
     from spark_rapids_jni_tpu.utils import metrics
-    return {k: metrics.counter_value(k) for k in WINDOW_COUNTERS}
+    return {"jax.backend_compile": float(sum(_xla_compiles)),
+            **{k: metrics.counter_value(k) for k in WINDOW_COUNTERS}}
 
 
 def moved(before: dict, after: dict) -> float:

@@ -3,7 +3,8 @@ loop for the window, read the metrics, decide ``correct``.
 
 Driven by data: ``BENCHMARK.json`` names the cell's configuration and
 traffic; ``configs/<config>.json`` holds the deployment's sizes,
-``traffic/<traffic>.json`` the driver, its callers and the rate metric;
+``traffic/<traffic>.json`` the driver, its callers, the rate metric and,
+where the mix has a steady one, the tail metric;
 a per-layer metric is ``metrics/<name>.json`` naming a reader in
 ``readers/``.  A cell reports a per-layer metric when the metric's file
 lists the cell or ``workloads/<cell>.json`` lists the metric.
@@ -12,10 +13,12 @@ lists the cell or ``workloads/<cell>.json`` lists the metric.
 from __future__ import annotations
 
 import contextlib
+import gc
 import importlib
 import json
 import os
 import statistics
+import sys
 import threading
 import time
 
@@ -24,7 +27,6 @@ from . import guards, trace
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRACE_DIR = os.path.join(ROOT, "chipbench_out", "trace")
-P95_METRIC = {"name": "call_p95_ms", "unit": "ms"}
 SETUP_METRIC = {"name": "setup_s", "unit": "s"}
 
 
@@ -98,9 +100,12 @@ def drive(cell: Cell, state, rec: Recorder, seconds: float):
     """The closed loop: every caller sends its next call when the last one
     has answered, until the window's end; a call in flight then is waited
     for and counts.  Returns ``(latencies, work, failed, elapsed)``: the
-    rate is all the work over all the time to the last answer."""
+    rate is all the work over all the time to the last answer.  ``state.
+    starts`` keeps each call's start, seconds into the window, in the order
+    of ``latencies``."""
     callers = int(cell.traffic.get("callers", 1))
     lat, work, failed, ends = [], [0.0], [0], []
+    state.starts = starts = []
     lock = threading.Lock()
     t0 = time.perf_counter()
     deadline = t0 + seconds
@@ -123,6 +128,7 @@ def drive(cell: Cell, state, rec: Recorder, seconds: float):
             b = time.perf_counter()
             with lock:
                 lat.append(b - a)
+                starts.append(a - t0)
                 work[0] += done
             i += 1
         with lock:
@@ -142,7 +148,6 @@ def find_chip(cell: Cell) -> dict | None:
     on stderr) without the TPU chips the cell asks for or without the
     native library.  Points the compile cache where the program's one rule
     puts it."""
-    import sys
     import jax
     devs = jax.devices()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -170,12 +175,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     import jax
     from spark_rapids_jni_tpu.utils import metrics
     metrics.set_enabled(True)
+    guards.watch_xla_compiles()
     config = dict(cell.config if config is None else config)
     rec = Recorder(traced)
     fb0 = guards.fallbacks()
     state = cell.driver.setup(config, cell.traffic, seed, rec)
     state.errors = []
     rec.clear()
+    # what set-up left on the heap is not the window's to collect
+    gc.collect()
+    gc.freeze()
     setup_s = time.time() - t_start
 
     comp0 = guards.compiles()
@@ -185,20 +194,20 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         with rec.span("window"):
             lat, work, failed, elapsed = drive(cell, state, rec, seconds)
     window_mono = time.monotonic() - mono0
+    gc.unfreeze()
     comp1, fb1 = guards.compiles(), guards.fallbacks()
 
     peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in jax.local_devices())
-    rate = cell.traffic["rate"]
-    end_to_end = {
-        rate["metric"]: {"value": work / rate["per"] / elapsed,
-                         "unit": rate["unit"]},
-        P95_METRIC["name"]: {"value": percentile(lat, 95) * 1e3
-                             if lat else None,
-                             "unit": P95_METRIC["unit"]},
-        SETUP_METRIC["name"]: {"value": setup_s,
-                               "unit": SETUP_METRIC["unit"]},
-    }
+    rate, tail = cell.traffic["rate"], cell.traffic.get("tail")
+    end_to_end = {rate["metric"]: {"value": work / rate["per"] / elapsed,
+                                   "unit": rate["unit"]}}
+    if tail:
+        end_to_end[tail["metric"]] = {
+            "value": percentile(lat, tail["percentile"]) * 1e3
+            if lat else None, "unit": "ms"}
+    end_to_end[SETUP_METRIC["name"]] = {"value": setup_s,
+                                        "unit": SETUP_METRIC["unit"]}
     out_device = dict(device or {})
     out_device["memory_peak_bytes"] = int(peak_bytes)
     result = {"correct": False, "attempted": len(lat) + failed,
@@ -208,7 +217,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         path = trace.find_xplane(TRACE_DIR)
         reduced = trace.reduce_trace(path) if path else None
         ctx = {"config": config, "facts": state.facts, "spans": rec.spans,
-               "calls": len(lat), "window_s": window_mono,
+               "calls": len(lat), "latencies": lat, "window_s": window_mono,
                "trace": reduced, "device_kind": out_device.get("kind"),
                "program_metrics": metrics}
         per_layer = {}
@@ -226,6 +235,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
             result["breakdown"] = {"device_ops": reduced["device_ops"],
                                    "idle_gaps": reduced["idle_gaps"]}
 
+    for error in state.errors:
+        print("chipbench: a call failed:", error, file=sys.stderr)
     got = cell.driver.answers(state)
     if control:
         got = cell.driver.control_answers(state, got)
@@ -239,5 +250,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         c["value"] <= c["limit"] for c in compared.values())
     result["calls"] = len(lat)
     result["median_call_ms"] = statistics.median(lat) * 1e3 if lat else None
+    # where a rate dips and the tail does not: the few longest calls, each
+    # [seconds into the window, ms]
+    result["slowest_calls"] = [
+        [round(state.starts[i], 3), round(lat[i] * 1e3, 3)]
+        for i in sorted(range(len(lat)), key=lambda i: -lat[i])[:5]]
     result["compared"] = compared          # comes last on the line
     return result

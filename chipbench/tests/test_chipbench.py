@@ -65,6 +65,27 @@ def test_driver_agrees_with_reference_and_control_fails(name):
     assert not passes(control), control
 
 
+def test_transcode_compares_a_sample_drawn_from_the_seed_and_the_last():
+    cell = harness.Cell("fixed12_roundtrip")
+    config = {**tiny(cell), "check_sample": {"calls": 2, "of_first": 5}}
+    rec = harness.Recorder()
+    kept = []
+    for _ in range(2):
+        state = cell.driver.setup(config, cell.traffic, 2**31 + 5, rec)
+        assert not state.kept and len(state.keep) == 2
+        for i in range(7):
+            cell.driver.call(state, 0, i, rec)
+        kept.append(sorted(state.keep))
+        got = cell.driver.answers(state)
+        assert len(got) == 3 and passes(cell.driver.compare(state, got))
+    assert kept[0] == kept[1]
+    # the last call's own number drawn: it is compared once
+    state = cell.driver.setup(config, cell.traffic, 2**31 + 5, rec)
+    for i in range(max(kept[0]) + 1):
+        cell.driver.call(state, 0, i, rec)
+    assert len(cell.driver.answers(state)) == 2
+
+
 def test_whole_run_traced_on_cpu_reports_spans_and_no_device_share():
     cell = harness.Cell("fixed12_roundtrip")
     r = harness.run_cell(cell, 5, 0.3, True, time.time(), FAKE_CHIP,
@@ -82,6 +103,16 @@ def test_end_to_end_line_has_the_cells_metrics():
     assert set(r["metrics"]) == {"transcode_gbps", "call_p95_ms", "setup_s"}
     assert all(m["value"] > 0 for m in r["metrics"].values())
     assert r["attempted"] == r["calls"] and r["failed"] == 0
+
+
+def test_every_cell_reports_the_end_to_end_metrics_benchmark_json_lists():
+    for w in BENCH["workloads"]:
+        traffic = harness.Cell(w["name"]).traffic
+        reported = {traffic["rate"]["metric"], "setup_s"} | (
+            {traffic["tail"]["metric"]} if "tail" in traffic else set())
+        listed = {m["name"] for m in BENCH["end_to_end"]
+                  if w["name"] in m.get("workloads", [w["name"]])}
+        assert reported == listed, w["name"]
 
 
 def test_command_fails_without_a_chip_and_prints_no_result():
@@ -130,7 +161,9 @@ def test_altered_row_byte_is_caught(monkeypatch):
     compared = _run_broken(
         "fixed12_roundtrip", monkeypatch,
         lambda m: m.setattr(sr, "convert_to_rows", to_rows))
-    assert compared["row_byte_mismatches"]["value"] == 1
+    # one byte in each round trip compared: the last, and the sampled ones
+    # that the short window reached
+    assert 1 <= compared["row_byte_mismatches"]["value"] <= 3
 
 
 def test_altered_column_on_the_way_back_is_caught(monkeypatch):
@@ -147,7 +180,7 @@ def test_altered_column_on_the_way_back_is_caught(monkeypatch):
     compared = _run_broken(
         "fixed12_roundtrip", monkeypatch,
         lambda m: m.setattr(sr, "convert_from_rows", from_rows))
-    assert compared["roundtrip_mismatches"]["value"] == 1
+    assert 1 <= compared["roundtrip_mismatches"]["value"] <= 3
     assert compared["row_byte_mismatches"]["value"] == 0
 
 
@@ -180,6 +213,22 @@ def test_altered_sql_answer_is_caught(monkeypatch):
         "star_streams4", monkeypatch,
         lambda m: m.setattr(sql, "_host_answer", host_answer))
     assert compared["sum_rel_gap"]["value"] > 5e-10
+
+
+def test_a_program_compiled_inside_the_window_is_caught(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from chipbench.drivers import transcode
+    real = transcode.call
+
+    def call(state, caller, i, rec):
+        if i == 3:                       # a shape the warm-up never saw
+            jax.jit(lambda x: x * 3 + i)(jnp.ones(17)).block_until_ready()
+        return real(state, caller, i, rec)
+    compared = _run_broken("fixed12_roundtrip", monkeypatch,
+                           lambda m: m.setattr(transcode, "call", call))
+    assert compared["compiles_in_window"]["value"] >= 1
+    assert compared["row_byte_mismatches"]["value"] == 0
 
 
 # --- (b) the trace reducer on a recorded trace ---------------------------------
