@@ -32,7 +32,7 @@ def main(argv=None) -> int:
         state = cell.driver.setup(dict(cell.config), cell.traffic, seed, rec)
         state.errors = []
         setup_s = time.time() - t0
-        lat, _, failed, _ = harness.drive(cell, state, rec, args.seconds)
+        lat, _, _, failed, _ = harness.drive(cell, state, rec, args.seconds)
         got = cell.driver.answers(state)
         program = cell.driver.compare(state, got)
         control = cell.driver.compare(

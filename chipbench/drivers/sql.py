@@ -143,4 +143,4 @@ def compare(state, got) -> dict:
 
 
 # set from readings on the chip (PERF.md §2 "limits of correct")
-SUM_RTOL = 1e-12
+SUM_RTOL = 1e-10

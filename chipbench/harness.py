@@ -27,7 +27,6 @@ from . import guards, trace
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRACE_DIR = os.path.join(ROOT, "chipbench_out", "trace")
-SETUP_METRIC = {"name": "setup_s", "unit": "s"}
 
 
 def _json(*parts):
@@ -99,13 +98,15 @@ def percentile(values, q: float) -> float:
 def drive(cell: Cell, state, rec: Recorder, seconds: float):
     """The closed loop: every caller sends its next call when the last one
     has answered, until the window's end; a call in flight then is waited
-    for and counts.  Returns ``(latencies, work, failed, elapsed)``: the
-    rate is all the work over all the time to the last answer.  ``state.
-    starts`` keeps each call's start, seconds into the window, in the order
-    of ``latencies``."""
+    for and counts.  Where a caller's sequence is ``cycle_calls`` long (a
+    stream's pass over its query set), it ends on a whole pass, so the
+    work counted has the mix's proportions whatever the end cuts.  Returns
+    ``(latencies, starts, work, failed, elapsed)``: the rate is all the work
+    over all the time to the last answer; ``starts`` are the calls' starts,
+    seconds into the window, in the order of ``latencies``."""
     callers = int(cell.traffic.get("callers", 1))
-    lat, work, failed, ends = [], [0.0], [0], []
-    state.starts = starts = []
+    cycle = int(cell.traffic.get("cycle_calls", 1))
+    lat, starts, work, failed, ends = [], [], [0.0], [0], []
     lock = threading.Lock()
     t0 = time.perf_counter()
     deadline = t0 + seconds
@@ -114,7 +115,7 @@ def drive(cell: Cell, state, rec: Recorder, seconds: float):
         i = 0
         while True:
             a = time.perf_counter()
-            if a >= deadline:
+            if a >= deadline and i % cycle == 0:
                 break
             try:
                 done = cell.driver.call(state, caller, i, rec)
@@ -140,7 +141,7 @@ def drive(cell: Cell, state, rec: Recorder, seconds: float):
         t.start()
     for t in threads:
         t.join()
-    return lat, work[0], failed[0], max(ends) - t0
+    return lat, starts, work[0], failed[0], max(ends) - t0
 
 
 def find_chip(cell: Cell) -> dict | None:
@@ -168,10 +169,9 @@ def find_chip(cell: Cell) -> dict | None:
 
 def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
              t_start: float, device: dict | None = None,
-             config: dict | None = None, control: bool = False) -> dict:
+             config: dict | None = None) -> dict:
     """Everything of a run after the look for a chip.  ``config`` overrides
-    the cell's sizes (tests run a tiny copy); ``control=True`` puts the
-    configuration's control in the program's place at the comparison."""
+    the cell's sizes (tests run a tiny copy)."""
     import jax
     from spark_rapids_jni_tpu.utils import metrics
     metrics.set_enabled(True)
@@ -192,7 +192,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     prof = trace.capture(TRACE_DIR) if traced else contextlib.nullcontext()
     with prof:
         with rec.span("window"):
-            lat, work, failed, elapsed = drive(cell, state, rec, seconds)
+            lat, starts, work, failed, elapsed = drive(cell, state, rec,
+                                                       seconds)
     window_mono = time.monotonic() - mono0
     gc.unfreeze()
     comp1, fb1 = guards.compiles(), guards.fallbacks()
@@ -206,8 +207,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         end_to_end[tail["metric"]] = {
             "value": percentile(lat, tail["percentile"]) * 1e3
             if lat else None, "unit": "ms"}
-    end_to_end[SETUP_METRIC["name"]] = {"value": setup_s,
-                                        "unit": SETUP_METRIC["unit"]}
+    end_to_end["setup_s"] = {"value": setup_s, "unit": "s"}
     out_device = dict(device or {})
     out_device["memory_peak_bytes"] = int(peak_bytes)
     result = {"correct": False, "attempted": len(lat) + failed,
@@ -237,10 +237,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
 
     for error in state.errors:
         print("chipbench: a call failed:", error, file=sys.stderr)
-    got = cell.driver.answers(state)
-    if control:
-        got = cell.driver.control_answers(state, got)
-    compared = cell.driver.compare(state, got)
+    compared = cell.driver.compare(state, cell.driver.answers(state))
     compared["fallbacks_moved"] = {"value": guards.moved(fb0, fb1),
                                    "limit": 0}
     compared["compiles_in_window"] = {"value": guards.moved(comp0, comp1),
@@ -253,7 +250,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     # where a rate dips and the tail does not: the few longest calls, each
     # [seconds into the window, ms]
     result["slowest_calls"] = [
-        [round(state.starts[i], 3), round(lat[i] * 1e3, 3)]
+        [round(starts[i], 3), round(lat[i] * 1e3, 3)]
         for i in sorted(range(len(lat)), key=lambda i: -lat[i])[:5]]
     result["compared"] = compared          # comes last on the line
     return result

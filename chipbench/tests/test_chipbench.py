@@ -55,8 +55,10 @@ def test_driver_agrees_with_reference_and_control_fails(name):
     rec = harness.Recorder()
     state = cell.driver.setup(tiny(cell), cell.traffic, 2**31 + 11, rec)
     state.errors = []
-    lat, work, failed, elapsed = harness.drive(cell, state, rec, 0.5)
+    lat, _, work, failed, elapsed = harness.drive(cell, state, rec, 0.5)
     assert lat and not failed and work > 0 and elapsed >= 0.5
+    # every stream ended on a whole pass over its queries
+    assert len(lat) % cell.traffic.get("cycle_calls", 1) == 0
     got = cell.driver.answers(state)
     program = cell.driver.compare(state, got)
     assert passes(program), program
@@ -336,6 +338,7 @@ def test_new_config_cell_and_metric_as_files_only(tmp_path):
         "traffic": "roundtrip_c1", "chips": 1, "why": "test"})
     # the new cell joins a metric that is there (its own file names it) and a
     # new metric joins the new cell (the metric's file names the cell)
+    (tmp_path / "chipbench/workloads").mkdir(exist_ok=True)
     (tmp_path / "chipbench/workloads/fixed30_roundtrip.json").write_text(
         json.dumps({"metrics": ["to_rows_ms"]}))
     (tmp_path / "chipbench/metrics/window_span_ms.json").write_text(
