@@ -10,12 +10,15 @@ import io
 
 import numpy as np
 
-# spark-rapids-jni benchmarks/row_conversion.cpp:30-38 — the nvbench cycle
-NVBENCH_CYCLE = ("int8", "int16", "int32", "int64", "float32", "float64",
-                 "bool8")
+# spark-rapids-jni src/main/cpp/benchmarks/row_conversion.cpp: the cycle of
+# both of its axes ("Fixed Width Only", 212 columns; "Fixed or Variable
+# Width" without strings, 155 columns)
+NVBENCH_CYCLE = ("int8", "int32", "int16", "int64", "int32", "bool8",
+                 "uint16", "uint8", "uint64")
 _NP = {"int8": np.int8, "int16": np.int16, "int32": np.int32,
-       "int64": np.int64, "float32": np.float32, "float64": np.float64,
-       "bool8": np.uint8}
+       "int64": np.int64, "uint8": np.uint8, "uint16": np.uint16,
+       "uint32": np.uint32, "uint64": np.uint64, "float32": np.float32,
+       "float64": np.float64, "bool8": np.uint8}
 
 
 def np_dtype(type_name: str) -> np.dtype:
@@ -25,8 +28,9 @@ def np_dtype(type_name: str) -> np.dtype:
 def nvbench_columns(n_rows: int, n_cols: int, seed: int,
                     null_every: int = 3, valid_share: float = 0.9,
                     cycle=NVBENCH_CYCLE):
-    """``[(type_name, values, validity | None)]``: the reference's
-    fixed-width axis, ~10% nulls on every ``null_every``-th column."""
+    """``[(type_name, values, validity | None)]``: a fixed-width table of
+    the reference's benchmark, ~10% nulls on every ``null_every``-th
+    column."""
     rng = np.random.default_rng(seed)
     cols = []
     for i in range(n_cols):

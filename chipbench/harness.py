@@ -188,13 +188,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     setup_s = time.time() - t_start
 
     comp0 = guards.compiles()
-    mono0 = time.monotonic()
+    mono0, started_at = time.monotonic(), time.time()
     prof = trace.capture(TRACE_DIR) if traced else contextlib.nullcontext()
     with prof:
         with rec.span("window"):
             lat, starts, work, failed, elapsed = drive(cell, state, rec,
                                                        seconds)
-    window_mono = time.monotonic() - mono0
     gc.unfreeze()
     comp1, fb1 = guards.compiles(), guards.fallbacks()
 
@@ -217,7 +216,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         path = trace.find_xplane(TRACE_DIR)
         reduced = trace.reduce_trace(path) if path else None
         ctx = {"config": config, "facts": state.facts, "spans": rec.spans,
-               "calls": len(lat), "latencies": lat, "window_s": window_mono,
+               "calls": len(lat), "window_start_monotonic": mono0,
                "trace": reduced, "device_kind": out_device.get("kind"),
                "program_metrics": metrics}
         per_layer = {}
@@ -248,7 +247,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     result["calls"] = len(lat)
     result["median_call_ms"] = statistics.median(lat) * 1e3 if lat else None
     # where a rate dips and the tail does not: the few longest calls, each
-    # [seconds into the window, ms]
+    # [seconds into the window, ms], and the window's start on the wall clock
+    result["window_started_at"] = started_at
     result["slowest_calls"] = [
         [round(starts[i], 3), round(lat[i] * 1e3, 3)]
         for i in sorted(range(len(lat)), key=lambda i: -lat[i])[:5]]
