@@ -83,7 +83,9 @@ admission → coalesce window → batch membership → dispatch →
 ``block_until_ready`` → resolve.  Each stage records (a) a flight-recorder
 event (``utils/flight.py`` — always on, so the black box has the full
 lifecycle when an incident snapshot fires) and (b) an exact per-stage
-latency attribution histogram: ``exec.stage.queue_ms`` (submit →
+latency attribution histogram: ``exec.stage.frontend_ms`` (``submit_sql``
+only: entry → ``submit``; SQL memo or parse + bind + optimize, fingerprint,
+qfn lookup), ``exec.stage.queue_ms`` (submit →
 dequeue/gather), ``exec.stage.coalesce_ms`` (gather → batch launch),
 ``exec.stage.admission_ms``, ``exec.stage.dispatch_ms`` (launch → outputs
 dispatched), ``exec.stage.ready_ms`` (dispatch → buffers materialized) —
@@ -477,6 +479,7 @@ class QueryScheduler:
         incident — nothing is enqueued."""
         from .. import sql as sql_fe
         from ..plan import ir as plan_ir
+        t_entry = time.monotonic()
         tree = sql_fe.sql_to_plan(text, schemas, params)  # SqlError here
         fp = plan_ir.fingerprint(tree)
         key = (fp, tuple(sorted((t, tuple(c)) for t, c in schemas.items())))
@@ -494,9 +497,16 @@ class QueryScheduler:
         if metrics.recording():
             metrics.count("sql.submitted")
         flight.record("sql.submit", fingerprint=fp, chars=len(text))
-        return self.submit(fp, qfn, tables, loader=loader,
-                           priority=priority, timeout_s=timeout_s,
-                           nbytes=nbytes)
+        # the front end's stage: SQL memo or parse + bind + optimize,
+        # fingerprint, qfn lookup — everything before the queue
+        frontend_s = time.monotonic() - t_entry
+        if metrics.recording():
+            metrics.observe("exec.stage.frontend_ms", frontend_s * 1e3)
+        ticket = self.submit(fp, qfn, tables, loader=loader,
+                             priority=priority, timeout_s=timeout_s,
+                             nbytes=nbytes)
+        ticket.timings["frontend_s"] = frontend_s
+        return ticket
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -932,10 +942,11 @@ class QueryScheduler:
         try:
             with grant, structured_log.bound(batch_rids=",".join(rids)):
                 scope = mbudget.query_budget(
-                    name, batched=len(batch),
+                    name, batched=len(batch), rid=batch[0].rid,
                     device=rep.name if self.n_devices > 1 else None) \
                     if mbudget.enabled() \
-                    else metrics.span(f"query:{name}", batched=len(batch))
+                    else metrics.span(f"query:{name}", rid=batch[0].rid,
+                                      batched=len(batch))
                 with scope, metrics.span("batch", size=len(batch),
                                          members=",".join(rids)), \
                         rep.scope(pin_device=self.n_devices > 1):
@@ -1078,10 +1089,10 @@ class QueryScheduler:
                 # per-request overhead off the serving hot path
                 scope = mbudget.query_budget(
                     req.name, queue_wait_ms=round(queue_wait * 1e3, 3),
-                    degraded=grant.degrade,
+                    degraded=grant.degrade, rid=req.rid,
                     device=rep.name if self.n_devices > 1 else None) \
                     if mbudget.enabled() \
-                    else metrics.span(f"query:{req.name}",
+                    else metrics.span(f"query:{req.name}", rid=req.rid,
                                       degraded=grant.degrade)
                 with ctx, scope, \
                         rep.scope(pin_device=self.n_devices > 1):

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..column import Table
+from ..utils import metrics
 
 COLUMNS = ["l_quantity", "l_extendedprice", "l_discount", "l_shipdate"]
 
@@ -41,9 +42,15 @@ def q6_kernel(quantity, extendedprice, discount, shipdate,
 def run(file_bytes: bytes, date_lo_days: int, date_hi_days: int):
     """Scan a lineitem parquet file and compute Q6 revenue on device."""
     from ..parquet import device_scan
-    table = device_scan.scan_table(file_bytes, columns=COLUMNS)
-    q, ep, disc, ship = (table[i].values() for i in range(4))
-    revenue, matched = q6_kernel(q, ep, disc, ship,
-                                 jnp.int32(date_lo_days),
-                                 jnp.int32(date_hi_days))
-    return float(revenue), int(matched)
+    with metrics.span("q6.run", file_bytes=len(file_bytes)) as sp:
+        table = device_scan.scan_table(file_bytes, columns=COLUMNS)
+        # dispatch, then the host's wait for upload + decode + kernel
+        with metrics.span("q6.answer"):
+            q, ep, disc, ship = (table[i].values() for i in range(4))
+            revenue, matched = q6_kernel(q, ep, disc, ship,
+                                         jnp.int32(date_lo_days),
+                                         jnp.int32(date_hi_days))
+            answer = float(revenue), int(matched)
+        if sp is not None:
+            sp.annotate(rows=table.num_rows)
+    return answer

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import functools
 import struct as _struct
+import time
 from typing import Optional
 
 import jax
@@ -63,8 +64,36 @@ def _resolve_args(args):
     return tuple(staging.resolve(a) for a in args)
 
 
+class _WalkStats:
+    """What one column's page walk read, summed in plain fields page by
+    page and written once: onto the walk's span and into the counters the
+    host decoder keeps for the same things (``decode.py``)."""
+
+    __slots__ = ("pages_data", "pages_dict", "compressed", "uncompressed",
+                 "decompress_s")
+
+    def __init__(self):
+        self.pages_data = self.pages_dict = 0
+        self.compressed = self.uncompressed = 0
+        self.decompress_s = 0.0
+
+    def write(self, sp) -> None:
+        ms = self.decompress_s * 1e3
+        sp.annotate(pages=self.pages_data + self.pages_dict,
+                    bytes_compressed=self.compressed,
+                    bytes_uncompressed=self.uncompressed,
+                    decompress_ms=round(ms, 3))
+        if self.pages_data:
+            metrics.count("parquet.pages.data", self.pages_data)
+        if self.pages_dict:
+            metrics.count("parquet.pages.dict", self.pages_dict)
+        metrics.count("parquet.bytes.compressed", self.compressed)
+        metrics.count("parquet.bytes.uncompressed", self.uncompressed)
+        metrics.count("parquet.decompress_ms", ms)
+
+
 def _walk_chunk_raw(file_bytes: bytes, chunk, max_def: int, max_rep: int,
-                    type_len: int = 0):
+                    type_len: int = 0, stats: Optional[_WalkStats] = None):
     """Page walk that KEEPS raw PLAIN payload bytes (or dictionary+index
     run plans) instead of decoding values.  Returns None when the chunk
     needs the host decoder (unsupported physical type / encoding /
@@ -77,7 +106,8 @@ def _walk_chunk_raw(file_bytes: bytes, chunk, max_def: int, max_rep: int,
     present-value total the payload slicing needs.  FIXED_LEN_BYTE_ARRAY
     chunks (width ≤ 16 — the parquet DECIMAL carrier) are fixed-width
     too: their payload is kept raw and assembled into decimal limbs on
-    device."""
+    device.  ``stats`` (metrics recording) takes the chunk's pages, bytes
+    and decompression time."""
     from . import rle_device as RLE
     md = chunk.get(D.CC.META_DATA)
     phys = md.get(D.CMD.TYPE)
@@ -98,6 +128,16 @@ def _walk_chunk_raw(file_bytes: bytes, chunk, max_def: int, max_rep: int,
         start = dict_off
     total = md.get(D.CMD.TOTAL_COMPRESSED_SIZE)
     stream = D._PageStream(file_bytes[start:start + total], codec)
+    if stats is not None:
+        stats.compressed += total
+
+    def _inflate(buf: bytes, size: int) -> bytes:
+        if stats is None:
+            return D._decompress(buf, codec, size)
+        t0 = time.perf_counter()
+        out = D._decompress(buf, codec, size)
+        stats.decompress_s += time.perf_counter() - t0
+        return out
 
     # def-level streams expand on device only when the whole expansion is
     # a bit test (flat optional column, max_def == 1) and no host stage
@@ -126,12 +166,16 @@ def _walk_chunk_raw(file_bytes: bytes, chunk, max_def: int, max_rep: int,
         header, raw = stream.next_page()
         ptype = header.get(D.PH.TYPE)
         usize = header.get(D.PH.UNCOMPRESSED_SIZE)
-        if metrics.recording() and ptype in (D.PAGE_DATA, D.PAGE_DICTIONARY):
-            metrics.count("parquet.pages.dict" if ptype == D.PAGE_DICTIONARY
-                          else "parquet.pages.data")
+        if stats is not None and ptype in (D.PAGE_DATA, D.PAGE_DATA_V2,
+                                           D.PAGE_DICTIONARY):
+            if ptype == D.PAGE_DICTIONARY:
+                stats.pages_dict += 1
+            else:
+                stats.pages_data += 1
+            stats.uncompressed += usize or 0
         if ptype == D.PAGE_DICTIONARY:
             dph = header.get(D.PH.DICT_PAGE)
-            data = D._decompress(raw, codec, usize)
+            data = _inflate(raw, usize)
             m = dph.get(D.DPH.NUM_VALUES)
             if is_bool:
                 return None
@@ -154,7 +198,7 @@ def _walk_chunk_raw(file_bytes: bytes, chunk, max_def: int, max_rep: int,
             dph = header.get(D.PH.DATA_PAGE)
             n = dph.get(D.DPH.NUM_VALUES)
             enc = dph.get(D.DPH.ENCODING)
-            data = D._decompress(raw, codec, usize)
+            data = _inflate(raw, usize)
             pos = 0
             dentry, n_present = None, n
             if max_def > 0:
@@ -170,7 +214,7 @@ def _walk_chunk_raw(file_bytes: bytes, chunk, max_def: int, max_rep: int,
             dl_len = dph.get(D.DPH2.DEF_LEVELS_BYTE_LENGTH, 0)
             body = raw[dl_len:]
             if dph.get(D.DPH2.IS_COMPRESSED, True):
-                body = D._decompress(body, codec, usize - dl_len)
+                body = _inflate(body, usize - dl_len)
             dentry, n_present = None, n
             if max_def > 0 and dl_len:
                 dentry, n_present = _levels(raw[:dl_len], n)
@@ -838,18 +882,27 @@ def scan_column_device(file_bytes: bytes, chunks, leaf) -> Optional[Column]:
     return assemble(_BUILDERS[key](statics, _resolve_args(args)))
 
 
-def _walk_column(file_bytes: bytes, chunks, leaf):
+def _walk_column(file_bytes: bytes, chunks, leaf, parent=None):
     """Host page walk for every chunk of one column — pure host work (no
     device calls), the producer half of the staged scan pipeline.
-    None → host fallback."""
-    parts = []
-    for chunk in chunks:
-        part = _walk_chunk_raw(file_bytes, chunk, leaf.max_def, leaf.max_rep,
-                               leaf.type_len or 0)
-        if part is None:
-            return None
-        parts.append(part)
-    return parts
+    None → host fallback.  ``parent`` is the scan's span where the walk
+    runs on another thread than the scan."""
+    with metrics.span("parquet.scan.walk", parent=parent,
+                      column=leaf.name) as sp:
+        stats = None if sp is None else _WalkStats()
+        try:
+            parts = []
+            for chunk in chunks:
+                part = _walk_chunk_raw(file_bytes, chunk, leaf.max_def,
+                                       leaf.max_rep, leaf.type_len or 0,
+                                       stats)
+                if part is None:
+                    return None
+                parts.append(part)
+            return parts
+        finally:
+            if stats is not None:
+                stats.write(sp)
 
 
 def stage_column_device(file_bytes: bytes, chunks, leaf, stager=None):
@@ -867,6 +920,15 @@ def stage_column_device(file_bytes: bytes, chunks, leaf, stager=None):
 
 def _stage_column_parts(parts, leaf, stager=None):
     """Device staging from walked raw parts (the consumer half)."""
+    with metrics.span("parquet.scan.stage", column=leaf.name) as sp:
+        queued = stager.queued_bytes if stager is not None else 0
+        spec = _stage_parts(parts, leaf, stager)
+        if sp is not None and stager is not None:
+            sp.annotate(bytes=stager.queued_bytes - queued)
+    return spec
+
+
+def _stage_parts(parts, leaf, stager=None):
     kinds = {p[0] for p in parts}
     physes = {p[1] for p in parts}
     if len(kinds) > 1 or len(physes) > 1:
@@ -1124,6 +1186,51 @@ def _prune_row_groups(groups_list, leaves, names, conds):
     return kept
 
 
+def _decode_deferred(deferred) -> dict:
+    """The fused decode of a file's device-path columns: arena admission,
+    one dispatch of ``_decode_file_jit`` (its single-use input slabs
+    donated where the backend takes donations), and each column's
+    assemble.  ``deferred`` is ``(col index, key, statics, resolved args,
+    assemble)``; returns ``{col index: Column}``."""
+    # admission for the fused scan's staged input slabs (the decode
+    # outputs are the table itself — not ephemeral — so only the raw
+    # page/dictionary buffers are reserved)
+    from ..memory import arena
+    scan_bytes = sum(int(getattr(a, "nbytes", 0) or 0)
+                     for _, _, _, args, _ in deferred for a in args)
+    with arena.reserve(scan_bytes, tag="parquet.scan"):
+        if staging.donate_enabled():
+            plan = tuple((key, statics, _DONATE[key][:len(args)])
+                         for _, key, statics, args, _ in deferred)
+            don, keep = [], []
+            for _, key, _, args, _ in deferred:
+                for a, m in zip(args, _DONATE[key][:len(args)]):
+                    (don if m else keep).append(a)
+            don_bytes = sum(int(getattr(a, "nbytes", 0) or 0)
+                            for a in don)
+            flight.record("parquet.scan.donate", buffers=len(don),
+                          bytes=don_bytes)
+            if metrics.recording():
+                metrics.count("parquet.scan.donated_bytes", don_bytes)
+                metrics.annotate(donated_bytes=don_bytes)
+            import warnings
+            with warnings.catch_warnings():
+                # CPU PJRT ignores donation with a warning — forcing
+                # the knob there is a test mode, keep it quiet
+                warnings.filterwarnings("ignore",
+                                        message=".*[Dd]onat.*")
+                outs = _decode_file_jit_donated(plan, tuple(don),
+                                                tuple(keep))
+        else:
+            plan = tuple((key, statics, len(args))
+                         for _, key, statics, args, _ in deferred)
+            flat = tuple(a for _, _, _, args, _ in deferred
+                         for a in args)
+            outs = _decode_file_jit(plan, flat)
+    return {i: assemble(out)
+            for (i, _, _, _, assemble), out in zip(deferred, outs)}
+
+
 def _span_overlap_ms(a_spans, b_spans) -> float:
     """Σ pairwise intersection of two interval lists, in milliseconds —
     how long the host page walk ran concurrently with device staging."""
@@ -1166,37 +1273,39 @@ def scan_table(file_bytes: bytes,
     and prune rows before anything uploads or decodes (``parquet.
     rowfilter``).  The result table carries ``fused_filter_complete``
     so the planner knows whether a re-apply is still needed."""
-    import os
-    meta = parse_struct(extract_footer_bytes(file_bytes))
-    leaves = D._leaf_schema_elements(meta)
-    names = [leaf.name for leaf in leaves]
-    want = list(range(len(leaves))) if columns is None else [
-        names.index(c) for c in columns]
-    groups = meta.get(D.FMD.ROW_GROUPS)
-    groups_list = list(groups.values)
-    kept = (list(range(len(groups_list))) if row_groups is None
-            else sorted(set(row_groups)))
-    if rowgroup_predicate:
-        stat_kept = set(_prune_row_groups(groups_list, leaves, names,
-                                          rowgroup_predicate))
-        pruned = [gi for gi in kept if gi not in stat_kept]
-        kept = [gi for gi in kept if gi in stat_kept]
-        if metrics.recording():
-            metrics.count("plan.scan.rowgroups_pruned", len(pruned))
-            metrics.count("plan.scan.rowgroups_kept", len(kept))
-        metrics.profile_op("scan.prune", rowgroups_pruned=len(pruned),
-                           rowgroups_kept=len(kept))
-    selecting = len(kept) < len(groups_list)
+    with metrics.span("parquet.scan.footer") as sp:
+        meta = parse_struct(extract_footer_bytes(file_bytes))
+        leaves = D._leaf_schema_elements(meta)
+        names = [leaf.name for leaf in leaves]
+        want = list(range(len(leaves))) if columns is None else [
+            names.index(c) for c in columns]
+        groups = meta.get(D.FMD.ROW_GROUPS)
+        groups_list = list(groups.values)
+        kept = (list(range(len(groups_list))) if row_groups is None
+                else sorted(set(row_groups)))
+        if rowgroup_predicate:
+            stat_kept = set(_prune_row_groups(groups_list, leaves, names,
+                                              rowgroup_predicate))
+            pruned = [gi for gi in kept if gi not in stat_kept]
+            kept = [gi for gi in kept if gi in stat_kept]
+            if metrics.recording():
+                metrics.count("plan.scan.rowgroups_pruned", len(pruned))
+                metrics.count("plan.scan.rowgroups_kept", len(kept))
+            metrics.profile_op("scan.prune", rowgroups_pruned=len(pruned),
+                               rowgroups_kept=len(kept))
+        selecting = len(kept) < len(groups_list)
+        chunk_lists = {i: [] for i in want}
+        for gi in kept:
+            chunks = groups_list[gi].get(D.RG.COLUMNS).values
+            for i in want:
+                chunk_lists[i].append(chunks[i])
+        if sp is not None:
+            sp.annotate(row_groups=len(kept), columns=len(want))
     if not kept:
         # every row group pruned: zero-row table via the host assembler
         return D.read_table(
             file_bytes, row_groups=[],
             columns=None if columns is None else [names[i] for i in want])
-    chunk_lists = {i: [] for i in want}
-    for gi in kept:
-        chunks = groups_list[gi].get(D.RG.COLUMNS).values
-        for i in want:
-            chunk_lists[i].append(chunks[i])
 
     fused = knobs.get("SRJT_FUSED_SCAN")
     stager = staging.SlabStager() if staging.enabled() else None
@@ -1248,17 +1357,18 @@ def scan_table(file_bytes: bytes,
     elif pipelined:
         import queue as _qmod
         import threading
-        import time
         depth = max(1, int(knobs.get("SRJT_STAGE_PIPELINE_DEPTH") or 2))
         ch: _qmod.Queue = _qmod.Queue(maxsize=depth)
         walk_spans: list[tuple[float, float]] = []
+        # the walker's spans hang under this call's, and carry its id
+        scan_span = metrics.current_span()
 
         def _producer():
             try:
                 for i in want:
                     t0 = time.perf_counter()
                     parts = _walk_column(file_bytes, chunk_lists[i],
-                                         leaves[i])
+                                         leaves[i], parent=scan_span)
                     walk_spans.append((t0, time.perf_counter()))
                     ch.put((i, parts))
             except BaseException as exc:   # re-raised by the consumer
@@ -1270,7 +1380,9 @@ def scan_table(file_bytes: bytes,
         th.start()
         try:
             for _ in want:
-                i, parts = ch.get()
+                # the walk that the pipeline did not hide
+                with metrics.span("parquet.scan.walk_wait"):
+                    i, parts = ch.get()
                 if i is None:
                     raise parts
                 t0 = time.perf_counter()
@@ -1296,47 +1408,17 @@ def scan_table(file_bytes: bytes,
         for i in want:
             _dispatch(i, stage_column_device(file_bytes, chunk_lists[i],
                                              leaves[i], stager))
-    if stager is not None:
-        stager.flush()                 # file-wide slab wave (async)
-    if deferred:
+    with metrics.span("parquet.scan.upload") as sp:
+        if stager is not None:
+            stager.flush()             # file-wide slab wave (async)
         deferred = [(i, key, statics, _resolve_args(args), assemble)
                     for i, key, statics, args, assemble in deferred]
-        # admission for the fused scan's staged input slabs (the decode
-        # outputs are the table itself — not ephemeral — so only the raw
-        # page/dictionary buffers are reserved)
-        from ..memory import arena
-        scan_bytes = sum(int(getattr(a, "nbytes", 0) or 0)
-                         for _, _, _, args, _ in deferred for a in args)
-        with arena.reserve(scan_bytes, tag="parquet.scan"):
-            if staging.donate_enabled():
-                plan = tuple((key, statics, _DONATE[key][:len(args)])
-                             for _, key, statics, args, _ in deferred)
-                don, keep = [], []
-                for _, key, _, args, _ in deferred:
-                    for a, m in zip(args, _DONATE[key][:len(args)]):
-                        (don if m else keep).append(a)
-                don_bytes = sum(int(getattr(a, "nbytes", 0) or 0)
-                                for a in don)
-                flight.record("parquet.scan.donate", buffers=len(don),
-                              bytes=don_bytes)
-                if metrics.recording():
-                    metrics.count("parquet.scan.donated_bytes", don_bytes)
-                import warnings
-                with warnings.catch_warnings():
-                    # CPU PJRT ignores donation with a warning — forcing
-                    # the knob there is a test mode, keep it quiet
-                    warnings.filterwarnings("ignore",
-                                            message=".*[Dd]onat.*")
-                    outs = _decode_file_jit_donated(plan, tuple(don),
-                                                    tuple(keep))
-            else:
-                plan = tuple((key, statics, len(args))
-                             for _, key, statics, args, _ in deferred)
-                flat = tuple(a for _, _, _, args, _ in deferred
-                             for a in args)
-                outs = _decode_file_jit(plan, flat)
-        for (i, _, _, _, assemble), out in zip(deferred, outs):
-            by_index[i] = assemble(out)
+        if sp is not None and stager is not None:
+            sp.annotate(bytes=stager.slab_bytes, transfers=stager.transfers,
+                        pack_ms=round(stager.pack_s * 1e3, 3))
+    if deferred:
+        with metrics.span("parquet.scan.decode"):
+            by_index.update(_decode_deferred(deferred))
     if metrics.recording():
         # device/host split per scan — the fast-path coverage counter
         metrics.count("parquet.device_cols", len(want) - len(fallback))

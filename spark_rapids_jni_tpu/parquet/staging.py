@@ -23,7 +23,9 @@ ONE cudaMemcpyAsync per slab so the copy engine streams at link rate
 
 Metrics: ``parquet.stage.slab_bytes`` / ``parquet.stage.transfers`` /
 ``parquet.stage.buffers`` per flush; the flight recorder keeps a
-``parquet.stage.flush`` breadcrumb per slab wave.
+``parquet.stage.flush`` breadcrumb per slab wave.  The scan's
+``parquet.scan.upload`` span takes the stager's bytes, transfers and the
+time its slab packing took (``pack_s``) as attributes.
 
 ``SRJT_STAGE_SLABS=0`` reverts every call site to the old per-buffer
 ``jnp.asarray`` uploads (the differential-testing baseline).
@@ -31,6 +33,7 @@ Metrics: ``parquet.stage.slab_bytes`` / ``parquet.stage.transfers`` /
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import jax
@@ -87,6 +90,8 @@ class SlabStager:
         self.slab_bytes = 0          # lifetime bytes shipped via slabs
         self.transfers = 0           # lifetime device_put count
         self.buffers = 0             # lifetime queued-buffer count
+        self.queued_bytes = 0        # lifetime bytes queued by add()
+        self.pack_s = 0.0            # lifetime seconds packing slabs
 
     # -- queueing ------------------------------------------------------------
     def add(self, arr: np.ndarray) -> Handle:
@@ -100,6 +105,7 @@ class SlabStager:
             return h
         self._pending.append(h)
         self.buffers += 1
+        self.queued_bytes += arr.nbytes
         return h
 
     def asarray(self, arr: np.ndarray) -> Handle:
@@ -149,7 +155,9 @@ class SlabStager:
             h._dev = jax.device_put(h._arr)
             h._arr = None
             return 1
+        t0 = time.perf_counter()
         slab = np.concatenate([h._arr.reshape(-1) for h in wave])
+        self.pack_s += time.perf_counter() - t0
         dev = jax.device_put(slab)       # ONE transfer, non-blocking
         pos = 0
         for h in wave:

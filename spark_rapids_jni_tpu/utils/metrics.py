@@ -5,10 +5,12 @@ an RMM logging level through the build (SURVEY §5.5); this module is the
 query-level half of that story the TPU rebuild was missing: counters
 (join-engine choice, build-index cache hits, tape lengths, pages decoded,
 bytes shuffled), gauges (HBM live-byte watermarks), histograms (expansion
-pair totals), and a per-query SPAN TREE that upgrades the flat
-``tracing.func_range`` wall-time events into a parent/child stage
-hierarchy exportable as Chrome-trace JSON (``chrome://tracing`` /
-Perfetto-loadable) and as a structured summary dict.
+pair totals), and a per-query SPAN TREE: a parent/child stage hierarchy
+exportable as Chrome-trace JSON (``chrome://tracing`` / Perfetto-loadable)
+and as a structured summary dict.  Every span also opens a
+``jax.profiler.TraceAnnotation`` named ``srjt:<name>``, so a profile
+holds the same spans on the host plane, on the clock of the device
+plane's ``XLA Ops`` (``utils.tracing.traced`` entries are spans too).
 
 Knobs
 -----
@@ -37,11 +39,14 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import json
 import os
 import re
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from ..analysis import sanitize
 from . import knobs
@@ -62,8 +67,15 @@ _samples: dict[str, "collections.deque[tuple[float, float]]"] = {}
 
 _EPOCH = time.perf_counter()        # trace time base (ts exported rel. us)
 
+# every span's name on the profiler (``tracing.func_range`` too): one rule
+# for whoever reads a profile
+PREFIX = "srjt:"
+_ROOTS_MAX = 4096                   # completed root trees kept (newest)
+
 _tls = threading.local()            # per-thread open-span stack
-_roots: list["Span"] = []           # completed root spans (all threads)
+# completed root spans (all threads)
+_roots: "collections.deque[Span]" = collections.deque(maxlen=_ROOTS_MAX)
+_rids = itertools.count(1)          # request ids of roots not given one
 
 # compile-cost ledger: plan fingerprint → summed cost fields (capture_ms,
 # trace_ms, traces, first_dispatch_ms, runs, cache_hits, ...) — the
@@ -275,17 +287,28 @@ def percentile(name: str, q: float,
 
 
 class Span:
-    """One timed range; completed children hang off ``children``."""
+    """One timed range; completed children hang off ``children``.
 
-    __slots__ = ("name", "attrs", "t0", "dur", "tid", "children")
+    ``parent`` is the span this one hangs under: the innermost open span
+    of the entering thread, or the one passed in — the way a span opened
+    on another thread joins the call that caused it.  ``rid`` is the
+    request id every span of one call shares: a root draws a fresh one
+    unless given one, everything under it inherits."""
 
-    def __init__(self, name: str, attrs: dict):
+    __slots__ = ("name", "attrs", "t0", "dur", "tid", "children", "parent",
+                 "rid", "_note")
+
+    def __init__(self, name: str, attrs: dict,
+                 parent: Optional["Span"] = None, rid=None):
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0           # seconds since _EPOCH, set on __enter__
         self.dur = 0.0          # seconds
         self.tid = 0
         self.children: list[Span] = []
+        self.parent = parent
+        self.rid = rid
+        self._note = None
 
     def annotate(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -294,24 +317,35 @@ class Span:
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
+        if self.parent is None and stack:
+            self.parent = stack[-1]
+        if self.rid is None:
+            self.rid = (self.parent.rid if self.parent is not None
+                        else next(_rids))
         stack.append(self)
         self.tid = threading.get_ident()
+        self._note = TraceAnnotation(PREFIX + self.name, rid=self.rid)
+        self._note.__enter__()
         self.t0 = time.perf_counter() - _EPOCH
         return self
 
     def __exit__(self, *exc) -> None:
         self.dur = (time.perf_counter() - _EPOCH) - self.t0
-        stack = _tls.stack
-        stack.pop()
-        if stack:
-            stack[-1].children.append(self)
-        else:
+        self._note.__exit__(*exc)
+        self._note = None
+        _tls.stack.pop()
+        if self.parent is None:
             with _lock:
                 _roots.append(self)
+        else:
+            # also from another thread than the parent's: one list.append
+            # is atomic under the interpreter lock
+            self.parent.children.append(self)
 
     def as_dict(self) -> dict:
         d = {"name": self.name, "start_ms": round(self.t0 * 1e3, 3),
-             "dur_ms": round(self.dur * 1e3, 3)}
+             "dur_ms": round(self.dur * 1e3, 3), "tid": self.tid,
+             "rid": self.rid}
         if self.attrs:
             d["attrs"] = dict(self.attrs)
         if self.children:
@@ -322,13 +356,15 @@ class Span:
 _NOOP = contextlib.nullcontext()
 
 
-def span(name: str, **attrs):
+def span(name: str, *, parent: Optional[Span] = None, rid=None, **attrs):
     """Context manager recording a span under the current thread's open
-    span (or as a new root).  Returns a shared no-op context when disabled
-    or under a replay trace — zero allocation on the hot path."""
+    span, under ``parent`` where one is passed (a span opened on another
+    thread than its cause), or as a new root with request id ``rid``.
+    Returns a shared no-op context when disabled or under a replay trace —
+    zero allocation on the hot path."""
     if not recording():
         return _NOOP
-    return Span(name, attrs)
+    return Span(name, attrs, parent, rid)
 
 
 def current_span() -> Optional[Span]:
@@ -437,10 +473,16 @@ def snapshot() -> dict:
                 "ledger": {k: dict(v) for k, v in _ledger.items()}}
 
 
-def span_roots() -> list[dict]:
-    """Completed root span trees (dict form), in completion order."""
+def span_roots(window_s: Optional[float] = None) -> list[dict]:
+    """Completed root span trees (dict form), in completion order: the
+    newest ``_ROOTS_MAX`` of them, or of those the ones that began in the
+    last ``window_s`` seconds."""
     with _lock:
-        return [s.as_dict() for s in _roots]
+        roots = list(_roots)
+    if window_s is not None:
+        cutoff = (time.perf_counter() - _EPOCH) - max(float(window_s), 0.0)
+        roots = [s for s in roots if s.t0 >= cutoff]
+    return [s.as_dict() for s in roots]
 
 
 def _walk(spans, fn):

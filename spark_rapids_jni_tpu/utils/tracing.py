@@ -11,12 +11,13 @@ runtime: :func:`set_enabled` flips it (parity with
 ``structured_log.configure`` — tests and the hot knob need the toggle
 without a process restart).
 
-``@traced`` entries additionally feed two sinks when their knobs are on:
-
-* ``utils.structured_log`` — one event record with wall-time duration per
-  call (the RMM-logging/spdlog analog);
-* ``utils.metrics`` — one span in the per-query span tree (the NVTX range
-  upgraded into a hierarchy; see ``utils/metrics.py``).
+A ``@traced`` entry opens ONE range per call.  With metrics on it is a
+``utils.metrics`` span, which carries the profiler annotation itself; with
+metrics off (the default) it is :func:`func_range`.  Either way the
+profiler sees ``srjt:<name>`` (``metrics.PREFIX``), so whoever reads a
+profile needs one rule.  With ``utils.structured_log`` on, each call also
+emits one event record with its wall-time duration (the RMM-logging/spdlog
+analog).
 """
 
 from __future__ import annotations
@@ -28,6 +29,8 @@ import time
 from typing import Optional
 
 import jax
+
+from . import metrics
 
 
 def _read_env() -> bool:
@@ -49,11 +52,13 @@ def set_enabled(on: Optional[bool] = None) -> None:
 
 @contextlib.contextmanager
 def func_range(name: str):
-    """NVTX-range analog: a named scope visible in HLO and xprof traces."""
+    """NVTX-range analog: a named scope visible in HLO, and the annotation
+    ``srjt:<name>`` in xprof traces."""
     if not _ENABLED:
         yield
         return
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+    with jax.named_scope(name), \
+            jax.profiler.TraceAnnotation(metrics.PREFIX + name):
         yield
 
 
@@ -64,14 +69,15 @@ def traced(name: str | None = None):
     ``utils.structured_log``): when enabled, each call emits one event
     record with wall-time duration — the RMM-logging/spdlog analog.
     With metrics on (``SPARK_RAPIDS_TPU_METRICS``, ``utils.metrics``),
-    each call records one span in the current span tree."""
+    the call's range is one span in the current span tree, and the span
+    opens the annotation; the HLO scope stays as :func:`func_range` has
+    it, so a compiled program is the same whichever recorder is on."""
 
     def wrap(fn):
         scope = name or fn.__qualname__
 
         @functools.wraps(fn)
         def inner(*args, **kwargs):
-            from . import metrics
             from . import structured_log as slog
             rec = metrics.recording()
             log = slog.enabled()
@@ -79,9 +85,14 @@ def traced(name: str | None = None):
                 with func_range(scope):
                     return fn(*args, **kwargs)
             t0 = time.perf_counter()
-            ctx = metrics.span(scope) if rec else contextlib.nullcontext()
-            with ctx, func_range(scope):
-                out = fn(*args, **kwargs)
+            if rec:
+                hlo = (jax.named_scope(scope) if _ENABLED
+                       else contextlib.nullcontext())
+                with metrics.span(scope), hlo:
+                    out = fn(*args, **kwargs)
+            else:
+                with func_range(scope):
+                    out = fn(*args, **kwargs)
             if log:
                 slog.event(scope, duration_s=time.perf_counter() - t0)
             return out
