@@ -8,8 +8,15 @@ planner-selected build-side index (``ops.join_plan``):
   keys) a ``(span,)`` CSR lookup table turns each probe into one gather;
   with unique build keys the pair-expansion step is skipped entirely.
 * **sort-probe** — the fallback for sparse/float/string keys: sort the
-  build side once, binary-search every probe key (``searchsorted`` lowers
-  to a vectorized compare tree).
+  build side once, then find each probe key's run of equal build keys.
+
+``join_plan.probe_counts`` probes in one of three ways, picked from the
+index it is handed: ``dense`` — two gathers from the CSR window (a dense
+index); ``compare`` — count the build keys below / equal to each probe
+key, a fused compare-and-sum with no gather (a sorted index of at most
+``join_plan.COMPARE_PROBE_MAX_KEYS`` keys); ``bsearch`` — two
+``jnp.searchsorted``, each a scan of ``ceil(log2(n+1))`` levels whose
+body is a probe-length gather from the key table (a larger sorted index).
 
 Both index kinds return identical (lo, counts, row_ids) probe results, so
 this module's match-expansion tail — the only dynamically-sized step, its
@@ -36,6 +43,7 @@ from ..column import Column, Table
 from ..memory import arena
 from ..memory.budget import PAIR_EXPANSION_BYTES
 from ..utils import metrics, syncs
+from . import join_plan
 from .filter import gather, sized_nonzero
 
 JoinKey = Union[Column, Sequence[Column]]
@@ -76,8 +84,6 @@ def join_indices(left: JoinKey, right: JoinKey,
 
 
 def _join_indices(lcols: list, rcols: list, how: str):
-    from . import join_plan
-
     # plan the probe lanes (string encode / composite pack / fingerprint),
     # then index the build (right) side — planner-selected layout, memoized
     # on the key buffers' identity; null build keys are dropped outright
@@ -112,6 +118,7 @@ def _join_indices(lcols: list, rcols: list, how: str):
             if metrics.recording():
                 metrics.observe("join.match_rows", total)
             metrics.profile_op("join", engine=ix.kind, how=how,
+                               probe=join_plan.probe_kind(ix),
                                match_rows=total, unique_build=True)
             left_idx = sized_nonzero(counts > 0, total)
             right_idx = ix.row_ids[pos[left_idx]]
@@ -140,7 +147,8 @@ def _join_indices(lcols: list, rcols: list, how: str):
                         total if matched_rows is None else matched_rows)
         metrics.annotate(expand_pairs=total)
     metrics.profile_op(
-        "join", engine=ix.kind, how=how, expand_pairs=total,
+        "join", engine=ix.kind, how=how, probe=join_plan.probe_kind(ix),
+        expand_pairs=total,
         match_rows=total if matched_rows is None else matched_rows)
     # admission-control the ephemeral expansion working set (the int64
     # lanes + mask below) before XLA materializes it; under pressure this
@@ -204,6 +212,7 @@ def _verified_join(plan, ix, lo, counts, how: str):
         if how in ("inner", "left"):
             metrics.observe("join.match_rows", kept)
     metrics.profile_op("join", engine=ix.kind, how=how,
+                       probe=join_plan.probe_kind(ix),
                        candidates=int(li.shape[0]), match_rows=kept)
     sel = sized_nonzero(eq, kept)
     li, ri = li[sel], ri[sel]

@@ -16,13 +16,26 @@ bit-identical indices:
   ``span <= max(DENSE_SPAN_FACTOR * n_valid, DENSE_SPAN_FLOOR)`` and
   ``span <= DENSE_SPAN_CAP``.  A ``(span,)`` CSR lookup table
   (slot → start offset + run length into key-sorted ``row_ids``) is
-  materialized once; probing is one subtract + clip + two gathers —
-  no ``searchsorted`` compare tree.  TPC-DS surrogate keys are contiguous
-  integers, so the star joins all take this path.  When every slot holds
-  at most one build row (``unique``) the index is built by direct scatter
-  (no sort at all) and ``ops.join`` skips pair expansion entirely.
-* **sorted** — the fallback for sparse/float/string/128-bit keys: the
-  original sort-probe (stable key sort + two ``searchsorted``).
+  materialized once; probing is one subtract + clip + two gathers
+  (``join.probe.dense``).  TPC-DS surrogate keys are contiguous
+  integers, so unfiltered star joins take this path.  When every slot
+  holds at most one build row (``unique``) the index is built by direct
+  scatter (no sort at all) and ``ops.join`` skips pair expansion entirely.
+* **sorted** — the fallback for sparse/float/string/128-bit keys, and
+  for a filtered dimension whose few keys span many ids: a stable key
+  sort, probed one of two ways by the build's size, which the index
+  carries (:func:`probe_kind`).  At most ``COMPARE_PROBE_MAX_KEYS`` keys:
+  count the keys below / not above each probe key, a compare fused into a
+  sum over the key axis, no gather (``join.probe.compare``).  More: two
+  ``jnp.searchsorted``, each a scan of ``ceil(log2(n+1))`` levels whose
+  body gathers once per probe row from the key table
+  (``join.probe.bsearch``) — on the chip a gather costs the same 8.6 ns a
+  row from a 212-entry table as from a large one.
+
+``join.engine.<kind>`` and ``join.probe.<kind>`` tick where the Python
+runs: once per join in eager execution, and under whole-query replay
+once per plan, at its capture run (a replay re-trace records nothing,
+``utils/metrics.py``), not once per request.
 
 The span bounds (``kmin``/``kmax``), the valid-row count, and the
 uniqueness bit all resolve through ``syncs.scalar``, so the planner's
@@ -69,6 +82,20 @@ from .filter import sized_nonzero
 DENSE_SPAN_FACTOR = 2
 DENSE_SPAN_FLOOR = 4096
 DENSE_SPAN_CAP = 1 << 23
+# A sorted index of at most this many build keys is probed by counting
+# compares, a larger one by binary search (probe_kind).  Per probe row a
+# binary search costs 2 * ceil(log2(n+1)) * g and the count 2 * n * c, with
+# g the ns a row of one gather from the key table and c the ns a key a row
+# of one fused compare-and-add.  On a v5e over a 10M-row int32 probe
+# (tools/probe_rates.py, PERF.md section 6, PR 27): g = 8.6 at 212 keys,
+# 7.54 from 4096 up; c = 0.0011 at 212, 0.00224 from 4096 up.  They meet
+# where n / ceil(log2(n+1)) = 7.54 / 0.00224, n = 53866 at 16 levels; this
+# is the power of two under it (measured there: the count 1470 ms, the
+# search 2414; at 65536 the count loses, 2937 against 2565).
+COMPARE_PROBE_MAX_KEYS = 1 << 15
+# [n_valid, block] cells a backend that does not fuse the compare into the
+# reduction holds at a time in _probe_compare: 64 MiB of int32 a side.
+_UNFUSED_COMPARE_CELLS = 1 << 24
 
 # THREAD-LOCAL: the exec runtime's degraded-admission path pins one
 # request's joins to the low-footprint sorted engine from its worker
@@ -409,11 +436,56 @@ def extend_build_index(ix: BuildIndex, delta_data, delta_valid,
                           new_lo, new_cnt, unique, int(max_run))
 
 
+def _compare_counts(sorted_keys, ldata):
+    """``(lo, hi - lo)`` of the two binary searches, by counting the build
+    keys below / not above each probe key.  ``compare_all`` uses the
+    comparators of the default ``scan`` method, so both results are equal
+    to its everywhere, for every dtype."""
+    lo = jnp.searchsorted(sorted_keys, ldata, side="left",
+                          method="compare_all")
+    hi = jnp.searchsorted(sorted_keys, ldata, side="right",
+                          method="compare_all")
+    return lo, hi - lo
+
+
+@jax.jit
+def _probe_compare(sorted_keys, ldata):
+    """:func:`_compare_counts` in one jit, also when the caller runs
+    eagerly (a plan's capture run).  XLA:TPU fuses the compare into the
+    sum over the key axis, so no ``[n_valid, n_probe]`` array exists
+    (0 temp bytes at 212 keys x 10M rows, tools/probe_rates.py).  XLA:CPU
+    does not fuse into a reduction and would hold two such arrays, so off
+    the TPU the probe axis goes by in blocks of bounded size."""
+    n, m = sorted_keys.shape[0], ldata.shape[0]
+    block = m if jax.default_backend() == "tpu" \
+        else max(_UNFUSED_COMPARE_CELLS // max(n, 1), 1)
+    if m <= block:
+        return _compare_counts(sorted_keys, ldata)
+    nb = -(-m // block)
+    q = jnp.pad(ldata, (0, nb * block - m)).reshape(nb, block)
+    lo, counts = jax.lax.map(
+        lambda qb: _compare_counts(sorted_keys, qb), q)
+    return lo.reshape(-1)[:m], counts.reshape(-1)[:m]
+
+
+def probe_kind(ix: BuildIndex) -> str:
+    """How :func:`probe_counts` probes ``ix``: ``dense`` (two gathers from
+    the CSR window), ``compare`` (a sorted index of at most
+    ``COMPARE_PROBE_MAX_KEYS`` keys) or ``bsearch`` (a larger one)."""
+    if ix.kind == "dense":
+        return "dense"
+    return "compare" if ix.n_valid <= COMPARE_PROBE_MAX_KEYS else "bsearch"
+
+
 def probe_counts(ix: BuildIndex, ldata, lvalid):
     """Per probe row: (first match position into ``ix.row_ids``, match
-    count).  ``lo`` is unspecified where ``counts == 0`` (callers guard,
-    as the sort-probe tail always has)."""
-    if ix.kind == "dense":
+    count).  On a dense index ``lo`` is unspecified where ``counts == 0``
+    (callers guard); on a sorted one it is the insertion point, whichever
+    of the two ways computes it."""
+    kind = probe_kind(ix)
+    if metrics.recording():
+        metrics.count(f"join.probe.{kind}")
+    if kind == "dense":
         d = ldata.astype(jnp.int64) - ix.kmin
         in_r = (d >= 0) & (d < ix.span)
         if lvalid is not None:
@@ -421,9 +493,12 @@ def probe_counts(ix: BuildIndex, ldata, lvalid):
         slot = jnp.clip(d, 0, max(ix.span - 1, 0)).astype(jnp.int32)
         counts = jnp.where(in_r, ix.lut_cnt[slot], 0)
         return ix.lut_lo[slot], counts
-    lo = jnp.searchsorted(ix.sorted_keys, ldata, side="left")
-    hi = jnp.searchsorted(ix.sorted_keys, ldata, side="right")
-    counts = hi - lo
+    if kind == "compare":
+        lo, counts = _probe_compare(ix.sorted_keys, ldata)
+    else:
+        lo = jnp.searchsorted(ix.sorted_keys, ldata, side="left")
+        hi = jnp.searchsorted(ix.sorted_keys, ldata, side="right")
+        counts = hi - lo
     if lvalid is not None:
         counts = jnp.where(lvalid, counts, 0)
     return lo, counts

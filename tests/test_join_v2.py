@@ -620,3 +620,89 @@ def test_decimal128_key_with_nulls_and_collision_scale():
                     for j, b in enumerate(rv)
                     if a is not None and b is not None and a == b)
     assert pairs == expect
+
+
+# ---- the sorted index's two probes ------------------------------------------
+
+
+_C = join_plan.COMPARE_PROBE_MAX_KEYS
+_KEY_DTYPES = {"int32": (np.int32, None), "int64": (np.int64, None),
+               "date": (np.int32, sr.types.timestamp_days)}
+
+
+@pytest.mark.parametrize("key", sorted(_KEY_DTYPES))
+@pytest.mark.parametrize("nulls", [False, True], ids=["nonull", "nulls"])
+@pytest.mark.parametrize("dups", [False, True], ids=["distinct", "dups"])
+@pytest.mark.parametrize("n_valid",
+                         [0, 1, 18, 127, 128, 129, 212, _C, _C + 1])
+def test_sorted_probe_compare_equals_bsearch(n_valid, dups, nulls, key,
+                                             monkeypatch):
+    # a sorted index of at most COMPARE_PROBE_MAX_KEYS keys is probed by
+    # counting compares, a larger one by the two binary searches: same
+    # (lo, counts) everywhere, same join indices as the dense engine
+    import re
+
+    import jax
+    from spark_rapids_jni_tpu.utils import metrics
+
+    npdt, dt = _KEY_DTYPES[key]
+    rng = np.random.default_rng(1000 * n_valid + 2 * dups + nulls)
+    pool = rng.choice(1 << 18, max(n_valid // 3 if dups else n_valid, 1),
+                      replace=False) + 1000
+    rk = (rng.choice(pool, n_valid) if dups else pool[:n_valid]).astype(npdt)
+    lk = np.where(rng.random(1500) < 0.5, rng.choice(pool, 1500),
+                  rng.integers(0, (1 << 18) + 2000, 1500)).astype(npdt)
+    rv = lv = None
+    if nulls:
+        # null build rows carry keys that WOULD match: they must not count
+        rk = np.concatenate([rk, rng.choice(pool, 40).astype(npdt)])
+        rv = np.arange(rk.shape[0]) < n_valid
+        perm = rng.permutation(rk.shape[0])
+        rk, rv = rk[perm], rv[perm]
+        lv = rng.random(1500) < 0.85
+    left, right = int_col(lk, lv, dt), int_col(rk, rv, dt)
+
+    with join_plan.force_engine("sorted"):
+        ix = join_plan.build_index(right.data, right.validity, True)
+    assert (ix.kind, ix.n_valid) == ("sorted", n_valid)
+    want = "compare" if n_valid <= _C else "bsearch"
+    assert join_plan.probe_kind(ix) == want
+
+    metrics.set_enabled(True)
+    metrics.reset()
+    try:
+        lo, counts = join_plan.probe_counts(ix, left.data, left.validity)
+        ticks = {k: metrics.counter_value(f"join.probe.{k}")
+                 for k in ("compare", "bsearch", "dense")}
+    finally:
+        metrics.reset()
+        metrics.set_enabled(None)
+    assert ticks == {k: int(k == want) for k in ticks}
+    lo_ref = jnp.searchsorted(ix.sorted_keys, left.data, side="left")
+    hi_ref = jnp.searchsorted(ix.sorted_keys, left.data, side="right")
+    cnt_ref = hi_ref - lo_ref
+    if lv is not None:
+        cnt_ref = jnp.where(left.validity, cnt_ref, 0)
+    assert (lo.dtype, counts.dtype) == (lo_ref.dtype, cnt_ref.dtype)
+    np.testing.assert_array_equal(np.asarray(lo), np.asarray(lo_ref))
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(cnt_ref))
+
+    # the program the chip gets (off it the probe axis goes by in blocks);
+    # a fresh function each time: a trace is cached on the function
+    probe = (lambda keys, q: join_plan._probe_compare.__wrapped__(keys, q)) \
+        if want == "compare" else lambda keys, q: join_plan.probe_counts(
+            ix._replace(sorted_keys=keys), q, None)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        text = str(jax.make_jaxpr(probe)(ix.sorted_keys, left.data))
+    loops = re.findall(r"\b(?:gather|scan|while)\[", text)
+    assert not loops if want == "compare" else loops
+
+    events = []
+    monkeypatch.setattr(metrics, "_profile_op_hook",
+                        lambda name, **f: events.append((name, f["probe"])))
+    for how in ("inner", "left", "semi", "anti"):
+        _assert_same(*_both_engines(left, right, how))
+    # an empty build has no span to be dense over
+    assert set(events) == {("join", "dense" if n_valid else want),
+                           ("join", want)}

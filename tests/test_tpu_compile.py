@@ -215,6 +215,22 @@ def test_f64_bits_to_values_and_q6(one_chip, as_tpu):
              _s((n,), jnp.int32), _s((), jnp.int32), _s((), jnp.int32))
 
 
+# --- join: q42's probe at the star cell's size ---------------------------------
+
+def test_compare_probe_fuses_at_q42_size(one_chip, as_tpu):
+    # 212 sorted build keys against the 10M-row fact: the compare fuses
+    # into the sum over the key axis, so the program holds no [212, 10M]
+    # array, no loop over levels or blocks, and no gather
+    from spark_rapids_jni_tpu.ops import join_plan
+    # (a fresh function: a trace is cached on the function, backend and all)
+    probe = jax.jit(lambda k, q: join_plan._probe_compare.__wrapped__(k, q))
+    c = _compile(one_chip, probe, _s((212,), jnp.int32),
+                 _s((10_000_000,), jnp.int32))
+    assert c.memory_analysis().temp_size_in_bytes == 0
+    text = c.as_text()
+    assert " while(" not in text and " gather(" not in text
+
+
 # --- four chips: the shuffle step as ONE program across the 2x2 mesh ------------
 
 def test_mesh_shuffle_program_four_chips(topo):
