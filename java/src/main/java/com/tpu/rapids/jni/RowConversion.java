@@ -16,8 +16,9 @@
  *     b*8+i, bytes appended after the last data slot;
  *   - string chars follow the validity bytes; every row is padded to an
  *     8-byte boundary;
- *   - a row may not exceed 1KB, and each output batch stays under 2GB
- *     (int32 offsets), split at 32-row multiples.
+ *   - each output batch stays under 2GB (int32 offsets), split at 32-row
+ *     multiples; a row only has to fit a batch (no 1KB limit, as in the
+ *     reference's convert_to_rows).
  */
 package com.tpu.rapids.jni;
 

@@ -278,11 +278,10 @@ void srjt_table_free(void* h) { delete static_cast<Table*>(h); }
 // ---- table-level transcode (the convertToRows/convertFromRows surface) ----
 
 // Table → ≤2GB JCUDF row batches.  Returns a RowBatches handle, null on
-// unsupported schema or >1KB fixed rows (RowConversion.java:98-99).
+// unsupported schema or a row that does not fit a batch.
 void* srjt_to_rows(void* table_handle) {
   Table& t = *static_cast<Table*>(table_handle);
   Layout L = compute_layout(t);
-  if (L.fixed_only && L.row_size > 1024) return nullptr;
   auto out = new (std::nothrow) RowBatches();
   if (!out) return nullptr;
   auto bounds = batch_bounds(t, L);

@@ -194,8 +194,7 @@ JNIEXPORT jlong JNICALL Java_com_tpu_rapids_jni_RowConversion_convertToRows(
   if (!rows) rows = srjt_to_rows(reinterpret_cast<void*>(table_handle));
   if (!rows)
     THROW_ILLEGAL(env,
-                  "Row size exceeds JCUDF 1KB limit or unsupported schema "
-                  "(RowConversion.java:98-99)");
+                  "Unsupported schema, or a row that does not fit a batch");
   return reinterpret_cast<jlong>(rows);
 }
 

@@ -23,7 +23,8 @@ translation (see SURVEY §7):
   host, then a statically-shaped jitted scatter/gather does the copies.
 * Output is split into ≤2GB batches exactly like ``build_batches``
   (``row_conversion.cu:1460-1539``); ``convert_from_rows`` accepts exactly one
-  batch (``row_conversion.cu:2124-2139``).
+  batch (``row_conversion.cu:2124-2139``).  A row has no size limit of its
+  own (the reference's ``convert_to_rows`` has none): it has to fit a batch.
 
 Dynamic-shape note: everything under ``jit`` here is static-shaped; the only
 host syncs are the same ones the reference performs (string totals).
@@ -46,7 +47,7 @@ from ..faultinj import fault_site
 from ..utils import bitmask, knobs, metrics, syncs
 from ..utils.tracing import traced
 from .layout import (RowLayout, compute_row_layout, build_batches,
-                     row_sizes_with_strings, MAX_ROW_SIZE, MAX_BATCH_BYTES,
+                     row_sizes_with_strings, MAX_BATCH_BYTES,
                      BATCH_ROW_MULTIPLE)
 
 
@@ -284,6 +285,17 @@ def _to_rows_fixed_words(layout: RowLayout, datas: tuple[jnp.ndarray, ...],
               for d, dt in zip(datas, layout.schema)]
     vbytes_w = [padrows(v) for v in _pack_validity_words(layout, valid)]
 
+    words = _compose_row_words(layout, staged, vbytes_w, W, n_pad)
+    flat = _interleave_words(words, W)
+    return flat[:n * W] if n_pad != n else flat
+
+
+def _compose_row_words(layout: RowLayout, staged, vbytes_w, W: int,
+                       n: int) -> list[jnp.ndarray]:
+    """The first ``W`` row words, each a u32 [n] vector OR-ed from its
+    statically planned fragments (:func:`_word_plan`).  ``staged[ci]`` is
+    the column's staged payload; a string column's is its (offset, length)
+    slot as u32 [n, 2], two row words like any 8-byte column."""
     plan = _word_plan(layout)
     words = []
     for w in range(W):
@@ -302,9 +314,8 @@ def _to_rows_fixed_words(layout: RowLayout, datas: tuple[jnp.ndarray, ...],
                     v = x << jnp.uint32(arg * 8)
             acc = v if acc is None else acc | v
         words.append(acc if acc is not None
-                     else jnp.zeros((n_pad,), jnp.uint32))
-    flat = _interleave_words(words, W)
-    return flat[:n * W] if n_pad != n else flat
+                     else jnp.zeros((n,), jnp.uint32))
+    return words
 
 
 def _decode_row_words(layout: RowLayout, word, n: int):
@@ -319,14 +330,22 @@ def _decode_row_words(layout: RowLayout, word, n: int):
     (compute_column_information, ``row_conversion.cu:1331-1370``), so no
     fragment straddles a word.
     """
+    datas, vcols, slots = _decode_row_columns(layout, word, n)
+    return (datas, jnp.stack(vcols, axis=1),
+            tuple(jnp.stack(s, axis=1) for s in slots))
+
+
+def _decode_row_columns(layout: RowLayout, word, n: int):
+    """:func:`_decode_row_words` with nothing stacked: ``(datas, per-column
+    validity vectors, per-variable-column (offset, length) u32 vector
+    pairs)``."""
     datas = []
     slots = []
     for ci, dt in enumerate(layout.schema):
         start = layout.column_starts[ci]
         size = layout.column_sizes[ci]
         if dt.is_variable_width:
-            slots.append(jnp.stack([word(start // 4)[:n],
-                                    word(start // 4 + 1)[:n]], axis=1))
+            slots.append((word(start // 4)[:n], word(start // 4 + 1)[:n]))
             datas.append(None)
             continue
         if size == 16:   # DECIMAL128: four words → [n, 2] int64 lanes
@@ -359,8 +378,7 @@ def _decode_row_words(layout: RowLayout, word, n: int):
         bit = ((word(byte // 4) >> jnp.uint32(8 * (byte % 4) + c % 8))
                & jnp.uint32(1))
         vcols.append(bit.astype(jnp.bool_)[:n])
-    valid = jnp.stack(vcols, axis=1)
-    return tuple(datas), valid, tuple(slots)
+    return tuple(datas), vcols, tuple(slots)
 
 
 # Concat-based fixed compose (round-5 alternate engine, SRJT_FIXED_CONCAT):
@@ -610,12 +628,16 @@ def _var_fixed_region(layout: RowLayout, datas: tuple[jnp.ndarray, ...],
 
 # Above this many string columns the per-column segmented-copy passes (each
 # touching the full char region) lose to the single-pass XLA gather path.
+# The reference benchmark's strings table (15 string columns) is over it,
+# but never gets here: xpack serves it in row tiles (strings155_roundtrip,
+# PR 28: no fallback counted, neither DMA nor gather reached).
 _DMA_MAX_VAR_COLS = 8
 
 # from_rows DMA geometry needs per-row (offset, len) slots on the HOST;
 # above this row count the device-side gather path (which syncs only
 # per-column char totals) is taken instead.  (Threshold set for the old
-# environment, not re-measured — ROADMAP S7.)
+# environment, not re-measured — ROADMAP S7; no cell reaches it: both cells
+# of the transcode are served before the DMA path is asked.)
 _DMA_FROM_ROWS_MAX_N = 1 << 16
 
 
@@ -885,15 +907,6 @@ def _table_valid_matrix(table: Table) -> jnp.ndarray:
     return jnp.stack([c.validity_or_true() for c in table.columns], axis=1)
 
 
-def _check_row_size(layout: RowLayout, row_sizes: np.ndarray | None = None):
-    worst = (layout.fixed_row_size if row_sizes is None
-             else int(row_sizes.max(initial=0)))
-    if worst > MAX_ROW_SIZE:
-        raise ValueError(
-            f"row size {worst} exceeds JCUDF limit {MAX_ROW_SIZE} "
-            "(RowConversion.java:98-99)")
-
-
 @traced("convert_to_rows")
 @fault_site("convert_to_rows")
 def convert_to_rows(table: Table,
@@ -911,7 +924,6 @@ def convert_to_rows(table: Table,
         # reference reaches the same boundaries by scanning a constant-valued
         # row_sizes vector, row_conversion.cu:1460-1539) and offsets are a
         # device-side arange — no host scan, no H2D offset upload.
-        _check_row_size(layout)
         stride = layout.fixed_row_size
         if stride > max_batch_bytes:
             raise ValueError("a single row exceeds the maximum batch size")
@@ -944,14 +956,22 @@ def convert_to_rows(table: Table,
     # through the host-mirror cache — the arrays are host-born anyway, so
     # a device→host pull of 1M offsets would be pure waste.
     from ..utils import hostcache
-    total_lens = np.zeros(n, dtype=np.int64)
-    for ci in layout.variable_column_indices:
-        offs = hostcache.host_i64(table[ci].offsets)
-        total_lens += offs[1:] - offs[:-1]
-    row_sizes = row_sizes_with_strings(layout, total_lens)
-    _check_row_size(layout, row_sizes)
-
-    batches = build_batches(row_sizes, max_batch_bytes)
+    with metrics.span("rowconv.var.sizes", rows=n):
+        # memoized on the offset arrays, like the engines' geometry: the
+        # steady state re-converts the tables it holds
+        key_arrays = [table[ci].offsets
+                      for ci in layout.variable_column_indices]
+        tag = f"var_batches:{hash(layout)}:{max_batch_bytes}"
+        batches = syncs.memo_get(tag, key_arrays)
+        if batches is None:
+            total_lens = np.zeros(n, dtype=np.int64)
+            for ci in layout.variable_column_indices:
+                offs = hostcache.host_i64(table[ci].offsets)
+                total_lens += offs[1:] - offs[:-1]
+            batches = build_batches(
+                row_sizes_with_strings(layout, total_lens), max_batch_bytes)
+            syncs.memo_put(tag, key_arrays, batches)
+        metrics.annotate(batches=batches.num_batches)
     from . import ragged, xpack
     use_dma = ragged.dma_supported()
     use_xpack = knobs.get("SRJT_XPACK")
@@ -959,35 +979,46 @@ def convert_to_rows(table: Table,
     for bi, (lo, hi) in enumerate(zip(batches.row_boundaries[:-1],
                                       batches.row_boundaries[1:])):
         sub = Table([_slice_column(c, lo, hi) for c in table.columns])
-        data = None
+        boffs_np = batches.row_offsets_within_batch[bi]
+        data = boffs = None
+        engine = "xpack"
         if use_xpack:
             # primary engine (round 4): slab-gather + fused-roll program,
             # one jitted dispatch for the whole batch (see rowconv/xpack.py)
             col_offs = [hostcache.host_i64(sub[ci].offsets)
                         for ci in layout.variable_column_indices]
-            data = xpack.to_rows_var_x(
-                layout, sub, batches.row_offsets_within_batch[bi],
-                col_offs)
+            res = xpack.to_rows_var_x(layout, sub, boffs_np, col_offs)
+            if res is not None:
+                data, boffs = res
         valid = None if data is not None else _table_valid_matrix(sub)
         if data is None and use_dma:
-            data = _to_rows_var_dma(
-                layout, sub, valid, batches.row_offsets_within_batch[bi])
+            engine = "dma"
+            data = _to_rows_var_dma(layout, sub, valid, boffs_np)
         if data is None:
-            row_offs = jnp.asarray(
-                batches.row_offsets_within_batch[bi].astype(np.int64))
+            engine = "gather"
             data = _to_rows_var(
                 layout, batches.batch_bytes[bi],
                 tuple(c.data for c in sub.columns),
                 # _slice_column already rebases string offsets to zero
                 tuple(sub[ci].offsets
                       for ci in layout.variable_column_indices),
-                valid, row_offs)
-        boffs_np = batches.row_offsets_within_batch[bi]
-        boffs = jnp.asarray(boffs_np)
+                valid, jnp.asarray(boffs_np.astype(np.int64)))
+        metrics.count(f"rowconv.var.engine.to.{engine}")
+        if boffs is None:
+            boffs = jnp.asarray(boffs_np)
         hostcache.seed(boffs, np.asarray(boffs_np, dtype=np.int64))
         out.append(RowBatch(data, boffs))
     _record_transcode("rowconv.to_rows", n, out)
+    _record_chars(table.columns)
     return out
+
+
+def _record_chars(columns) -> None:
+    """String bytes moved, by either direction."""
+    if metrics.recording():
+        metrics.count("rowconv.var.chars_bytes",
+                      sum(c.data.shape[0] for c in columns
+                          if c.dtype.is_variable_width))
 
 
 def _record_transcode(prefix: str, rows: int, batches) -> None:
@@ -1118,7 +1149,8 @@ def convert_from_rows(batch: RowBatch, schema: Sequence[T.DType]) -> Table:
         res = xpack.from_rows_var_x(layout, batch)
         if res is not None:
             datas, valid, chars, out_offsets = res
-            return _assemble(schema, datas, valid, chars, list(out_offsets))
+            return _assembled("xpack", schema, datas, valid, chars,
+                              list(out_offsets))
     bdata = batch.device_u8()   # var path is byte-granular (DMA engine)
     if (ragged.dma_supported()
             and len(layout.variable_column_indices) <= _DMA_MAX_VAR_COLS):
@@ -1207,8 +1239,8 @@ def convert_from_rows(batch: RowBatch, schema: Sequence[T.DType]) -> Table:
                     chars.append(_gather_chars(
                         int(meta[vi, 0]), bdata, row_base, slots[vi],
                         out_offsets[vi]))
-        return _assemble(schema, datas, valid, tuple(chars),
-                         [o.astype(jnp.int32) for o in out_offsets])
+        return _assembled("dma", schema, datas, valid, tuple(chars),
+                          [o.astype(jnp.int32) for o in out_offsets])
 
     row_offsets = batch.offsets.astype(jnp.int64)
 
@@ -1222,14 +1254,15 @@ def convert_from_rows(batch: RowBatch, schema: Sequence[T.DType]) -> Table:
         for s in slots]
     # the documented per-column char-total pull: one stacked sync, counted
     syncs.note_sync()
-    totals_np = (np.asarray(jnp.stack([o[-1] for o in out_offsets]))  # srjt-lint: disable=trace-host-sync
-                 if out_offsets else np.zeros((0,), np.int64))
+    with metrics.span("rowconv.var.totals_sync", bytes=8 * len(out_offsets)):
+        totals_np = (np.asarray(jnp.stack([o[-1] for o in out_offsets]))  # srjt-lint: disable=trace-host-sync
+                     if out_offsets else np.zeros((0,), np.int64))
     char_totals = [int(t) for t in totals_np]
     datas, valid, chars = _from_rows_var(
         layout, tuple(char_totals), bdata, row_offsets,
         tuple(out_offsets), slots)
-    return _assemble(schema, datas, valid, chars,
-                     [o.astype(jnp.int32) for o in out_offsets])
+    return _assembled("gather", schema, datas, valid, chars,
+                      [o.astype(jnp.int32) for o in out_offsets])
 
 
 def _gather_chars(total: int, data: jnp.ndarray, row_base: jnp.ndarray,
@@ -1262,14 +1295,25 @@ def _gather_chars_jit(padded: int, data: jnp.ndarray, row_base: jnp.ndarray,
     return data[jnp.clip(src, 0, data.shape[0] - 1)]
 
 
+def _assembled(engine: str, schema, datas, valid, chars,
+               out_offsets) -> Table:
+    """The table a variable-width ``convert_from_rows`` returns, counted
+    against the engine that served it."""
+    table = _assemble(schema, datas, valid, chars, out_offsets)
+    metrics.count(f"rowconv.var.engine.from.{engine}")
+    _record_chars(table.columns)
+    return table
+
+
 def _assemble(schema, datas, valid, chars, out_offsets) -> Table:
     # Validity stays on device: the reference likewise always materializes a
     # null mask on this path ("always add it in", row_conversion.cu:1299-1301);
     # deciding all-valid here would force a D2H sync per conversion.
+    # ``valid``: the bool matrix [n, ncols], or a vector per column.
     cols = []
     vi = 0
     for ci, dt in enumerate(schema):
-        v = valid[:, ci]
+        v = valid[ci] if isinstance(valid, tuple) else valid[:, ci]
         if dt.is_variable_width:
             cols.append(Column(dt, chars[vi], out_offsets[vi], v))
             vi += 1

@@ -18,8 +18,9 @@ bit-identical to spark-rapids-jni's JCUDF format:
 * Output is split into ≤2GB batches (int32 offset limit) —
   ``row_conversion.cu:64,97-103,1460-1539`` (``build_batches``); batch
   boundaries rounded to 32-row multiples (``:1504-1506``).
-* Rows larger than 1KB are rejected (API contract,
-  ``RowConversion.java:98-99``).
+* No row-size limit of its own: like the reference's ``convert_to_rows``
+  (whose tiled path has no 1 KB check; its benchmark runs 1160 B rows), a
+  row only has to fit a batch (``build_batches`` raises otherwise).
 
 All of this is static host metadata — on TPU it feeds static shapes /
 scalar-prefetch grids instead of runtime kernel args.
@@ -35,7 +36,6 @@ import numpy as np
 from .. import types as T
 
 JCUDF_ROW_ALIGNMENT = 8
-MAX_ROW_SIZE = 1024            # RowConversion.java:98-99
 MAX_BATCH_BYTES = 2**31 - 1    # size_type max, row_conversion.cu:64
 BATCH_ROW_MULTIPLE = 32        # row_conversion.cu:1504-1506
 
@@ -92,11 +92,6 @@ def compute_row_layout(schema: Sequence[T.DType]) -> RowLayout:
     validity_bytes = -(-len(schema) // 8)
     fixed_plus_validity = validity_offset + validity_bytes
     fixed_row_size = _round_up(fixed_plus_validity, JCUDF_ROW_ALIGNMENT)
-
-    if fixed_row_size > MAX_ROW_SIZE and not variable:
-        raise ValueError(
-            f"row size {fixed_row_size} exceeds JCUDF limit of {MAX_ROW_SIZE} "
-            "bytes (RowConversion.java:98-99)")
 
     return RowLayout(
         schema=tuple(schema),

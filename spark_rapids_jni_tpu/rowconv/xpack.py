@@ -435,6 +435,14 @@ def _group_windows_combine(acc: jnp.ndarray, dstg: jnp.ndarray,
                            total: int) -> jnp.ndarray:
     """Window combine: group accumulators [ngroups, Bd] u32 at byte-granular
     group destinations ``dstg`` [ngroups+1] → packed u8 [total]."""
+    return _words_to_u8(
+        _group_windows_words(acc, dstg, ngroups, Bd, P, nwin))[:total]
+
+
+def _group_windows_words(acc: jnp.ndarray, dstg: jnp.ndarray, ngroups: int,
+                         Bd: int, P: int, nwin: int) -> jnp.ndarray:
+    """The window combine as u32 words [nwin * WIN_W] (bytes past the last
+    group's end are zero)."""
     fr = _first_row_per_window(dstg, ngroups, nwin, 512)
     fr = jnp.clip(fr, 0, ngroups - 1)
     padded = jnp.pad(acc, ((0, P), (0, 0)))
@@ -453,8 +461,7 @@ def _group_windows_combine(acc: jnp.ndarray, dstg: jnp.ndarray,
         glen = dstg[r + 1] - dstg[r]
         mask = _byte_mask(F, d_b, d_b + glen)
         out = out | jnp.where(live[:, None], placed & mask, jnp.uint32(0))
-    flat = out[:, Bd:Bd + WIN_W].reshape(-1)
-    return _words_to_u8(flat)[:total]
+    return out[:, Bd:Bd + WIN_W].reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -564,34 +571,48 @@ def to_rows_var_x(layout: RowLayout, sub, offs_np: np.ndarray,
 
     ``offs_np``: host row offsets [n+1] (8-byte-aligned rows).
     ``col_offs_np``: host char offsets per var column (geometry buckets).
-    Returns u32 words [total/4] or None when the geometry exceeds the
-    supported buckets (caller falls back).
+    Returns ``(u32 words [total/4], device row offsets or None)``, or None
+    when the geometry exceeds the supported buckets (caller falls back).
+    A layout whose rows are never narrower than a pack window goes through
+    the program in row tiles (``xtile``), which also hands back the row
+    offsets it computed on the device.
 
     The host geometry pass is memoized on the string-offset device arrays
     (the analytics steady state re-converts the same tables), so a warm
     call is pure dispatch: no host scans, no device uploads.
     """
+    from . import xtile
+    from ..utils import metrics, syncs
     n = sub.num_rows
     var_idx = layout.variable_column_indices
     if n == 0 or int(offs_np[-1]) == 0:
         return None
-    from ..utils import syncs
+    tiled = xtile.serves(layout)
     key_arrays = [sub[ci].offsets for ci in var_idx]
     # the geometry depends on the LAYOUT too (fpv feeds the row sizes), so
     # the memo tag carries it — the same string column objects reused under
     # a different schema must not hit a stale geometry
     tag = f"xpack_geom:{hash(layout)}"
-    geom = syncs.memo_get(tag, key_arrays)
+    with metrics.span("rowconv.var.plan", direction="to"):
+        geom = syncs.memo_get(tag, key_arrays)
+        hit = geom is not None
+        if not hit:
+            geom = (xtile.plan_to_rows if tiled else _plan_geometry)(
+                layout, n, offs_np, col_offs_np)
+            if geom is not None:
+                syncs.memo_put(tag, key_arrays, geom)
+        metrics.annotate(memo_hit=int(hit),
+                         Mw=geom[1] if geom else 0,
+                         tiles=-(-n // geom[2]) if geom and tiled else 1)
     if geom is None:
-        geom = _plan_geometry(layout, n, offs_np, col_offs_np)
-        if geom is None:
-            return None
-        syncs.memo_put(tag, key_arrays, geom)
-    return _to_rows_x_jit(
-        layout, geom,
-        tuple(c.data for c in sub.columns),
-        tuple(sub[ci].offsets for ci in var_idx),
-        tuple(c.validity for c in sub.columns))
+        return None
+    args = (tuple(c.data for c in sub.columns),
+            tuple(sub[ci].offsets for ci in var_idx),
+            tuple(c.validity for c in sub.columns))
+    with metrics.span("rowconv.var.launch", direction="to"):
+        if tiled:
+            return xtile.to_rows_jit(layout, geom, *args)
+        return _to_rows_x_jit(layout, geom, *args), None
 
 
 # ---------------------------------------------------------------------------
@@ -651,6 +672,14 @@ def _combine_to_stream(piece: jnp.ndarray, lens: jnp.ndarray,
     bytes live) → packed u8 [total] at byte destinations ``dst_offs``.
     Group-accumulate then window-combine — the segment-packing half of
     ``segmented_gather`` with the pieces already in hand."""
+    return _words_to_u8(_combine_to_words(piece, lens, dst_offs, n, g, Bd,
+                                          P, nwin))[:total]
+
+
+def _combine_to_words(piece: jnp.ndarray, lens: jnp.ndarray,
+                      dst_offs: jnp.ndarray, n: int, g: int, Bd: int,
+                      P: int, nwin: int) -> jnp.ndarray:
+    """:func:`_combine_to_stream` as u32 words [nwin * WIN_W]."""
     ngroups = -(-n // g)
     pad = ngroups * g - n
     piece3 = jnp.pad(piece, ((0, pad), (0, 0))).reshape(
@@ -668,7 +697,7 @@ def _combine_to_stream(piece: jnp.ndarray, lens: jnp.ndarray,
         placed = _place_words(fun, drel // 4, Bd)
         mask = _byte_mask(Bd, drel, drel + lens2[:, j])
         acc = acc | jnp.where(live[:, None], placed & mask, jnp.uint32(0))
-    return _group_windows_combine(acc, dstg, ngroups, Bd, P, nwin, total)
+    return _group_windows_words(acc, dstg, ngroups, Bd, P, nwin)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -829,9 +858,75 @@ def plan_from_rows(layout: RowLayout, batch, words: jnp.ndarray):
 
 def from_rows_var_x(layout: RowLayout, batch):
     """Packed JCUDF rows → (datas, valid, chars, out_offsets), one fused
-    program; None (caller falls back) outside the geometry buckets."""
+    program; None (caller falls back) outside the geometry buckets.
+    ``valid`` is the bool matrix [n, ncols], or a vector per column from
+    the tiled programs (``xtile``: two programs around the totals sync)."""
+    from . import xtile
+    from ..utils import metrics
     words = batch_words(batch)
-    geom = plan_from_rows(layout, batch, words)
+    if xtile.serves(layout):
+        return _from_rows_tiled(layout, batch, words)
+    with metrics.span("rowconv.var.plan", direction="from"):
+        geom = plan_from_rows(layout, batch, words)
+        metrics.annotate(Mw=geom[1] if geom else 0, tiles=1)
     if geom is None:
         return None
-    return _from_rows_x_jit(layout, geom, words, batch.offsets)
+    with metrics.span("rowconv.var.launch", direction="from"):
+        return _from_rows_x_jit(layout, geom, words, batch.offsets)
+
+
+def _from_rows_tiled(layout: RowLayout, batch, words: jnp.ndarray):
+    from . import xtile
+    from ..utils import hostcache, metrics, syncs
+    n = batch.num_rows
+    if n == 0:
+        return None
+    offs_np = hostcache.host_i64(batch.offsets)
+    if int(offs_np[-1]) == 0 or int(offs_np[-1]) % 4:
+        return None
+    tag = f"xunpack_geom:{hash(layout)}"
+    keys = [batch.data, batch.offsets]
+    with metrics.span("rowconv.var.plan", direction="from"):
+        memo = syncs.memo_get(tag, keys)
+        if memo == "reject":
+            geom_a = None
+        else:
+            geom_a = (memo[0] if memo else
+                      xtile.plan_from_rows_fixed(layout, n, offs_np))
+        metrics.annotate(memo_hit=int(memo is not None),
+                         Mw=geom_a[1] if geom_a else 0,
+                         tiles=-(-n // geom_a[2]) if geom_a else 1)
+    if geom_a is None:
+        return None
+    with metrics.span("rowconv.var.launch", direction="from"):
+        datas, valid, slots, out_offs, stats = xtile.from_rows_fixed_jit(
+            layout, geom_a, words, batch.offsets)
+    if memo is None:
+        # the one sync: char totals shape the output; slot violations and
+        # the stream geometry ride along (row_conversion.cu:2215 syncs on
+        # the same scanned totals)
+        with metrics.span("rowconv.var.totals_sync", bytes=stats.nbytes):
+            syncs.note_sync()
+            stats_np = np.asarray(stats)  # srjt-lint: disable=trace-host-sync
+        if stats_np[:, 1].any():
+            raise ValueError("corrupt row data: string slot outside its row")
+        with metrics.span("rowconv.var.plan", direction="from"):
+            geom_b = xtile.plan_from_rows_chars(layout, geom_a, stats_np)
+            metrics.annotate(memo_hit=0, Mw=geom_a[1],
+                             tiles=-(-n // geom_a[2]))
+        # rejections memoize too: a repeat conversion of an out-of-cap
+        # batch must not re-count its fallback on every call
+        syncs.memo_put(tag, keys,
+                       (geom_a, geom_b) if geom_b is not None else "reject")
+    else:
+        geom_b = memo[1]
+    if geom_b is None:
+        return None
+    live, totals = geom_b[-2:]
+    chars = [jnp.zeros((0,), jnp.uint8)] * len(totals)
+    if live:
+        with metrics.span("rowconv.var.launch", direction="from"):
+            for vi, c in zip(live, xtile.from_rows_chars_jit(
+                    layout, geom_b, words, batch.offsets, slots, out_offs)):
+                chars[vi] = c
+    return datas, valid, tuple(chars), out_offs

@@ -196,6 +196,11 @@ def test_row_conversion_round_trip_through_jni():
 
 
 def test_row_size_limit_throws_java_exception():
+    # no 1KB limit any more: the 200 x int64 table (1632 B rows) converts
+    # through the bridge, nothing is thrown, and its bytes equal the
+    # reference packer's
+    from spark_rapids_jni_tpu import Column, Table
+    from spark_rapids_jni_tpu.rowconv import reference as ref
     env = MockEnv()
     make_fixed = _fn("Java_com_tpu_rapids_jni_HostColumn_makeFixed",
                      C.c_int64, [ENVP, VOIDP, C.c_int32, C.c_int32,
@@ -204,17 +209,31 @@ def test_row_size_limit_throws_java_exception():
                      C.c_int64, [ENVP, VOIDP, C.c_void_p])
     to_rows = _fn("Java_com_tpu_rapids_jni_RowConversion_convertToRows",
                   C.c_int64, [ENVP, VOIDP, C.c_int64])
+    rows_free = _fn("Java_com_tpu_rapids_jni_RowConversion_freeRows",
+                    None, [ENVP, VOIDP, C.c_int64])
+    batches = _fn("srjt_rows_num_batches", C.c_int32, [C.c_void_p])
+    batch_data = _fn("srjt_rows_batch_data", C.POINTER(C.c_uint8),
+                     [C.c_void_p, C.c_int32])
+    batch_size = _fn("srjt_rows_batch_size", C.c_int64,
+                     [C.c_void_p, C.c_int32])
 
     n = 8
-    data = np.zeros(n, dtype=np.int64)
+    rng = np.random.default_rng(200)
+    datas = [rng.integers(-(2**60), 2**60, n, dtype=np.int64)
+             for _ in range(200)]
     handles = [make_fixed(env.env, None, int(sr.int64.id), 0, n,
-                          data.ctypes.data, 0) for _ in range(200)]
+                          d.ctypes.data, 0) for d in datas]
     th = make_table(env.env, None, env.long_array(handles))
     out = to_rows(env.env, None, th)  # 200*8B + validity > 1KB
-    assert out == 0
-    assert env.thrown is not None
-    assert env.thrown[0] == "java/lang/IllegalArgumentException"
-    assert "1KB" in env.thrown[1]
+    assert out and env.thrown is None
+    assert batches(C.c_void_p(out)) == 1
+    size = batch_size(C.c_void_p(out), 0)
+    got = np.ctypeslib.as_array(batch_data(C.c_void_p(out), 0),
+                                shape=(size,)).copy()
+    want, _ = ref.to_rows_np(Table([Column.from_numpy(d) for d in datas]))
+    assert size == n * 1632
+    np.testing.assert_array_equal(got, want)
+    rows_free(env.env, None, out)
 
 
 def test_string_round_trip_through_jni():

@@ -343,7 +343,8 @@ def test_xpack_fallback_accounting():
     from spark_rapids_jni_tpu.rowconv import xpack
     before = sum(xpack.fallback_counts.values())
     n = 40
-    # 600-char strings: rows stay under the 1KB JCUDF cap, but a group of
+    # 600-char strings (608 B rows; a row has no size cap of its own, only
+    # the engines' geometry caps, each with its counted fallback): a group of
     # 8 rows spans ~4.8KB of chars -> the from_rows dst-span bucket (Bd)
     # exceeds its 512-word cap and the engine must degrade with accounting
     strs = [("q" * 600) for _ in range(n)]

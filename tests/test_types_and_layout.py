@@ -61,9 +61,40 @@ def test_layout_validity_byte_per_8_columns():
 
 
 def test_row_size_limit_enforced():
-    # 1KB hard limit, RowConversion.java:98-99
-    with pytest.raises(ValueError, match="1024"):
-        L.compute_row_layout([sr.int64] * 200)
+    # no 1 KB limit on the path that stands for the reference's
+    # convert_to_rows: its own benchmark's 212-column "Fixed Width Only"
+    # table (1160 B rows) and 200 x int64 lay out, and round-trip equal to
+    # the plain packer
+    import numpy as np
+    from spark_rapids_jni_tpu import (Column, Table, convert_from_rows,
+                                      convert_to_rows)
+    from spark_rapids_jni_tpu.rowconv import reference as ref
+    cycle = [sr.int8, sr.int32, sr.int16, sr.int64, sr.int32, sr.bool8,
+             sr.uint16, sr.uint8, sr.uint64]
+    wide = L.compute_row_layout([cycle[i % 9] for i in range(212)])
+    assert wide.fixed_row_size == 1160 and wide.fixed_width_only
+    assert L.compute_row_layout([sr.int64] * 200).fixed_row_size == 1632
+    rng = np.random.default_rng(212)
+    n = 1000
+    cols = []
+    for i, dt in enumerate(wide.schema):
+        st = np.dtype(dt.storage)
+        values = (rng.integers(0, 2, n, dtype=np.uint8) if dt == sr.bool8
+                  else rng.integers(np.iinfo(st).min // 2,
+                                    np.iinfo(st).max // 2, n, dtype=st))
+        cols.append(Column.from_numpy(
+            values, dt, rng.random(n) < 0.9 if i % 3 == 0 else None))
+    table = Table(cols)
+    (batch,) = convert_to_rows(table)
+    want_bytes, want_offsets = ref.to_rows_np(table)
+    np.testing.assert_array_equal(batch.host_bytes(), want_bytes)
+    np.testing.assert_array_equal(np.asarray(batch.offsets), want_offsets)
+    back = convert_from_rows(batch, table.schema)
+    for sent, came in zip(table.columns, back.columns):
+        np.testing.assert_array_equal(np.asarray(came.data),
+                                      np.asarray(sent.data))
+        np.testing.assert_array_equal(np.asarray(came.validity_or_true()),
+                                      np.asarray(sent.validity_or_true()))
 
 
 def test_build_batches_single():
