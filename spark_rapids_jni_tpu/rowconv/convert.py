@@ -148,15 +148,13 @@ def _from_bytes_dt(b: jnp.ndarray, dt) -> jnp.ndarray:
 # fixed-width core: [cols…] → uint32 row words [n * W]
 # ---------------------------------------------------------------------------
 
-# Row-word counts up to which the layout-preserving 3-D permute beats one
-# big 2-D transpose, per direction.  Measured on the target chip
-# (tools/profile_transcode.py + crossover sweep, round 3):
+# Row-word count up to which the layout-preserving 3-D permute beats one
+# big 2-D transpose when interleaving.  Measured on the target chip
+# (tools/profile_transcode.py + crossover sweep, round 3; differenced in-jit
+# loops, not re-measured from a caller's side):
 #   interleave  perm3/transpose GB/s — W=11: 343/136, W=24: 747/263,
 #                                      W=40: 351/323, W=53: 154/375
-#   deinterleave                      — W=11: 286/51,  W=24: 469/101,
-#                                      W=32: 145/254, W=53: 154/372
 _IL_PERM3_MAX_W = 40
-_DL_PERM3_MAX_W = 24
 
 
 def _interleave_words(words: list[jnp.ndarray], W: int) -> jnp.ndarray:
@@ -172,9 +170,20 @@ def _interleave_words(words: list[jnp.ndarray], W: int) -> jnp.ndarray:
 
 
 def _deinterleave_words(flat: jnp.ndarray, W: int) -> jnp.ndarray:
-    """Inverse of :func:`_interleave_words`: u32 [n_pad*W] → [W, n_pad]."""
-    if W <= _DL_PERM3_MAX_W:
-        return flat.reshape(-1, 128, W).transpose(2, 0, 1).reshape(W, -1)
+    """Inverse of :func:`_interleave_words`: u32 [n*W] → word-major [W, n],
+    one 2-D transpose at every width and any n (no padding to 128 rows).
+
+    This is the one deinterleave of the fixed-width decode: every column and
+    validity vector is then read from a contiguous, lane-dense word row
+    ``t2[w]``.  Nothing of shape [n, k], k < 128, row-major is ever built:
+    one word column sliced out of the [n, W] matrix is padded 128x under the
+    (8,128) tiling (10.5 GiB of temporaries and 252 ms at 155 columns x 1M
+    rows).  ``convert_from_rows`` + block, 1<<20 rows, median ms on one v5e
+    (PERF.md §6 PR 29), transpose / 3-D permute / sliced matrix:
+      W=14: 4.37 / 4.52 / 17.50,  W=48: 11.00 / 11.52 / 52.27,
+      W=212: 37.17 / 43.22 / 251.78
+    The 3-D permute also costs 3.3-6.2 GiB of temporaries at W=14 once n is
+    not a power of two (compile rehearsal, PR 29)."""
     return flat.reshape(-1, W).T
 
 
@@ -251,8 +260,7 @@ def _stage_column_dt(data: jnp.ndarray, dt) -> jnp.ndarray:
 def _pack_validity_words(layout: RowLayout,
                          valid: jnp.ndarray) -> list[jnp.ndarray]:
     """Per validity byte k: u32 [n] vector with the byte's bits in the low
-    8 — shared by both fixed compose engines (the byte-identical invariant
-    the differential test pins depends on ONE packing implementation)."""
+    8."""
     n = valid.shape[0]
     out = []
     for k in range(layout.validity_bytes):
@@ -381,156 +389,6 @@ def _decode_row_columns(layout: RowLayout, word, n: int):
     return tuple(datas), vcols, tuple(slots)
 
 
-# Concat-based fixed compose (round-5 alternate engine, SRJT_FIXED_CONCAT):
-# instead of composing W per-word [n] vectors and permuting them into row
-# order, build the [n, W] row-word matrix DIRECTLY as one axis-1
-# concatenate of per-column u32 blocks — an 8-byte column's natural
-# [n, 2] bitcast IS its two adjacent row words, so the formulation has no
-# per-word lane selects and no 3-D permute; alignment gaps become zero
-# blocks and co-worded sub-byte columns pre-combine.  The inverse slices
-# the same blocks back out.  Chip A/B decides the default; both paths are
-# byte-identical (differential-tested).
-
-def _word_blocks(layout: RowLayout):
-    """Static [start_word, word_count, members] runs covering the row:
-    members = [(col_index | 'valid', kind, arg)] sharing the run."""
-    W = layout.fixed_row_size // 4
-    owner: list[list] = [[] for _ in range(W)]
-    for ci, dt in enumerate(layout.schema):
-        start = layout.column_starts[ci]
-        size = layout.column_sizes[ci]
-        w0 = start // 4
-        if size >= 4:
-            for j in range(size // 4):
-                owner[w0 + j].append((ci, "wide", j))
-        else:
-            owner[w0].append((ci, "sub", start % 4))
-    vo = layout.validity_offset
-    for k in range(layout.validity_bytes):
-        byte = vo + k
-        owner[byte // 4].append(("valid", "vbyte", (k, byte % 4)))
-    return owner
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def _to_rows_fixed_concat(layout: RowLayout, datas: tuple[jnp.ndarray, ...],
-                          valid: jnp.ndarray) -> jnp.ndarray:
-    """Fixed-width columns + validity matrix → flat u32 row words [n*W]
-    via ONE axis-1 concatenate of per-column blocks."""
-    n = valid.shape[0]
-    W = layout.fixed_row_size // 4
-    owner = _word_blocks(layout)
-    staged = {}
-
-    def stage(ci):
-        if ci not in staged:
-            staged[ci] = _stage_column_dt(datas[ci], layout.schema[ci])
-        return staged[ci]
-
-    vbytes_w = _pack_validity_words(layout, valid)
-
-    blocks = []
-    w = 0
-    while w < W:
-        mem = owner[w]
-        if not mem:
-            # alignment gap: extend over the whole zero run
-            w1 = w
-            while w1 < W and not owner[w1]:
-                w1 += 1
-            blocks.append(jnp.zeros((n, w1 - w), jnp.uint32))
-            w = w1
-            continue
-        if len(mem) == 1 and mem[0][1] == "wide" and mem[0][2] == 0:
-            ci = mem[0][0]
-            x = stage(ci)
-            blocks.append(x[:, None] if x.ndim == 1 else x)
-            w += 1 if x.ndim == 1 else x.shape[1]
-            continue
-        # mixed word: sub-word columns and/or validity bytes combine
-        acc = jnp.zeros((n,), jnp.uint32)
-        for ci, kind, arg in mem:
-            if kind == "vbyte":
-                k, shift = arg
-                acc = acc | (vbytes_w[k] << jnp.uint32(shift * 8))
-            elif kind == "sub":
-                acc = acc | (stage(ci) << jnp.uint32(arg * 8))
-            else:                      # a wide column's j-th word
-                x = stage(ci)
-                acc = acc | (x if x.ndim == 1 else x[:, arg])
-        blocks.append(acc[:, None])
-        w += 1
-    return jnp.concatenate(blocks, axis=1).reshape(-1)
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def _from_rows_fixed_concat(layout: RowLayout, flat: jnp.ndarray):
-    """Inverse: [n, W] row-word matrix sliced back into column blocks."""
-    W = layout.fixed_row_size // 4
-    n = flat.shape[0] // W
-    m2 = flat.reshape(n, W)
-    datas = []
-    for ci, dt in enumerate(layout.schema):
-        start = layout.column_starts[ci]
-        size = layout.column_sizes[ci]
-        w0 = start // 4
-        if size == 16:
-            quad = m2[:, w0:w0 + 4]
-            datas.append(jax.lax.bitcast_convert_type(
-                quad.reshape(-1, 2, 2), jnp.int64))
-            continue
-        st = dt.storage
-        if size == 8:
-            pair = m2[:, w0:w0 + 2]
-            datas.append(pair if _is_f64(st)
-                         else jax.lax.bitcast_convert_type(pair,
-                                                           jnp.dtype(st)))
-        elif size == 4:
-            datas.append(jax.lax.bitcast_convert_type(m2[:, w0],
-                                                      jnp.dtype(st)))
-        else:
-            v = ((m2[:, w0] >> jnp.uint32(8 * (start % 4)))
-                 & jnp.uint32((1 << (8 * size)) - 1))
-            unsigned = np.dtype(f"u{size}")
-            datas.append(jax.lax.bitcast_convert_type(
-                v.astype(jnp.dtype(unsigned)), jnp.dtype(st)))
-    vcols = []
-    for c in range(layout.num_columns):
-        byte = layout.validity_offset + c // 8
-        bit = ((m2[:, byte // 4] >> jnp.uint32(8 * (byte % 4) + c % 8))
-               & jnp.uint32(1))
-        vcols.append(bit.astype(jnp.bool_))
-    return tuple(datas), jnp.stack(vcols, axis=1)
-
-
-def _fixed_engine(direction: str) -> str:
-    """Measured round-5 policy (chip A/B; record deleted in PR 23, not
-    re-measured since): compose-to-rows
-    keeps the perm3 word engine (39.8/57.2 GB/s vs concat's 28.2 and a
-    64x-padding OOM at 212 cols — axis-1 concatenate of narrow blocks
-    writes terribly), while decode-from-rows uses the concat engine
-    everywhere (contiguous [n, W] slices: 64.1 GB/s at 12 cols, 825 GB/s
-    at 212 vs perm's 26.5/192.7).  SRJT_FIXED_CONCAT overrides both
-    directions for A/B; read OUTSIDE jit and passed as a static arg."""
-    env = knobs.get("SRJT_FIXED_CONCAT")
-    if env is not None:
-        return "concat" if env.lower() in ("1", "on") else "perm"
-    return "perm" if direction == "to" else "concat"
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def _from_rows_fixed_words(layout: RowLayout, flat: jnp.ndarray):
-    """Flat u32 row words [n*W] → (datas tuple, valid bool [n, ncols])."""
-    W = layout.fixed_row_size // 4
-    n = flat.shape[0] // W
-    n_pad = -(-n // 128) * 128
-    if n_pad != n:
-        flat = jnp.pad(flat, (0, (n_pad - n) * W))
-    t2 = _deinterleave_words(flat, W)                    # [W, n_pad]
-    datas, valid, _ = _decode_row_words(layout, lambda w: t2[w], n)
-    return datas, valid
-
-
 # Fused whole-call cores for the public fixed-width path.  The orchestration
 # around the reference's kernels is host code (offset columns built with
 # Thrust + D2D copies, row_conversion.cu:1460-1539); here that host work
@@ -538,9 +396,8 @@ def _from_rows_fixed_words(layout: RowLayout, flat: jnp.ndarray):
 # validity-matrix build, word compose, interleave, offsets arange — is one
 # jit program and the only transfer is the column payloads already in HBM.
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+@functools.partial(jax.jit, static_argnums=(0, 1))
 def _to_rows_fixed_full(layout: RowLayout, has_valid: tuple[bool, ...],
-                        engine: str,
                         datas: tuple[jnp.ndarray, ...],
                         valids: tuple[jnp.ndarray, ...]):
     """Fixed-width table → (flat u32 row words, int32 row offsets), one
@@ -552,22 +409,26 @@ def _to_rows_fixed_full(layout: RowLayout, has_valid: tuple[bool, ...],
     cols_valid = [next(vi) if hv else jnp.ones((n,), dtype=jnp.bool_)
                   for hv in has_valid]
     valid = jnp.stack(cols_valid, axis=1)
-    flat = (_to_rows_fixed_concat(layout, datas, valid)
-            if engine == "concat"
-            else _to_rows_fixed_words(layout, datas, valid))
+    flat = _to_rows_fixed_words(layout, datas, valid)
     offsets = jnp.arange(n + 1, dtype=jnp.int32) * layout.fixed_row_size
     return flat, offsets
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _from_rows_fixed_full(layout: RowLayout, engine: str,
-                          words: jnp.ndarray):
+@functools.partial(jax.jit, static_argnums=0)
+def _from_rows_fixed_full(layout: RowLayout, words: jnp.ndarray):
     """Flat u32 row words → (datas, per-column validity vectors)."""
-    datas, valid = (_from_rows_fixed_concat(layout, words)
-                    if engine == "concat"
-                    else _from_rows_fixed_words(layout, words))
-    valids = tuple(valid[:, ci] for ci in range(layout.num_columns))
-    return datas, valids
+    W = layout.fixed_row_size // 4
+    t2 = _deinterleave_words(words, W)                   # [W, n]
+    datas, vcols, _ = _decode_row_columns(layout, lambda w: t2[w],
+                                          words.shape[0] // W)
+    return datas, tuple(vcols)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _from_rows_fixed_words(layout: RowLayout, flat: jnp.ndarray):
+    """Flat u32 row words [n*W] → (datas tuple, valid bool [n, ncols])."""
+    datas, vcols = _from_rows_fixed_full(layout, flat)
+    return datas, jnp.stack(vcols, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -944,7 +805,7 @@ def convert_to_rows(table: Table,
             cols = (table.columns if (lo, hi) == (0, n)
                     else [_slice_column(c, lo, hi) for c in table.columns])
             data, offsets = _to_rows_fixed_full(
-                layout, has_valid, _fixed_engine("to"),
+                layout, has_valid,
                 tuple(c.data for c in cols),
                 tuple(c.validity for c in cols if c.validity is not None))
             out.append(RowBatch(data, offsets))
@@ -1133,8 +994,7 @@ def convert_from_rows(batch: RowBatch, schema: Sequence[T.DType]) -> Table:
                 f"describe {n} rows of {layout.fixed_row_size} bytes")
         words = (batch.data if batch.data.dtype == jnp.uint32
                  else _bytes_to_words(batch.data))
-        datas, valids = _from_rows_fixed_full(layout, _fixed_engine("from"),
-                                              words)
+        datas, valids = _from_rows_fixed_full(layout, words)
         cols = [Column(dt, datas[ci], validity=valids[ci])
                 for ci, dt in enumerate(schema)]
         return Table(cols)

@@ -302,9 +302,6 @@ register("SRJT_JOIN_ENGINE", None, _str,
 register("SRJT_RAGGED_DMA", "auto", _on_unless_0_off,
          "Pallas ragged DMA path on TPU backends; `0`/`off` forces the "
          "XLA gather fallback", "rowconv")
-register("SRJT_FIXED_CONCAT", None, _opt_str,
-         "A/B override for the fixed-width word engine: `1`/`on` forces "
-         "concat, anything else set forces perm", "rowconv")
 register("SRJT_XPACK", "1", _on_unless_0_off,
          "native xpack fast path for row conversion; `0`/`off` falls "
          "back to the reference composer", "rowconv")

@@ -358,28 +358,54 @@ def test_xpack_fallback_accounting():
     assert after > before, "fallback happened but was not accounted"
 
 
-def test_fixed_concat_engine_differential(monkeypatch):
-    """The round-5 concat compose (SRJT_FIXED_CONCAT=1) must be
-    byte-identical to the perm3/word-compose engine on both directions,
-    incl. decimal128 / f64-bit-pair / sub-word columns."""
-    monkeypatch.delenv("SRJT_FIXED_CONCAT", raising=False)
-    import bench as bench_mod
-    t = bench_mod.build_table(10_000, 12)
-    # the bench cycle has no decimal128: append one so the 16-byte quad
-    # block compose/decode is covered
-    import jax.numpy as jnp
-    lanes = RNG.integers(-2**62, 2**62, (10_000, 2), dtype=np.int64)
-    dec = Column(sr.types.decimal128(-2), jnp.asarray(lanes),
-                 validity=jnp.asarray(RNG.random(10_000) < 0.9))
-    t = Table(list(t.columns) + [dec])
-    b_ref = convert_to_rows(t)[0]
-    monkeypatch.setenv("SRJT_FIXED_CONCAT", "1")
-    b_new = convert_to_rows(t)[0]
-    np.testing.assert_array_equal(b_ref.host_bytes(), b_new.host_bytes())
-    back = convert_from_rows(b_new, t.schema)
-    monkeypatch.delenv("SRJT_FIXED_CONCAT")
-    want = convert_from_rows(b_ref, t.schema)
-    for a, c in zip(back.columns, want.columns):
-        np.testing.assert_array_equal(np.asarray(a.data), np.asarray(c.data))
-        np.testing.assert_array_equal(np.asarray(a.validity_or_true()),
-                                      np.asarray(c.validity_or_true()))
+_TEST_CYCLE = (sr.int8, sr.int16, sr.int32, sr.int64, sr.float32, sr.float64,
+               sr.bool8)
+# spark-rapids-jni benchmarks/row_conversion.cpp: the cycle of its 155-column
+# axis (chipbench's fixed155_roundtrip) and of its 212-column "Fixed Width
+# Only" table
+_NVBENCH_CYCLE = (sr.int8, sr.int32, sr.int16, sr.int64, sr.int32, sr.bool8,
+                  sr.uint16, sr.uint8, sr.uint64)
+
+
+def _fixed_table(kind, n):
+    """Nulls (~10%) on every third column; null slots keep their payload."""
+    cycle, n_cols = {"one": ((sr.int64,), 1),
+                     "mixed12_dec_f64": (_TEST_CYCLE, 12),
+                     "nvbench155": (_NVBENCH_CYCLE, 155),
+                     "nvbench212": (_NVBENCH_CYCLE, 212)}[kind]
+    cols = [random_column(cycle[i % len(cycle)], n,
+                          "most" if i % 3 == 0 else "all")
+            for i in range(n_cols)]
+    if kind == "mixed12_dec_f64":
+        # the cycle has no decimal128: the 16-byte quad compose/decode
+        import jax.numpy as jnp
+        lanes = RNG.integers(-2**62, 2**62, (n, 2), dtype=np.int64)
+        cols.append(Column(sr.types.decimal128(-2), jnp.asarray(lanes),
+                           validity=jnp.asarray(RNG.random(n) < 0.9)))
+    return Table(cols)
+
+
+@pytest.mark.parametrize("n", [1, 127, 1000])
+@pytest.mark.parametrize("kind", ["one", "mixed12_dec_f64", "nvbench155",
+                                  "nvbench212"])
+def test_fixed_word_major_roundtrip(kind, n):
+    """The fixed path's one engine each way (word compose + interleave,
+    deinterleave + word-row decode): the batch's bytes equal the NumPy
+    packer's, and the table comes back bit for bit, null slots' payload
+    included; n off a multiple of 128 leaves the last lane tile ragged."""
+    table = _fixed_table(kind, n)
+    (batch,) = convert_to_rows(table)
+    want_bytes, want_offsets = ref.to_rows_np(table)
+    np.testing.assert_array_equal(batch.host_bytes(), want_bytes)
+    np.testing.assert_array_equal(np.asarray(batch.offsets), want_offsets)
+    back = convert_from_rows(batch, table.schema)
+    assert back.num_columns == table.num_columns and back.num_rows == n
+    for i, (sent, came) in enumerate(zip(table.columns, back.columns)):
+        assert came.dtype == sent.dtype, f"col {i}"
+        assert np.asarray(came.data).dtype == np.asarray(sent.data).dtype
+        np.testing.assert_array_equal(np.asarray(came.data),
+                                      np.asarray(sent.data),
+                                      err_msg=f"col {i} payload")
+        np.testing.assert_array_equal(np.asarray(came.validity_or_true()),
+                                      np.asarray(sent.validity_or_true()),
+                                      err_msg=f"col {i} validity")

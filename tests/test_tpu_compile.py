@@ -142,17 +142,35 @@ def _fixed_axis(n_cols, n=1_000_000):
 def test_fixed_to_rows_program(one_chip, n_cols):
     layout, has_valid, datas, valids, _ = _fixed_axis(n_cols)
     _compile(one_chip, convert._to_rows_fixed_full, datas, valids,
-             statics=(layout, has_valid, convert._fixed_engine("to")))
+             statics=(layout, has_valid))
 
 
-@pytest.mark.parametrize("n_cols", [12, 212])
-def test_fixed_from_rows_program(one_chip, n_cols):
-    # 212 columns is the HBM-hungriest program of the smoke (~10 GB of
-    # temporaries): the fit check in _compile is the point
-    layout, _, _, _, n = _fixed_axis(n_cols)
+def _cell_layout():
+    """chipbench's fixed155_roundtrip: the reference benchmark's 155-column
+    nine-type cycle, 1<<20 rows."""
+    import json
+    with open(os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                           "configs", "nvbench_fixed155_1m.json")) as f:
+        cfg = json.load(f)
+    schema = [getattr(sr, cfg["type_cycle"][i % len(cfg["type_cycle"])])
+              for i in range(cfg["columns"])]
+    return compute_row_layout(schema), cfg["rows"]
+
+
+@pytest.mark.parametrize("shape", [12, "cell155", 212])
+def test_fixed_from_rows_program(one_chip, shape):
+    if shape == "cell155":
+        layout, n = _cell_layout()
+    else:
+        layout, _, _, _, n = _fixed_axis(shape)
     words = _s((n * layout.fixed_row_size // 4,), jnp.uint32)
-    _compile(one_chip, convert._from_rows_fixed_full, words,
-             statics=(layout, convert._fixed_engine("from")))
+    c = _compile(one_chip, convert._from_rows_fixed_full, words,
+                 statics=(layout,))
+    # word-major decode: no word column sliced out of the row-major [n, W]
+    # matrix (padded 128x under the (8,128) tiling; 10.5 GiB of temporaries
+    # at the cell's shape before PR 29)
+    assert f"[{n},1]{{1,0:T(8,128)" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 3 << 30
 
 
 # --- xpack: the strings engine, strings_mixed12 schema --------------------------
