@@ -54,8 +54,9 @@ def call(state, caller: int, i: int, rec) -> float:
 def answers(state):
     """The window's last round trip, on the host: the batch's bytes and the
     payload and validity of the table that came back.  Frees the device.
-    Earlier answers are not held: the chip has no room for one beside
-    ``from_rows``'s temporaries (PERF.md)."""
+    Earlier answers are not held, by choice: since PR 29 ``from_rows``
+    reserves 1.85 GiB of temporaries and earlier answers (1.50 GiB each)
+    would fit beside it (PERF.md)."""
     last, state.last, state.table = state.last, None, None
     if last is None:
         return []

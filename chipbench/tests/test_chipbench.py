@@ -47,6 +47,13 @@ def passes(compared):
     return all(c["value"] <= c["limit"] for c in compared.values())
 
 
+@pytest.fixture(autouse=True)
+def own_trace_dir(monkeypatch, tmp_path):
+    """A traced run empties ``harness.TRACE_DIR`` first: two xdist workers
+    tracing into the checkout's one directory empty each other's."""
+    monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path / "trace"))
+
+
 # --- (a) every driver: setup -> call -> check, and its control ---------------
 
 @pytest.mark.parametrize("name", [c for c in CELLS if c in
@@ -97,8 +104,11 @@ def test_whole_run_traced_on_cpu_reports_spans_and_no_device_share():
     r = harness.run_cell(cell, 5, 0.3, True, time.time(), FAKE_CHIP,
                          config=tiny(cell))
     assert r["correct"] and list(r)[-1] == "compared"
-    # host-clock spans are read; a roofline with no device plane is left out
-    assert set(r["metrics"]) == {"to_rows_ms", "from_rows_ms"}
+    # host-clock and program spans are read; what needs a device plane (the
+    # roofline) is left out, whatever later PRs add beside it
+    assert {"to_rows_ms", "from_rows_ms"} <= set(r["metrics"])
+    assert not any(cell.per_layer[name]["source"] == "device_trace"
+                   for name in r["metrics"])
     assert "busy_s" not in r["device"] and "breakdown" not in r
 
 
@@ -313,7 +323,8 @@ def test_layout_and_roofline_bytes_by_hand():
     assert rooflines.transcode_roundtrip(f155) == 2 * (1 << 20) * (
         532 + 52 + 848)
     # the source's 212-column table: 23 cycles = 1104 B, five more columns
-    # end at 1132, 27 validity bytes -> 1160 B, over the program's 1 KB limit
+    # end at 1132, 27 validity bytes -> 1160 B (over the 1 KB limit the
+    # program had until PR 28)
     assert references.jcudf_fixed_layout(
         [f155["type_cycle"][i % 9] for i in range(212)])[4] == 1160
     if "tpch_q6_sf1" in cfg:
