@@ -1,8 +1,4 @@
-"""Manual benchmark suite (nvbench-harness equivalent, SURVEY §2.8).
-
-Like the reference's ``src/main/cpp/benchmarks`` (nvbench, never run in CI —
-``CONTRIBUTING.md:223-231``), these are run by hand:
-
-    python -m benchmarks.row_conversion            # quick axes
-    python -m benchmarks.row_conversion --full     # the reference's axes
+"""Seeded data generators and pandas twins that the tests, ``chip_smoke.py``
+and ``tools/tpu_check.py`` share.  The benchmark is not here: ``BENCHMARK.json``
+and ``chipbench/`` (``python3 -m chipbench --workload <cell>``; PERF.md §2).
 """

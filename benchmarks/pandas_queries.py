@@ -1,12 +1,10 @@
 """Pandas implementations of the full TPC-DS query subset.
 
-The host baseline counterpart of ``models/tpcds.py:QUERIES`` — every
-plan re-expressed over pandas DataFrames so ``tools/query_host_baseline``
-can time the identical work on the CPU (the stand-in for the BASELINE
-north star's "CPU Spark" comparison; single-process pandas is what the
+The host counterpart of ``models/tpcds.py:QUERIES`` — every plan
+re-expressed over pandas DataFrames (single-process pandas is what the
 image provides).  Each function takes ``dfs`` (table name → DataFrame)
 and returns a DataFrame/Series; result row counts are cross-checked
-against the chip results in ``tests/test_pandas_queries.py``.
+against the program's results in ``tests/test_pandas_queries.py``.
 
 These are plan translations, not golden oracles — the per-query pandas
 differentials in ``tests/test_tpcds*.py`` remain the correctness

@@ -95,7 +95,7 @@ def _block(tree) -> None:
 def build_table(n_rows: int, n_cols: int, string_every: int = 0,
                 seed: int = 7):
     """The reference's nvbench type cycle (row_conversion.cpp:30-38, f64
-    included), ~10% nulls on every third column; the data bench.py times."""
+    included), ~10% nulls on every third column."""
     import spark_rapids_jni_tpu as sr
     from spark_rapids_jni_tpu import Column, Table
     cycle = [sr.int8, sr.int16, sr.int32, sr.int64, sr.float32, sr.float64,

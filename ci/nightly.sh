@@ -1,8 +1,9 @@
 #!/usr/bin/env bash
 # Nightly pipeline — the analog of the reference's ci/nightly-build.sh:25-30
 # (mvn deploy of the cuda-classified jar after a full build): build, full
-# test suite, driver-contract checks, benchmarks, and on-TPU validation,
-# with every artifact dropped under target/nightly/ for archival.
+# test suite, driver-contract checks, the chip drive, the on-TPU validation
+# sweep and the benchmark's cells, with every artifact dropped under
+# target/nightly/ for archival.
 #
 # Usage: ci/nightly.sh [--no-tpu]   (--no-tpu skips chip-bound stages)
 set -euo pipefail
@@ -22,22 +23,18 @@ env XLA_FLAGS= JAX_PLATFORMS= python __graft_entry__.py dryrun 8 \
     | tee "$OUT/dryrun.log"
 
 if [[ "${1:-}" != "--no-tpu" ]]; then
-    echo "== headline benchmark (real chip) =="
-    python bench.py > "$OUT/bench.json" || true
-    tail -1 "$OUT/bench.json"
+    echo "== the main path end to end on the chip, every answer checked =="
+    python chip_smoke.py | tee "$OUT/chip_smoke.log"
 
     echo "== on-TPU validation sweep =="
     python tools/tpu_check.py "$OUT/tpu_check.json" || true
 
-    echo "== SF1 scan benchmark =="
-    python tools/scan_bench.py 6000000 "$OUT/scan_bench.json" || true
-
-    echo "== SF1 query benchmark (persistent compile cache) =="
-    # query_bench.py applies utils/compile_cache.py's rule
-    # (JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache), so
-    # this nightly's compiles seed the cache and the next process's cold
-    # run reuses every executable (VERDICT r3 next-step #3)
-    python tools/query_bench.py 10000000 "$OUT/query_bench.json" || true
+    echo "== the benchmark's cells, one process each (BENCHMARK.json) =="
+    for cell in $(python3 -c 'import json
+print(*[w["name"] for w in json.load(open("BENCHMARK.json"))["workloads"]])'); do
+        python3 -m chipbench --workload "$cell" --seed 7 --seconds 51 --trace 0 \
+            | tail -1 > "$OUT/$cell.json" || true
+    done
 fi
 
 cp -f target/dist/*.whl "$OUT"/ 2>/dev/null || true

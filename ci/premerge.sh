@@ -52,28 +52,6 @@ PYEOF
 if [[ "${1:-}" != "--skip-tests" ]]; then
     echo "== tests =="
     python -m pytest tests/ -q
-    echo "== exec smoke (serving runtime) =="
-    ci/exec_smoke.sh
-    echo "== chaos smoke (fault-tolerant serving) =="
-    ci/chaos_smoke.sh
-    echo "== plan smoke (query planner) =="
-    ci/plan_smoke.sh
-    echo "== aqe smoke (adaptive query execution) =="
-    ci/aqe_smoke.sh
-    echo "== stream smoke (incremental maintenance) =="
-    ci/stream_smoke.sh
-    echo "== dict smoke (dictionary-string fast path) =="
-    ci/dict_smoke.sh
-    echo "== bytes smoke (staged/pipelined/donated scan) =="
-    ci/bytes_smoke.sh
-    echo "== profile smoke (EXPLAIN ANALYZE / per-node profiles) =="
-    ci/profile_smoke.sh
-    echo "== ml smoke (ETL→ML handoff) =="
-    ci/ml_smoke.sh
-    echo "== coldstart smoke (AOT plan-artifact store) =="
-    ci/coldstart_smoke.sh
-    echo "== sql smoke (SQL front-end / submit_sql) =="
-    ci/sql_smoke.sh
 fi
 
 echo "premerge OK"

@@ -12,7 +12,7 @@ Two halves, one discipline:
   reads whose defaults/docs live nowhere).
 * **Runtime** (``sanitize``) — ``SRJT_SANITIZE=1`` arms a lock-order
   watchdog and a retrace tripwire in the live process; ``strict`` makes
-  violations raise (the CI chaos/exec smokes run strict).
+  violations raise (``tests/conftest.py`` runs all of tier-1 strict).
 
 This ``__init__`` stays import-light on purpose: ``analysis.sanitize``
 is imported by hot modules (``utils``, ``exec``) at process start, so

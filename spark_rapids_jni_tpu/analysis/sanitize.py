@@ -4,9 +4,9 @@ Static analysis sees the shapes it can resolve; this module watches the
 *live* process.  ``SRJT_SANITIZE=1`` arms both sanitizers in incident
 mode — violations file a flight-recorder incident (kind ``lock_order``
 or ``retrace``) with the offending stacks and keep going.
-``SRJT_SANITIZE=strict`` raises instead; the CI chaos/exec smokes run
-strict so an inversion or an unexpected recompile fails the build, not
-the pager.
+``SRJT_SANITIZE=strict`` raises instead; ``tests/conftest.py`` starts
+every tier-1 process strict, so an inversion or an unexpected recompile
+in any suite fails the build, not the pager.
 
 Lock-order watchdog
     Lock sites create their primitives through :func:`tracked_lock` /

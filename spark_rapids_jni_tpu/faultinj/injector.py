@@ -46,7 +46,7 @@ Two extensions over the reference schema serve the chaos harness
 * ``maxHits`` (alias ``max_hits``) — an absolute cap on how many times
   the rule fires, independent of ``interceptionCount`` (which budgets
   *interceptions*, i.e. dice rolls).  ``maxHits: 1`` is the one-shot
-  kill used by ``ci/chaos_smoke.sh``: exactly one fatal fault, then the
+  kill used by ``tests/test_chaos.py``: exactly one fatal fault, then the
   device is genuinely healthy again for the recovery probe's canary.
 """
 

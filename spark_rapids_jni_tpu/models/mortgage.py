@@ -109,8 +109,7 @@ def feature_spec():
     (max_delinquency > 2 — the synthetic generator emits delinquency
     grades 2/3, so >2 is the class split that actually separates).
     The returned spec packs ``etl_tables`` output straight into the
-    on-device feature matrix — see ``tools/mortgage_bench.py`` for the
-    full parquet→trained-model path."""
+    on-device feature matrix."""
     from ..ml.features import Feature, FeatureSpec
     feats = [c for c in FEATURE_COLS
              if c not in ("loan_id", "max_delinquency")]

@@ -361,15 +361,15 @@ def _device_plain_w(phys: int, words: jnp.ndarray,
     expansion).  PLAIN fixed payloads are always 4-byte aligned, so the
     u8→u32 step happens on HOST as a free ``np.frombuffer`` view and the
     device decode collapses to bitcasts/reshapes (round 5 — the strided
-    u8 lane extraction was the round-4 scan's cost center at ~9 GB/s)."""
+    u8 lane extraction was the round-4 scan's cost center)."""
     if phys == D.PT_DOUBLE:
         typed = _word_pairs(words)         # IS the f64 bit-pair storage
     elif phys == D.PT_FLOAT:
         typed = jax.lax.bitcast_convert_type(words, jnp.float32)
     elif phys == D.PT_INT64:
         # bitcast packs the last axis LSW-first on the little-endian
-        # backends — 2x the u64 shift/or assembly on chip (33.8 vs 18.4
-        # GB/s measured round 5)
+        # backends, and saves the u64 shift/or assembly (not measured
+        # from a caller's side: PERF.md §5 has the scan's breakdown)
         typed = jax.lax.bitcast_convert_type(_word_pairs(words),
                                              jnp.int64)
     else:

@@ -2,7 +2,7 @@
 
 Round 5 uploaded every raw page payload, level stream, and dictionary
 with its own ``jnp.asarray`` — dozens of small synchronous transfers per
-file, measured at 0.031 GB/s end to end (SCAN_BENCH ``h2d_gbps``).  The
+file, each paying the link's fixed cost for a few kilobytes.  The
 reference stages a row group's pages into pinned host slabs and issues
 ONE cudaMemcpyAsync per slab so the copy engine streams at link rate
 (SURVEY §5.5); the PJRT analog is the same shape:

@@ -10,11 +10,11 @@ translation (see SURVEY §7):
   word is composed from a statically-planned set of column fragments
   (shift/or tree), and the column->row interleave is one layout-preserving
   3-D permute (or, for wide rows, one 2-D transpose) whose output minor
-  dimension is a 128-lane multiple.  Measured on the target chip
-  (tools/profile_transcode.py, round 3) these formulations run at 250-750
-  GB/s vs ~45-135 GB/s for strided lane writes and ~22 GB/s for a final
-  u32->u8 repack — which is why :class:`RowBatch` carries the row bytes AS
-  u32 words (JCUDF rows are 8-byte aligned, so the words are exact).
+  dimension is a 128-lane multiple.  Strided lane writes and a final
+  u32->u8 repack lost to these forms — which is why :class:`RowBatch`
+  carries the row bytes AS u32 words (JCUDF rows are 8-byte aligned, so the
+  words are exact).  What the path does from a caller's side, and where its
+  time goes: PERF.md §5.
 * The warp-ballot validity transpose (``row_conversion.cu:710-810``)
   becomes a weighted-sum bit pack (``utils.bitmask.pack_bool_matrix``).
 * Variable-width (string) handling follows the reference's two-phase shape
@@ -61,9 +61,9 @@ class RowBatch:
     [total_bytes] (variable-width batches, byte-granular DMA engine) or as
     uint32 [total_bytes/4] little-endian words (fixed-width batches — rows
     are 8-byte aligned so the word view is exact, and keeping words avoids
-    a ~22 GB/s u32->u8 relayout pass on TPU).  Both views describe the
-    identical JCUDF byte stream; :meth:`host_bytes` is the canonical byte
-    materialization.
+    a u32->u8 relayout pass over the whole batch on TPU).  Both views
+    describe the identical JCUDF byte stream; :meth:`host_bytes` is the
+    canonical byte materialization.
     """
 
     data: jnp.ndarray      # uint8 [total_bytes] or uint32 [total_bytes/4]
@@ -120,8 +120,8 @@ def _byte_view(data: jnp.ndarray, storage: np.dtype) -> jnp.ndarray:
 def _from_bytes(b: jnp.ndarray, storage: np.dtype) -> jnp.ndarray:
     """uint8 [n, itemsize] → [n] payload (f64: uint32 [n,2] bit pairs)."""
     if _is_f64(storage):
-        # flat u32 then reshape — the direct 3-D bitcast pays a ~15×
-        # narrow-minor layout penalty on TPU (measured round 3)
+        # flat u32 then reshape — the direct 3-D bitcast builds a narrow-
+        # minor array, which the TPU's tiling pads out to 128 lanes
         return jax.lax.bitcast_convert_type(
             b.reshape(-1, 4), jnp.uint32).reshape(-1, 2)
     if storage.itemsize == 1:
@@ -148,12 +148,11 @@ def _from_bytes_dt(b: jnp.ndarray, dt) -> jnp.ndarray:
 # fixed-width core: [cols…] → uint32 row words [n * W]
 # ---------------------------------------------------------------------------
 
-# Row-word count up to which the layout-preserving 3-D permute beats one
-# big 2-D transpose when interleaving.  Measured on the target chip
-# (tools/profile_transcode.py + crossover sweep, round 3; differenced in-jit
-# loops, not re-measured from a caller's side):
-#   interleave  perm3/transpose GB/s — W=11: 343/136, W=24: 747/263,
-#                                      W=40: 351/323, W=53: 154/375
+# Row-word count up to which interleaving takes the layout-preserving 3-D
+# permute and not one big 2-D transpose.  The crossover is unmeasured from a
+# caller's side: no cell has W <= 40, and the same permute in the other
+# direction lost 7-11x once n was no power of two and went (PERF.md §6, PR
+# 29).  ROADMAP S8 asks for the measurement that keeps one form.
 _IL_PERM3_MAX_W = 40
 
 
@@ -939,8 +938,9 @@ def fixed_rows_to_matrix(batch: RowBatch, layout: RowLayout) -> jnp.ndarray:
 # --- dictionary-codes passthrough (dict string fast path) -------------------
 #
 # A DictColumn reaching convert_to_rows materializes its bytes — correct
-# (JCUDF rows must carry the strings) but back on the 0.6 GB/s variable-
-# width cliff.  When BOTH endpoints speak this engine (shuffle, spill,
+# (JCUDF rows must carry the strings) but back on the variable-width path
+# (1.25 GB/s against 33.5 through the fixed one, from a caller's side:
+# ledger, PR 31).  When BOTH endpoints speak this engine (shuffle, spill,
 # cache), ship the CODES through the fixed-width path instead and send the
 # tiny dictionaries out of band: string columns transcode at int32 speed.
 

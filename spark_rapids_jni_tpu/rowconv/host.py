@@ -1,9 +1,7 @@
 """Vectorized host (NumPy) JCUDF engine.
 
 Two roles:
-* the **CPU baseline** for the headline benchmark (``bench.py`` measures
-  the device path against a host reference) and the full-size plain
-  reference of ``chip_smoke.py``, and
+* the full-size plain reference of ``chip_smoke.py``, and
 * a production host fallback for row conversion when no accelerator is
   attached (the reference has no such fallback — its only engine is CUDA —
   so this is strictly additive capability).

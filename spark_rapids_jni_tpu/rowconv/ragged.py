@@ -4,13 +4,13 @@ This is the TPU-native answer to the reference's variable-width CUDA kernels
 (``copy_strings_to_rows`` warp-per-row ``memcpy_async``,
 ``row_conversion.cu:827-875``, and ``copy_strings_from_rows``,
 ``:1131-1174``).  Three facts about the hardware/toolchain dictate the
-design (all measured on v5e in round 3; the record was deleted in PR 23
-and the numbers have not been re-measured since):
+design (round 3's readings on a v5e; their records are gone and nothing
+has re-measured them, so no figure stands here):
 
-* XLA's 1D gather scalarizes (~0.1 Gelem/s) — per-element indexing is not a
-  usable primitive for byte movement;
-* per-DMA issue rate tops out ~1.4 M/s, so per-row DMAs cap at ~1 GB/s for
-  typical row sizes;
+* XLA's 1D gather scalarizes — per-element indexing is not a usable
+  primitive for byte movement;
+* the rate at which DMAs can be issued is bounded, so one DMA a row cannot
+  move rows of typical sizes at the memory's bandwidth;
 * Mosaic DMA slices must be tile-aligned (512B windows), but in-register
   dynamic rolls (``pltpu.roll``) are cheap on 32-bit lanes.
 

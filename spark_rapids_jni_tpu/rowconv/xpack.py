@@ -1,15 +1,14 @@
 """Variable-width JCUDF composition as ONE fused XLA program (round 4).
 
-The round-3 string path moved bytes with per-(row|segment) machinery whose
-per-row cost floor was measured at 0.16-0.8 µs (Pallas per-row rolls) or
-24 ns (XLA row-granular gathers) — a 1M-row mixed batch could not beat
-~0.2 GB/s wall.  This module rebuilds the path on the two primitives the
-round-4 chip shootout (``PROFILE_strings.json``, ``tools/probe_slab.py``)
-showed to be fast:
+The round-3 string path moved bytes with per-(row|segment) machinery that
+pays a fixed cost for every row (Pallas per-row rolls, XLA row-granular
+gathers).  This module rebuilds the path on two primitives whose cost does
+not grow with the row count (what the path does from a caller's side
+today, and where its time goes: PERF.md §5):
 
-* **slab gathers** — XLA row gathers cost ~24 ns per *gathered row*
-  regardless of row width (43.9 GB/s at 512 B rows), so all gathers here
-  move WIDE slabs covering many logical rows: per-column char windows are
+* **slab gathers** — an XLA row gather costs by the *gathered row*, whatever
+  the row's width, so all gathers here move WIDE slabs covering many
+  logical rows: per-column char windows are
   gathered per GROUP of ``g`` rows (one slab covers the whole group's
   chars), and the output packing gathers one ``P``-row slab per 512 B
   output window.  Gather count is ``n/g + n_windows``, not ``n × pieces``.
@@ -523,9 +522,9 @@ def _to_rows_x_jit(layout: RowLayout, geom, datas, str_offsets, valid):
     rs_w = ((row_b + 7) // 8 * 8) // 4
     dst_w = jnp.concatenate([jnp.zeros(1, jnp.int32),
                              jnp.cumsum(rs_w, dtype=jnp.int32)])
-    # (a pair-compaction level before the pack was measured and REJECTED:
-    # the strided row split d[0::2]/d[1::2] alone cost ~76 ms at 1M rows —
-    # more than the whole frame-combine saving it buys)
+    # (a pair-compaction level before the pack was tried and REJECTED: the
+    # strided row split d[0::2]/d[1::2] alone cost more than the whole
+    # frame-combine saving it buys)
     return pack_windows(dense, dst_w, total_w, P, nwin)
 
 

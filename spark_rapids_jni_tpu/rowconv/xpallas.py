@@ -344,10 +344,11 @@ def _extract_call(nblocks, RB, MwS, KS, KOFF, interpret):
 # ---------------------------------------------------------------------------
 # gather_rows: padded row matrix [D, W] u32 + codes [n] → [n, W]
 #
-# XLA lowers `mat[idx]` to a row gather (~24 ns/row); on wide dictionaries
-# the DMA engine can instead stream each selected row HBM→VMEM directly.
-# One block gathers 8 rows with 8 in-flight row DMAs (the per-DMA issue
-# rate bounds this at ~1.4 M rows/s — wins when rows are ≥ ~512 B).
+# XLA lowers `mat[idx]` to a row gather that costs by the row; on wide
+# dictionaries the DMA engine can instead stream each selected row
+# HBM→VMEM directly.  One block gathers 8 rows with 8 in-flight row DMAs
+# (the rate at which DMAs issue bounds it, so it can only win on wide rows;
+# never timed from a caller's side: ROADMAP S8).
 # ---------------------------------------------------------------------------
 
 def try_gather_rows(mat: jnp.ndarray, idx: jnp.ndarray):

@@ -2,8 +2,7 @@
 
 Device invariant (see ``column.Column``): FLOAT64 columns carry their IEEE754
 *bit pattern* as uint32 [n, 2] (little-endian lo, hi half-words), never a
-float64 array.  Rationale, measured on the target chip (tools/profile runs,
-round 3):
+float64 array.  Rationale, found on the target chip:
 
 * ``lax.bitcast_convert_type`` on float64 fails to compile on XLA:TPU in any
   direction (f64->u32, f64->i64, i64->f64) — the backend emulates f64 and

@@ -14,9 +14,7 @@ Discipline
 * **Cheap enough to never turn off.**  One event is one small dict built
   by the caller and one ``deque.append`` under a lock; record sites are
   per-REQUEST (submit, dequeue, admit, dispatch, resolve), never per-row
-  or per-dispatch-inner-loop.  The ``serve_bench`` overhead measurement
-  (SERVE_BENCH.json ``flight_overhead``) holds the steady-state cost
-  under 2%.
+  or per-dispatch-inner-loop.  On the chip its cost is not measured.
 * **Records are atomic.**  An event dict is fully built before it enters
   the ring and never mutated after; concurrent writers interleave whole
   records, never fields (``tests/test_flight.py`` hammers this from 4+

@@ -274,7 +274,8 @@ register("SRJT_INCIDENT_PER_KIND", "5", _int,
 register("SRJT_SANITIZE", "0", _str,
          "runtime sanitizers: `1` files flight incidents on lock-order "
          "inversions and hot-path retraces, `strict` raises instead "
-         "(CI smokes run strict)", "observability")
+         "(`tests/conftest.py` runs all of tier-1 strict)",
+         "observability")
 register("SRJT_PROFILE", "0", _on_unless_off,
          "per-plan-node runtime profiling (`plan/profile.py`): rows/"
          "bytes/time per executed node, `explain_analyze()` rendering; "
@@ -414,35 +415,6 @@ register("SRJT_STREAM_ALLOW_APPROX", "0", _opt_in,
          "allow approximate incremental states (`1`/`true`/`on` only)",
          "stream")
 
-# tools / benches (registered so the lint gate covers every read; the
-# tools read through this registry too)
-register("SRJT_SERVE_WORKERS", "4", _int,
-         "serve_bench worker count", "tools")
-register("SRJT_QB_METRICS", "1", _on_unless_0_off,
-         "query_bench metrics collection; `0`/`off` disables", "tools")
-register("SRJT_QB_TRACE_DIR", None, _opt_str,
-         "query_bench per-query Chrome-trace export directory", "tools")
-register("SRJT_QB_RESUME", None, _str,
-         "query_bench crash-resume marker (`1` = resume into the "
-         "existing output file)", "tools")
-register("SRJT_QB_TRIES", "0", _int,
-         "query_bench crash-resume attempt counter", "tools")
-register("SRJT_QB_STEADY", "1", _on_unless_0_off,
-         "query_bench steady-state (compiled replay) sweep; `0`/`off` "
-         "skips it", "tools")
-register("SRJT_QB_STEADY_CAP", "10", _float,
-         "query_bench per-query steady-sweep time budget (s)", "tools")
-register("SRJT_QB_EXPLAIN", "0", _is_1,
-         "query_bench records `plan.explain` output per query", "tools")
-register("SRJT_QB_PROFILE", "0", _is_1,
-         "query_bench attaches per-plan-node profiles (`--profile`) to "
-         "QUERY_BENCH.json entries", "tools")
-register("SRJT_QB_SQL", "0", _is_1,
-         "query_bench compiles the TPC-DS mix from `models/tpcds_sql.py` "
-         "SQL text (`--sql`) instead of prebuilt plan trees", "tools")
-register("SRJT_BENCH_BUDGET_S", "1200", _float,
-         "bench.py total wall-clock budget (s)", "tools")
-
 
 # --- README table -----------------------------------------------------------
 
@@ -459,7 +431,6 @@ _SECTION_TITLES = {
     "parquet": "Parquet scan (`parquet/`)",
     "ml": "ML handoff (`ml/`)",
     "stream": "Streaming (`stream/`)",
-    "tools": "Tools & benches",
     "general": "General",
 }
 

@@ -492,8 +492,7 @@ def _walk(spans, fn):
 
 
 def stage_breakdown() -> dict[str, dict]:
-    """Aggregate all completed spans by name: call count, total/max ms —
-    the per-query stage table ``tools/query_bench.py`` emits."""
+    """Aggregate all completed spans by name: call count, total/max ms."""
     agg: dict[str, dict] = {}
 
     def add(s: Span):
@@ -599,7 +598,7 @@ def to_prometheus() -> str:
     built from the log2 buckets, plus ``_sum`` and ``_count`` — so a
     scrape of the serving runtime yields rate()-able latency and
     admission series without any sidecar.  The output is linted against
-    the grammar in CI (``ci/exec_smoke.sh``)."""
+    the grammar in ``tests/test_metrics.py``."""
     with _lock:
         counters = dict(_counters)
         gauges = dict(_gauges)

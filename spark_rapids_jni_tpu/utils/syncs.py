@@ -5,9 +5,8 @@ trip, so a multi-op query plan's wall time is often `sync_count × RTT`
 rather than compute.  Two countermeasures live here:
 
 * :func:`scalar` — the ONE funnel for intentional scalar syncs (group
-  counts, string widths, char totals).  It counts them, so
-  ``tools/query_bench.py`` can report a syncs-per-query figure and
-  regressions are visible.
+  counts, string widths, char totals).  It counts them, so a
+  syncs-per-query figure can be reported and regressions are visible.
 * weak per-array caches (:func:`memo_get` / :func:`memo_put`) keyed on
   device-array identity — dictionary encodes and string widths are pure
   functions of their column payloads, and analytics plans re-touch the

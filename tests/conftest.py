@@ -16,6 +16,10 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
+# The runtime sanitizers raise at the violation site in every suite: a lock
+# samples the mode when it is created, so it is set before the package is
+# imported, and child processes the tests start inherit it.
+os.environ.setdefault("SRJT_SANITIZE", "strict")
 
 import jax  # noqa: E402
 

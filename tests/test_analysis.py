@@ -333,6 +333,18 @@ def test_every_registered_knob_documented():
     assert not missing, f"knobs missing from README: {missing}"
 
 
+def test_every_registered_knob_is_read_by_the_package():
+    # a knob that only a script outside the package reads is that script's
+    # option, not the program's: it does not belong in the registry
+    text = "\n".join(
+        src.text for src in core.collect_sources(
+            REPO, subdirs=("spark_rapids_jni_tpu",))
+        if src.rel != knobpass._KNOBS_REL)
+    unread = [k for k in knobpass.load_registry(REPO)
+              if f'"{k}"' not in text]
+    assert not unread, f"registered, read by no file of the package: {unread}"
+
+
 # --------------------------------------------------------------------------
 # self-clean: the real tree lints to zero modulo the checked-in baseline
 # --------------------------------------------------------------------------

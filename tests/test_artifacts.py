@@ -22,6 +22,8 @@ hold every leg:
 
 import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -245,11 +247,44 @@ def test_plan_cache_zero_capture_from_store(aot_dir):
     assert metrics.counter_value("compiled.capture") == 0
     assert metrics.counter_value("compiled.rehydrate") == 1
     assert metrics.counter_value("exec.plan_cache.aot_hit") == 1
+    assert metrics.counter_value("aot.hit") >= 1      # the store served it
     # the rehydrated plan's ledger carries cold-start attribution
     # (CompiledQuery keys the ledger on the query function's name)
     led = metrics.ledger_snapshot().get("_q_filter", {})
     assert led.get("rehydrates") == 1
     assert "captures" not in led
+
+
+_FRESH = """
+import json, sys
+sys.path.insert(0, {tests!r})
+import test_artifacts as t
+t.metrics.set_enabled(True)
+out = t._canon(t.PlanCache().run("qf", t._q_filter, t._mktab(500)))
+print(json.dumps({{"out": [a.tolist() for a in out],
+                  "capture": t.metrics.counter_value("compiled.capture"),
+                  "rehydrate": t.metrics.counter_value("compiled.rehydrate"),
+                  "aot_hit": t.metrics.counter_value("exec.plan_cache.aot_hit"),
+                  "store_hit": t.metrics.counter_value("aot.hit")}}))
+"""
+
+
+def test_fresh_interpreter_serves_zero_capture_from_store(aot_dir):
+    # the store's keys must not depend on anything of the process that
+    # wrote them (ids, hash seeds): a second interpreter finds the artifact
+    here = os.path.dirname(os.path.abspath(__file__))
+    oracle = _canon(PlanCache().run("qf", _q_filter, _mktab(500)))
+    assert metrics.counter_value("aot.write") == 1
+    proc = subprocess.run(
+        [sys.executable, "-c", _FRESH.format(tests=here)],
+        cwd=os.path.dirname(here), env=dict(os.environ, SRJT_AOT_DIR=aot_dir),
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert (got["capture"], got["rehydrate"], got["aot_hit"]) == (0, 1, 1)
+    assert got["store_hit"] >= 1
+    assert _same(oracle, [np.asarray(a, o.dtype)
+                          for a, o in zip(got["out"], oracle)])
 
 
 def test_scheduler_serves_zero_capture_and_warms_up(aot_dir, monkeypatch):

@@ -98,16 +98,20 @@ def test_staged_scan_bit_identical(raw, eager, monkeypatch, pipeline):
     _assert_tables_identical(eager, staged)
 
 
-def test_staged_scan_coalesces_and_overlaps(raw, monkeypatch):
+def _scan_with_flight(raw_bytes, monkeypatch, env):
+    """(table, flight events of the scan)."""
     was = flight.enabled()
     flight.set_enabled(True)
     flight.reset()
     try:
-        _scan(raw, monkeypatch, {"SRJT_STAGE_SLABS": "1",
-                                 "SRJT_STAGE_PIPELINE": "1"})
-        evs = flight.events()
+        return _scan(raw_bytes, monkeypatch, env), flight.events()
     finally:
         flight.set_enabled(was)
+
+
+def test_staged_scan_coalesces_and_overlaps(raw, monkeypatch):
+    _, evs = _scan_with_flight(raw, monkeypatch, {"SRJT_STAGE_SLABS": "1",
+                                                  "SRJT_STAGE_PIPELINE": "1"})
     flushes = [e for e in evs if e["kind"] == "parquet.stage.flush"]
     assert flushes and sum(e["slabs"] for e in flushes) >= 1
     overlap = [e for e in evs if e["kind"] == "parquet.stage.overlap"]
@@ -329,11 +333,15 @@ def test_forced_donation_strict_sanitizer(raw, eager, monkeypatch):
     from spark_rapids_jni_tpu.analysis import sanitize
     sanitize.reset()
     try:
-        donated = _scan(raw, monkeypatch, {"SRJT_SCAN_DONATE": "1",
-                                           "SRJT_SANITIZE": "strict"})
+        donated, evs = _scan_with_flight(
+            raw, monkeypatch, {"SRJT_SCAN_DONATE": "1",
+                               "SRJT_SANITIZE": "strict"})
     finally:
         sanitize.reset()
     _assert_tables_identical(eager, donated)
+    # the donation engaged, it was not only asked for
+    donates = [e for e in evs if e["kind"] == "parquet.scan.donate"]
+    assert donates and donates[-1]["bytes"] > 0 and donates[-1]["buffers"] >= 1
 
 
 @pytest.mark.slow

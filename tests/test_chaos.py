@@ -159,9 +159,12 @@ def test_ejection_after_repeated_probe_failures():
     oracle = _canon(_q_sum(tables))
     inj = finj.get_injector()
     # probe_base large enough that the first canary fires AFTER the
-    # device-targeted kill rule below is armed (re-arm takes <50 ms)
-    with xc.QueryScheduler(workers=2, devices=2, probe_base_s=0.5,
-                           probe_max_s=0.6, eject_after=2) as sched:
+    # device-targeted kill rule below is armed: the six results between
+    # the fault and the re-arm include a relocated request's compile on
+    # the survivor, which took over 0.5 s on a loaded host (the driver's
+    # run of PR 32's first tree)
+    with xc.QueryScheduler(workers=2, devices=2, probe_base_s=2.0,
+                           probe_max_s=2.4, eject_after=2) as sched:
         # step 1: one-shot untargeted fault downs whichever replica
         # serves; step 2: pin an UNLIMITED rule to that device so its
         # recovery canaries keep failing until ejection

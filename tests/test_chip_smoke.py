@@ -126,8 +126,7 @@ def test_cache_rule_honours_the_environment(tmp_path, monkeypatch):
 
 # --- no device benchmark reports success off a TPU ------------------------------
 
-@pytest.mark.parametrize("script", ["bench.py", "tools/tpu_check.py",
-                                    "chip_smoke.py"])
+@pytest.mark.parametrize("script", ["tools/tpu_check.py", "chip_smoke.py"])
 def test_device_scripts_exit_nonzero_without_a_tpu(script, tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     args = [sys.executable, os.path.join(REPO, script)]

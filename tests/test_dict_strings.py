@@ -312,6 +312,54 @@ def test_empty_dictionary_unit():
     assert np.asarray(mat.offsets).tolist() == [0] * 8
 
 
+# --- a whole query on dictionary codes ---------------------------------------
+
+
+def test_tpcds_string_query_on_codes_bit_identical(monkeypatch):
+    """q_like_brands (scan -> LIKE/prefix predicate -> join -> groupby) over
+    a dictionary-encoded item file: no string byte is touched while the
+    query runs, and the answer is the materialized path's, bit for bit."""
+    import pyarrow.parquet as pq
+    from benchmarks import tpcds_data
+    from spark_rapids_jni_tpu.models import tpcds
+
+    def redict(raw_bytes):         # the generator writes plain pages
+        t = pq.read_table(io.BytesIO(raw_bytes))
+        return _write(dict(zip(t.column_names, t.columns)))
+
+    files = tpcds_data.generate(n_sales=8_000, n_items=300, seed=5)
+    item_raw = redict(files["item"])
+    sales = decode.read_table(files["store_sales"], columns=tpcds.SS_COLS)
+
+    def load():                    # the two tables the query reads
+        return {"store_sales": sales,
+                "item": device_scan.scan_table(item_raw,
+                                               columns=tpcds.ITEM_COLS)}
+
+    brand = tpcds.ITEM_COLS.index("i_brand")
+    on_codes = load()
+    assert as_dict_column(on_codes["item"][brand]) is not None
+    metrics.set_enabled(True)
+    try:
+        before = metrics.snapshot()["counters"]
+        got = tpcds.QUERIES["q_like_brands"](on_codes)
+        after = metrics.snapshot()["counters"]
+    finally:
+        metrics.set_enabled(None)
+    assert after.get("strings.dict.predicate", 0) \
+        > before.get("strings.dict.predicate", 0)
+    assert after.get("strings.dict.materialize", 0) \
+        == before.get("strings.dict.materialize", 0)
+    monkeypatch.setenv("SRJT_DICT_STRINGS", "0")
+    materialized = load()
+    assert as_dict_column(materialized["item"][brand]) is None
+    want = tpcds.QUERIES["q_like_brands"](materialized)
+    assert got.num_rows == want.num_rows > 0
+    _tables_equal(got, want)
+    for a, b in zip(got.columns, want.columns):
+        assert a.dtype.id == b.dtype.id
+
+
 # --- runtime parity: capture/replay + concurrent scheduler ------------------
 
 

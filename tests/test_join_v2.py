@@ -480,6 +480,7 @@ def test_multikey_pack_counters_and_cache_hits():
         _assert_same(a, b)
         c = metrics.snapshot()["counters"]
         assert c["join.pack.composite"] == 1
+        assert "join.pack" in metrics.stage_breakdown()    # its span
         assert c["join.pack.cache_hit"] >= 1
         assert c["join.build_index.cache_hit"] >= 1
     finally:

@@ -250,6 +250,8 @@ def test_tpcds_differential_under_tiny_budget(_tpcds_tables, qname):
         got = tpcds.QUERIES[qname](tables)
     snap = metrics.snapshot()["counters"]
     assert snap.get("arena.spill.events", 0) >= 1, snap
+    # the spill and the query's root are spans, so a trace carries both
+    assert {"arena.spill", f"query:{qname}"} <= set(metrics.stage_breakdown())
 
     budget.set_enabled(False)
     metrics.set_enabled(False)

@@ -359,6 +359,7 @@ def test_compile_ledger_attributes_per_fingerprint(tpcds):
         prom = metrics.to_prometheus()
         assert "srjt_compile_ledger" in prom
         assert f'plan="{qfn.plan_fingerprint}"' in prom
+        assert qfn.plan_fingerprint in metrics.chrome_trace()["srjtLedger"]
     finally:
         metrics.set_enabled(None)
         metrics.reset()
@@ -463,17 +464,3 @@ def test_profile_report_flatten_and_regress(tmp_path):
                     str(tmp_path / "old")]) == 3
     assert pr.main(["pr", str(tmp_path / "old"), "--regress",
                     str(tmp_path / "old")]) == 0
-
-
-def test_bench_history_flattens_artifacts(tmp_path):
-    import tools.bench_history as bh
-    (tmp_path / "X_BENCH.json").write_text(json.dumps(
-        {"benches": {"a": {"wall_s": 1.5, "ok": True, "name": "a"}},
-         "rows": 100}))
-    doc = bh.collect(str(tmp_path))
-    metrics_ = {m["metric"]: m["value"] for m in doc["metrics"]}
-    assert metrics_ == {"benches.a.wall_s": 1.5, "rows": 100.0}
-    assert doc["generated_from"] == ["X_BENCH.json"]
-    assert bh.main(["bh", "--root", str(tmp_path)]) == 0
-    out = json.loads((tmp_path / "BENCH_TRAJECTORY.json").read_text())
-    assert out["metrics"][0]["artifact"] == "X_BENCH.json"

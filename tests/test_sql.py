@@ -243,12 +243,19 @@ def test_trailing_garbage_rejected():
         parse("SELECT i_brand_id FROM item extra garbage here")
 
 
-def test_sql_parse_error_flight_incident():
+@pytest.mark.parametrize("entry", ["sql_to_plan", "submit_sql"])
+def test_sql_parse_error_flight_incident(entry, tables, sched):
     flight.set_enabled(True)
     try:
         base = metrics.counter_value("flight.incident.sql_parse_error")
-        with pytest.raises(SqlError):
-            sql_fe.sql_to_plan("SELECT nope FROM item", SCHEMAS)
+        with pytest.raises(SqlError) as ei:
+            if entry == "submit_sql":      # raised at submission, typed
+                sched.submit_sql("SELECT nope FROM item", tables,
+                                 schemas=SCHEMAS)
+            else:
+                sql_fe.sql_to_plan("SELECT nope FROM item", SCHEMAS)
+        assert (ei.value.line, ei.value.col) == (1, 8)
+        assert "^" in str(ei.value)
         assert metrics.counter_value(
             "flight.incident.sql_parse_error") == base + 1
         evs = [e for e in flight.events(last=20)

@@ -366,6 +366,7 @@ class TestViewRegistry:
             assert metrics.counter_value("stream.delta.rowgroups") - c0 == 1
             _bitcmp(got, _oracle(reg, v), f"epoch{e}")
         assert metrics.counter_value("stream.refresh.incremental") == 2
+        assert metrics.counter_value("stream.view.fallback") == 0
         # re-registering the same plan returns the same view
         assert reg.register_view(_cents_view_plan()) is v
         assert reg.stats()["incremental"] == 1

@@ -358,7 +358,16 @@ def test_run_all_executes_every_query(files):
 # ---- round-6 additions: composite multi-key joins + left-outer fusion ----
 
 def test_q_channel_day(tables, dfs):
-    out = tpcds.q_channel_day(tables)
+    from spark_rapids_jni_tpu.utils import metrics
+    metrics.set_enabled(True)
+    metrics.reset()
+    try:
+        out = tpcds.q_channel_day(tables)
+        counters = metrics.snapshot()["counters"]
+    finally:
+        metrics.set_enabled(False)
+    # the (item_sk, sold_date_sk) tuple took the packed composite path
+    assert counters.get("join.pack.composite", 0) >= 1, counters
     ss, ws, item = dfs["store_sales"], dfs["web_sales"], dfs["item"]
     s_rev = (ss.groupby(["ss_item_sk", "ss_sold_date_sk"], as_index=False)
              ["ss_ext_sales_price"].sum())
@@ -484,14 +493,33 @@ def test_plan_fusion_is_rule_detected(name):
                for ev in res.events)
 
 
-def test_plan_file_catalog_matches_hand_fused(files, tables, dfs):
+@pytest.mark.parametrize("row_group_size", [None, 256])
+def test_plan_file_catalog_matches_hand_fused(files, tables, dfs,
+                                              row_group_size):
     """Lowered Scan nodes read parquet bytes directly (pruned decode);
     results must still be bit-identical to hand kernels over the fully
-    decoded tables."""
+    decoded tables.  The counters show the planner's pushdown reaching the
+    decoder: columns dropped before decode and, where the dimension files
+    have more than one row group, groups dropped by their footer stats."""
+    from spark_rapids_jni_tpu.utils import metrics
+    if row_group_size is not None:
+        files = tpcds_data.generate(n_sales=20_000, n_items=2_000, seed=5,
+                                    row_group_size=row_group_size)
+        tables = tpcds.load_tables(files)
+        dfs = {"item": pd.read_parquet(io.BytesIO(files["item"]))}
     params = _plan_params("q3", dfs)
     res = tpcds_plans.optimized("q3", **params)
-    out = P.execute(res.tree, P.FileCatalog(dict(files)),
-                    record_stats=False)
+    metrics.set_enabled(True)
+    metrics.reset()
+    try:
+        out = P.execute(res.tree, P.FileCatalog(dict(files)),
+                        record_stats=False)
+        counters = metrics.snapshot()["counters"]
+    finally:
+        metrics.set_enabled(False)
+    assert counters.get("plan.scan.columns_pruned", 0) >= 1, counters
+    if row_group_size is not None:
+        assert counters.get("plan.scan.rowgroups_pruned", 0) >= 1, counters
     _assert_bitwise(out, tpcds.q3(tables, **params))
 
 

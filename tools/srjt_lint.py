@@ -44,7 +44,7 @@ from spark_rapids_jni_tpu.analysis import (  # noqa: E402
     concurrency, core, knobpass, tracepass)
 
 SCAN_SUBDIRS = ("spark_rapids_jni_tpu", "tools")
-SCAN_EXTRA = ("bench.py",)
+SCAN_EXTRA = ("chip_smoke.py",)
 BASELINE_DEFAULT = os.path.join("ci", "lint_baseline.json")
 KNOB_TABLE_BEGIN = "<!-- knob-table:begin (generated by tools/srjt_lint.py --knob-table; do not hand-edit) -->"
 KNOB_TABLE_END = "<!-- knob-table:end -->"
