@@ -33,6 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .layout import RowLayout
 
@@ -79,6 +80,12 @@ def _nbits_for(W: int) -> int:
     return b
 
 
+def _select_digit(digit: jnp.ndarray, vs: list) -> jnp.ndarray:
+    return jnp.where(digit == 1, vs[1],
+                     jnp.where(digit == 2, vs[2],
+                               jnp.where(digit == 3, vs[3], vs[0])))
+
+
 def _take_words(m: jnp.ndarray, sh: jnp.ndarray, Wo: int) -> jnp.ndarray:
     """out[r, j] = m[r, sh[r] + j] for j < Wo (zeros beyond the source).
 
@@ -106,9 +113,7 @@ def _take_words(m: jnp.ndarray, sh: jnp.ndarray, Wo: int) -> jnp.ndarray:
             if sl.shape[1] < Wn:
                 sl = jnp.pad(sl, ((0, 0), (0, Wn - sl.shape[1])))
             vs.append(sl)
-        cur = jnp.where(digit == 1, vs[1],
-                        jnp.where(digit == 2, vs[2],
-                                  jnp.where(digit == 3, vs[3], vs[0])))
+        cur = _select_digit(digit, vs)
     return cur[:, :Wo]
 
 
@@ -132,9 +137,7 @@ def _place_words(m: jnp.ndarray, sh: jnp.ndarray, Wo: int) -> jnp.ndarray:
                 continue
             vs.append(jnp.pad(cur[:, :keep],
                               ((0, 0), (k * wk, Wn - k * wk - keep))))
-        cur = jnp.where(digit == 1, vs[1],
-                        jnp.where(digit == 2, vs[2],
-                                  jnp.where(digit == 3, vs[3], vs[0])))
+        cur = _select_digit(digit, vs)
         if last:
             return cur
         wk *= 4
@@ -267,6 +270,126 @@ def _byte_funnel_right(win: jnp.ndarray, rb: jnp.ndarray) -> jnp.ndarray:
         v = (a << jnp.uint32(8 * k)) | (prev >> jnp.uint32(32 - 8 * k))
         fun = jnp.where(rbc == k, v, fun)
     return fun
+
+
+# ---------------------------------------------------------------------------
+# major-axis twins: the same trees with the words on the major axis
+# ---------------------------------------------------------------------------
+#
+# The helpers above shift along the MINOR axis of ``[strings, words]``: a
+# handful of words there is padded to 128 lanes by the (8,128) tiling, so a
+# level of a tree moves 10–30× its data.  These work on ``[words, *strings]``
+# with the per-string shift shaped ``[*strings]``: the strings fill the
+# lanes (and, with two or more string axes, the sublanes), a shift is a
+# slice of the untiled major axis, and a level is elementwise on dense
+# vregs.  Operands may broadcast against the shift along the string axes
+# (one source serving several strings).  The tiled programs (``xtile``) use
+# them; the whole-batch programs keep the minor-axis forms.
+
+def _pin_words_major(x: jnp.ndarray) -> jnp.ndarray:
+    """Hold ``x`` [words, *strings] in row-major order on the device.  The
+    compiler lays intermediates out as it likes, and a transposed gather
+    result it would rather relabel than move, which puts the words back on
+    the lanes; this is where the one physical transpose lands."""
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def _take_words_major(m: jnp.ndarray, sh: jnp.ndarray, Wo: int):
+    """out[j, s] = m[sh[s] + j, s] for j < Wo (zeros beyond the source):
+    :func:`_take_words` with the words on axis 0."""
+    levels = []
+    w = 1
+    while w < m.shape[0]:
+        levels.append(w)
+        w *= 4
+    cur = m
+    for wk in reversed(levels):
+        Wn = Wo + wk - 1
+        digit = ((sh // wk) % 4).astype(jnp.int32)[None]
+        vs = []
+        for k in range(4):
+            sl = cur[k * wk:k * wk + Wn]
+            vs.append(jnp.pad(sl, ((0, Wn - sl.shape[0]),)
+                              + ((0, 0),) * (cur.ndim - 1)))
+        cur = _select_digit(digit, vs)
+    return cur[:Wo]
+
+
+def _place_words_major(m: jnp.ndarray, sh: jnp.ndarray, Wo: int):
+    """out[sh[s] + j, s] = m[j, s] (zeros elsewhere), Wo words:
+    :func:`_place_words` with the words on axis 0."""
+    cur = m
+    wk = 1
+    while True:
+        last = wk * 4 >= Wo
+        Wn = Wo if last else min(cur.shape[0] + 3 * wk, Wo)
+        digit = ((sh // wk) % 4).astype(jnp.int32)[None]
+        vs = []
+        for k in range(4):
+            keep = max(0, min(cur.shape[0], Wn - k * wk))
+            lead = min(k * wk, Wn)
+            vs.append(jnp.pad(cur[:keep], ((lead, Wn - lead - keep),)
+                              + ((0, 0),) * (cur.ndim - 1)))
+        cur = _select_digit(digit, vs)
+        if last:
+            return cur
+        wk *= 4
+
+
+def _byte_mask_major(W: int, start_b: jnp.ndarray, end_b: jnp.ndarray):
+    """u32 mask [W, *strings]: byte positions in [start, end) per string."""
+    pos = (jnp.arange(W, dtype=jnp.int32) * 4).reshape(
+        (W,) + (1,) * start_b.ndim)
+    s, e = start_b[None], end_b[None]
+    m = jnp.zeros((W,) + start_b.shape, jnp.uint32)
+    for k in range(4):
+        inside = ((pos + k) >= s) & ((pos + k) < e)
+        m = m | jnp.where(inside, jnp.uint32(0xFF << (8 * k)), jnp.uint32(0))
+    return m
+
+
+def _roll_left_bytes_major(w: jnp.ndarray, Lw: int, rb: jnp.ndarray):
+    """[Lw+1, *strings] → [Lw, *strings]: each string LEFT by rb∈[0,4)
+    bytes."""
+    a, nxt = w[:Lw], w[1:Lw + 1]
+    rbc = rb.astype(jnp.uint32)[None]
+    out = a
+    for k in (1, 2, 3):
+        v = (a >> jnp.uint32(8 * k)) | (nxt << jnp.uint32(32 - 8 * k))
+        out = jnp.where(rbc == k, v, out)
+    return out
+
+
+def _byte_funnel_right_major(win: jnp.ndarray, rb: jnp.ndarray):
+    """[W, *strings] → [W+1, *strings]: each string RIGHT by rb∈[0,4)
+    bytes."""
+    rest = ((0, 0),) * (win.ndim - 1)
+    a = jnp.pad(win, ((0, 1),) + rest)
+    prev = jnp.pad(win, ((1, 0),) + rest)
+    rbc = rb.astype(jnp.uint32)[None]
+    fun = a
+    for k in (1, 2, 3):
+        v = (a << jnp.uint32(8 * k)) | (prev >> jnp.uint32(32 - 8 * k))
+        fun = jnp.where(rbc == k, v, fun)
+    return fun
+
+
+def _cut_strings_major(src: jnp.ndarray, at_b: jnp.ndarray, Lw: int):
+    """[Lw, *strings]: the ``Lw`` words from byte ``at_b`` of each string's
+    source ``src`` [W, *strings] (bytes past a string's end come along)."""
+    return _roll_left_bytes_major(
+        _take_words_major(src, at_b // 4, Lw + 1), Lw, at_b % 4)
+
+
+def _put_strings_major(piece: jnp.ndarray, len_b: jnp.ndarray,
+                       at_b: jnp.ndarray, Wo: int):
+    """[Wo, *strings]: the first ``len_b`` bytes of each ``piece``
+    [Lw, *strings] at byte ``at_b`` of ``Wo`` words, zeros elsewhere."""
+    piece = piece & _byte_mask_major(piece.shape[0], jnp.zeros_like(len_b),
+                                     len_b)
+    return _place_words_major(_byte_funnel_right_major(piece, at_b % 4),
+                              at_b // 4, Wo)
 
 
 def _words_to_u8(w: jnp.ndarray) -> jnp.ndarray:

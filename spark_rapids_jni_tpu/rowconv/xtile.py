@@ -18,11 +18,23 @@ What differs from the whole-batch programs besides the loop:
   fixed-width engine's word plan (``convert._compose_row_words``) and
   transposed a tile at a time — no byte matrix, no per-column
   ``.at[].set``;
-* all string columns share one geometry and one body: their rows are
-  flattened into one row axis (``[nvar * T, …]``), so a tile runs one
-  extract → funnel → place chain whatever the column count, and the
-  chars of a row — contiguous in column order — are placed into a frame
-  as wide as the chars region, not as the row;
+* all string columns share one geometry and one body, and the body keeps
+  the strings on the lanes: a tile's strings are ordered (row of its group,
+  column, group) and every per-string step — cutting a string's window out
+  of its group's slab, the byte roll, the length mask, the funnel, placing
+  it at its byte — works on ``u32[words, g, nvar * T // g]`` with the shift
+  a slice of the major axis (``xpack``'s ``*_major`` helpers), so a level of
+  a tree moves its data once instead of 128 lanes a handful of words.
+  ``to_rows`` transposes the gathered slabs once (``[groups, B/2]`` →
+  ``[B/2, groups]``), ORs the columns' placed chars into ``[Cw, g, T // g]``
+  and transposes that once into the rows' chars frames ``[T, Cw]`` — as
+  wide as the chars region, not as the row.  ``from_rows`` transposes the
+  rows' chars frames once (``[T, Cw]`` → ``[Cw, g, T // g]``, one frame
+  serving every column, never copied per column), accumulates each group's
+  chars into ``[Bd, nl * T // g]`` and transposes that once into the group
+  rows the window combine gathers.  Each transpose is pinned
+  (``xpack._pin_words_major``): the compiler would otherwise relabel it
+  and put the words back on the lanes;
 * ``from_rows`` is two programs around the one sync it cannot avoid (the
   output's shapes are the per-column char totals): the first transposes
   every row's fixed region into ``u32[words, n]``, decodes the fixed
@@ -40,16 +52,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from .layout import RowLayout
-from .xpack import (WIN_W, _bucket, _byte_funnel_right, _byte_mask,
-                    _combine_to_words, _pad_to_blocks, _place_words, _reject,
-                    _roll_left_bytes, _take_words, pack_windows)
+from .xpack import (WIN_W, _bucket, _byte_funnel_right, _cut_strings_major,
+                    _group_windows_words, _pad_to_blocks, _pin_words_major,
+                    _put_strings_major, _reject, _take_words, pack_windows)
 
 # u32 words of padded rows a tile holds ([T, Mw]): 16 MiB, 8192 rows of the
-# strings table.  Its round trip on a v5e by rows a tile (PR 28, PERF.md
-# §6): 32768 2.38 s, 16384 2.17, 8192 1.89, 4096 1.76, 2048 1.77, 1024 1.71.
-# Not the fastest: a program runs some hundreds of device ops a tile, and at
-# 2048 rows a tile a 51-s traced window makes twice the device events the
-# profiler keeps (the trace ends at 23 s); at 8192 it holds them all.
+# strings table.  `transcode_gbps` of the strings cell on a v5e by rows a
+# tile, 51-s windows (my chip run, PR 33; PERF.md §6): 16384 1.93 (traced),
+# 8192 2.18 (traced and not), 4096 2.28 (untraced).  Not the fastest: a
+# traced window at 8192 makes 5.5 M device-op events, which the profiler
+# keeps to the window's end; half the rows a tile doubles them for 4.7%,
+# under the metric's bound, and before PR 33 a window at 2048 rows a tile
+# made twice the events the profiler kept (its trace ended at 23 s: PR 28).
+# Before PR 33 a round trip took 2.38 s at 32768 rows a tile, 2.17 at 16384,
+# 1.89 at 8192, 1.76 at 4096, 1.77 at 2048, 1.71 at 1024 (PR 28).
 TILE_WORDS = 1 << 22
 ROW_QUANTUM = 128           # T is a multiple: of every group size, of a lane
 GROUP = 8                   # rows a char slab gather covers (to_rows)
@@ -135,6 +151,38 @@ def plan_to_rows(layout: RowLayout, n: int, offs_np: np.ndarray,
     return (n, Mw, tile_rows(n, Mw), B, Lw, total // 4)
 
 
+def _string_lanes(x: jnp.ndarray, g: int) -> jnp.ndarray:
+    """Per-(column, row) values [c, T] → [g, c * T // g]: the tile's strings
+    as the major-axis helpers want them, row ``j`` of a group of ``g`` on
+    sublane ``j``, the groups on the lanes in (column, group) order."""
+    c, T = x.shape
+    return x.reshape(c, T // g, g).transpose(2, 0, 1).reshape(g, c * (T // g))
+
+
+def _chars_frame(blocks, at, lt, pos, B: int, Lw: int, Cw: int):
+    """[T, Cw]: every string of a tile at its byte of the row's chars frame.
+    ``at``: the strings' byte offsets in the joined chars ``blocks`` holds,
+    ``lt`` their lengths, ``pos`` their bytes in the frame, [nvar, T] each.
+    One slab gather a group of ``GROUP`` rows of a column, transposed once;
+    from there the words run along the major axis (``[words, GROUP, nvar *
+    T // GROUP]``) through the cut (take, roll) and the put (mask, funnel,
+    place), the columns are OR-ed and the frame is transposed back into
+    rows."""
+    nvar, T = at.shape
+    ng = T // GROUP
+    starts = _string_lanes(at, GROUP)
+    blk = starts[0] // B
+    slab = _pin_words_major(
+        blocks[jnp.clip(blk, 0, blocks.shape[0] - 1)].T)      # [B / 2, groups]
+    piece = _cut_strings_major(slab[:, None], starts - blk * B, Lw)
+    placed = _put_strings_major(piece, _string_lanes(lt, GROUP),
+                                _string_lanes(pos, GROUP), Cw)
+    frame = _pin_words_major(functools.reduce(
+        jnp.bitwise_or,
+        [placed[:, :, vi * ng:(vi + 1) * ng] for vi in range(nvar)]))
+    return frame.transpose(2, 1, 0).reshape(T, Cw)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def to_rows_jit(layout: RowLayout, geom, datas, str_offsets, valid):
     """``(u32 row words [total_w], int32 row byte offsets [n + 1])``.
@@ -199,35 +247,13 @@ def to_rows_jit(layout: RowLayout, geom, datas, str_offsets, valid):
         dense = jnp.pad(jnp.stack(words, axis=0).T,
                         ((0, 0), (0, Mw - fpvw)))             # [T, Mw]
         if with_chars:
-            dense = dense | jnp.pad(_chars_frame(ot + bases, lt, pt),
-                                    ((0, 0), (cbase, 0)))
+            dense = dense | jnp.pad(
+                _chars_frame(blocks, ot[:, :T] + bases, lt, pt + fpv % 4,
+                             B, Lw, Cw), ((0, 0), (cbase, 0)))
         dw = jax.lax.dynamic_slice_in_dim(dst_w, r0, T + 1)
         packed = pack_windows(dense, dw - dw[0], T * Mw, 2,
                               -(-T * Mw // WIN_W))
         return jax.lax.dynamic_update_slice(out, packed, (dw[0],))
-
-    def _chars_frame(at, lt, pt):
-        """[T, Cw]: every string of the tile at its byte of the row's chars
-        frame (which starts at word ``cbase`` of the row).  ``at``: the
-        strings' byte offsets in the joined chars, [nvar, T + 1]."""
-        g = GROUP
-        ng = T // g
-        starts = at[:, :T].reshape(nvar * ng, g)
-        blk = starts[:, 0] // B
-        slab = blocks[jnp.clip(blk, 0, blocks.shape[0] - 1)]
-        pieces = []
-        for j in range(g):
-            amt = starts[:, j] - blk * B                      # [0, 2B)
-            w = _take_words(slab, amt // 4, Lw + 1)
-            pieces.append(_roll_left_bytes(w, Lw, amt % 4))
-        piece = jnp.stack(pieces, axis=1).reshape(nvar * T, Lw)
-        ln = lt.reshape(-1)
-        piece = piece & _byte_mask(Lw, jnp.zeros_like(ln), ln)
-        pos = (fpv % 4) + pt.reshape(-1)
-        placed = _place_words(_byte_funnel_right(piece, pos % 4), pos // 4,
-                              Cw)
-        return functools.reduce(jnp.bitwise_or,
-                                list(placed.reshape(nvar, T, Cw)))
 
     out = jax.lax.fori_loop(0, ntiles, tile,
                             jnp.zeros((total_w + T * Mw,), jnp.uint32))
@@ -340,6 +366,30 @@ def plan_from_rows_chars(layout: RowLayout, geom_fixed, stats: np.ndarray):
     return _reject("from_rows_col_caps", Bd=Bd, P=P)
 
 
+def _group_chars(frame, rel, ln, dst, dstg, g: int, Lw: int, Bd: int):
+    """[nl * T // g, Bd]: the chars of each group of ``g`` rows of a column
+    at their bytes of the group's stretch of the char stream.  ``frame``:
+    the rows' chars frames [T, Cw]; ``rel`` a string's byte in its frame,
+    ``ln`` its length, ``dst`` its byte of the stream, [nl, T] each;
+    ``dstg`` the groups' first bytes, [nl * T // g (+ 1)].  The frame is
+    transposed once and serves every column from there (no [nl * T, Cw]
+    copy); the words run along the major axis (``[words, g, nl * T // g]``)
+    through the cut and the put, the ``g`` rows are OR-ed and the groups
+    transposed back into rows."""
+    nl, T = rel.shape
+    ng = T // g
+    frame_t = _pin_words_major(
+        frame.reshape(ng, g, frame.shape[1]).transpose(2, 1, 0))
+    piece = _cut_strings_major(
+        frame_t[:, :, None], _string_lanes(rel, g).reshape(g, nl, ng),
+        Lw).reshape(Lw, g, nl * ng)
+    placed = _put_strings_major(
+        piece, _string_lanes(ln, g),
+        _string_lanes(dst, g) - dstg[None, :nl * ng], Bd)     # [Bd, g, groups]
+    return _pin_words_major(jax.lax.reduce(
+        placed, np.uint32(0), jax.lax.bitwise_or, (1,))).T
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def from_rows_chars_jit(layout: RowLayout, geom, words, offs, slots,
                         out_offs):
@@ -365,19 +415,17 @@ def from_rows_chars_jit(layout: RowLayout, geom, words, offs, slots,
         frame = _row_windows(
             blocks, jax.lax.dynamic_slice_in_dim(offs_w, r0, T) + cbase, Cw)
         st = jax.lax.dynamic_slice(slots, (0, 0, r0), (nl, 2, T))
-        rel = jnp.clip(st[:, 0] - cbase * 4, 0, Cw * 4).reshape(-1)
+        rel = jnp.clip(st[:, 0] - cbase * 4, 0, Cw * 4)
         ln = st[:, 1]                                         # [nl, T]
-        src = jnp.broadcast_to(frame[None], (nl, T, Cw)).reshape(nl * T, Cw)
-        piece = _roll_left_bytes(_take_words(src, rel // 4, Lw + 1), Lw,
-                                 rel % 4)
         # a column's bytes of the tile start at its S: rows past n start at
         # the next column's, where empty groups cost no window a pass
         live_row = (r0 + jnp.arange(T)) < n
         dst = jnp.where(live_row[None], jnp.cumsum(ln, axis=1) - ln, S) + col0
-        dst = jnp.concatenate([dst.reshape(-1),
-                               jnp.full((1,), nl * S, jnp.int32)])
-        stream = _combine_to_words(piece, ln.reshape(-1), dst, nl * T, g, Bd,
-                                   P, nl * S // 512)
+        dstg = jnp.concatenate([dst[:, ::g].reshape(-1),
+                                jnp.full((1,), nl * S, jnp.int32)])
+        acc = _group_chars(frame, rel, ln, dst, dstg, g, Lw, Bd)
+        stream = _group_windows_words(acc, dstg, nl * (T // g), Bd, P,
+                                      nl * S // 512)
         out = []
         for k, buf in enumerate(bufs):
             base = jax.lax.dynamic_slice(out_offs, (k, r0), (1, 1))[0, 0]

@@ -26,13 +26,67 @@ CASES = {
 }
 
 
+# row tiles of 256 rows (``TILE_WORDS`` patched to 320 * 256): what a tile's
+# strings look like, by row, for the bodies that keep the strings on the
+# lanes.  ``lengths(rng, n)`` redraws every string column's lengths.
+TILE = 256
+
+
+def _drawn_lengths(rng, n):
+    return datagen_strings.string_lengths(rng, n, DRAWN)
+
+
+def _one_tile(value):
+    def lengths(rng, n):
+        ln = _drawn_lengths(rng, n)
+        ln[TILE:2 * TILE] = value
+        return ln
+    return lengths
+
+
+def _rare_byte(rng, n):
+    ln = np.zeros(n, np.int64)
+    ln[1::4] = 1
+    return ln
+
+
+TILE_CASES = {
+    # case: (rows, lengths, rows a from_rows group holds)
+    "second_tile_all_empty": (1000, _one_tile(0), 8),
+    "second_tile_all_32_bytes": (1000, _one_tile(32), 8),
+    # five live rows in the last tile: its first group is part padding
+    "last_tile_five_rows": (3 * TILE + 5, _drawn_lengths, 8),
+    # short strings: a 512 B stretch of a char stream holds more groups of
+    # 8 rows than the combine takes, so the groups grow to 32 and 128 rows
+    # and a group's rows spread over that many sublanes of the lane order
+    "one_byte_strings": (1000, lambda rng, n: np.ones(n, np.int64), 32),
+    "rare_one_byte_strings": (2100, _rare_byte, 128),
+    "null_string_columns": (1000, _drawn_lengths, 8),
+}
+
+
 def make(n, case, seed=28):
-    columns = datagen_strings.strings_columns(n, COLUMNS, seed, 3, 0.9,
-                                              CASES[case])
+    spec = CASES.get(case, DRAWN)
+    columns = datagen_strings.strings_columns(n, COLUMNS, seed, 3, 0.9, spec)
     if case == "null_string_column":
         name, values, _ = columns[9]
         assert name == "string"
         columns[9] = (name, values, np.zeros(n, bool))
+    if case in TILE_CASES:
+        rng = np.random.default_rng(seed + 1)
+        lengths = TILE_CASES[case][1]
+        for ci, (name, _, valid) in enumerate(columns):
+            if name != "string":
+                continue
+            offsets = np.zeros(n + 1, np.int32)
+            np.cumsum(lengths(rng, n), out=offsets[1:])
+            chars = rng.integers(32, 127, int(offsets[-1]), dtype=np.uint8)
+            if case == "null_string_columns" and ci in (9, 39, 69):
+                # all null, every other row null, drawn: the bytes travel
+                valid = {9: np.zeros(n, bool), 39: np.arange(n) % 2 == 0,
+                         69: valid}[ci]
+                assert valid is not None
+            columns[ci] = (name, (offsets, chars), valid)
     return columns, transcode_strings.build_table(columns)
 
 
@@ -127,3 +181,24 @@ def test_strings155_batch_over_several_row_tiles(monkeypatch):
     for name in ("convert_to_rows", "convert_from_rows"):
         plan = find(find(roots, name), "rowconv.var.plan")[0]["attrs"]
         assert plan["tiles"] == 4
+
+
+@pytest.mark.parametrize("case", list(TILE_CASES))
+def test_strings155_tiles_of_unlike_strings(case, monkeypatch):
+    """What the lane-ordered bodies can get wrong and row-ordered ones
+    could not: a tile that differs from its neighbours, a group that is
+    part padding, groups of 32 and 128 rows, null strings — bytes against
+    the plain packers, then the round trip."""
+    monkeypatch.setattr(xtile, "TILE_WORDS", 384 * TILE)
+    planned = []
+    plan = xtile.plan_from_rows_chars
+    monkeypatch.setattr(
+        xtile, "plan_from_rows_chars",
+        lambda *a: planned.append(plan(*a)) or planned[-1])
+    n, _, group = TILE_CASES[case]
+    _, _, roots = round_trip_checked(n, case)
+    for name in ("convert_to_rows", "convert_from_rows"):
+        attrs = find(find(roots, name), "rowconv.var.plan")[0]["attrs"]
+        assert attrs["tiles"] == -(-n // TILE)
+    (geom,) = planned
+    assert geom[2] == TILE and geom[5] == group

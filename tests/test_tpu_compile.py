@@ -16,6 +16,7 @@ it with monkeypatch where the TPU branch is the one that must compile.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -200,6 +201,59 @@ def test_xpack_to_rows_program(one_chip):
              tuple(table[ci].offsets for ci in var_idx),
              tuple(c.validity for c in table.columns),
              statics=(layout, geom))
+
+
+def _minor_to_major(text, shape):
+    """The layouts the compiled program gives arrays of ``shape``, as the
+    HLO text writes them (minor to major)."""
+    dims = ",".join(str(d) for d in shape)
+    return set(re.findall(rf"u32\[{dims}\]\{{([0-9,]+)", text))
+
+
+def test_xtile_strings_levels_stay_words_major(one_chip):
+    """The tiled strings programs at the cell's tile (8192 rows, 15 string
+    columns of 0-32 B among 155): they compile for the chip, and every level
+    of the per-string trees keeps the words on the untiled major axis —
+    left alone, the compiler relabels the transposed slab gather and puts
+    them back on the lanes, padded to 128 (PERF.md §6, PR 33)."""
+    import functools
+    from chipbench import datagen_strings
+    from chipbench.drivers import transcode_strings
+    from spark_rapids_jni_tpu.rowconv import xtile
+    from spark_rapids_jni_tpu.utils import hostcache
+    n = 2 * 8192
+    table = transcode_strings.build_table(datagen_strings.strings_columns(
+        n, 155, 33, 3, 0.9, {"dist": "normal", "lo": 0, "hi": 32}))
+    layout = compute_row_layout(table.schema)
+    var_idx = layout.variable_column_indices
+    col_offs = [hostcache.host_i64(table[ci].offsets) for ci in var_idx]
+    lens = np.zeros(n, np.int64)
+    for o in col_offs:
+        lens += o[1:] - o[:-1]
+    batches = build_batches(row_sizes_with_strings(layout, lens),
+                            MAX_BATCH_BYTES)
+    geom = xtile.plan_to_rows(layout, n, batches.row_offsets_within_batch[0],
+                              col_offs)
+    assert geom is not None and geom[1:3] == (320, 8192), geom
+    text = _compile(one_chip, xtile.to_rows_jit,
+                    tuple(c.data for c in table.columns),
+                    tuple(table[ci].offsets for ci in var_idx),
+                    tuple(c.validity for c in table.columns),
+                    statics=(layout, geom)).as_text()
+    strings = (xtile.GROUP, 15 * 8192 // xtile.GROUP)
+    for words in (geom[4] + 1, 98):        # the narrowest level, the widest
+        assert _minor_to_major(text, (words,) + strings) == {"2,1,0"}, words
+
+    # from_rows' per-string stage alone (its whole program compiles for a
+    # minute and a half): groups of 8 rows, windows of 10 words, Bd 80
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    text = _compile(
+        one_chip,
+        jax.jit(functools.partial(xtile._group_chars, g=8, Lw=10, Bd=80)),
+        jnp.zeros((8192, 98), jnp.uint32), i32((15, 8192)), i32((15, 8192)),
+        i32((15, 8192)), i32((15 * 1024 + 1,))).as_text()
+    for words in (10, 74):
+        assert _minor_to_major(text, (words,) + strings) == {"2,1,0"}, words
 
 
 # --- scan: the q6 columns at 6M rows, and the f64 bits boundary -----------------
