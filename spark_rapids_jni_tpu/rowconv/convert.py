@@ -154,6 +154,7 @@ def _from_bytes_dt(b: jnp.ndarray, dt) -> jnp.ndarray:
 # direction lost 7-11x once n was no power of two and went (PERF.md §6, PR
 # 29).  ROADMAP S8 asks for the measurement that keeps one form.
 _IL_PERM3_MAX_W = 40
+_LANE = 128
 
 
 def _interleave_words(words: list[jnp.ndarray], W: int) -> jnp.ndarray:
@@ -186,16 +187,33 @@ def _deinterleave_words(flat: jnp.ndarray, W: int) -> jnp.ndarray:
     return flat.reshape(-1, W).T
 
 
-@jax.jit
-def _words_to_bytes(w: jnp.ndarray) -> jnp.ndarray:
-    """u32 [N] → u8 [4N] little-endian (byte-boundary use only)."""
-    return jax.lax.bitcast_convert_type(w, jnp.uint8).reshape(-1)
+# The two byte-boundary conversions keep 128 words (512 bytes) on the lanes:
+# ``bitcast_convert_type`` between u32 [N] and u8 [N, 4] builds an array whose
+# minor axis is 4, which the (8,128) tiling pads 32x — 68.7 GB for a batch of
+# 2,147,466,240 B, refused by the compiler (compile rehearsal, PR 34).
+
+def _words_to_u8(w: jnp.ndarray) -> jnp.ndarray:
+    """u32 [N] → u8 [4N] little-endian (elementwise)."""
+    pad = (-w.shape[0]) % _LANE
+    w2 = jnp.pad(w, (0, pad)).reshape(-1, _LANE)
+    out = jnp.zeros((w2.shape[0], 4 * _LANE), jnp.uint8)
+    for k in range(4):
+        out = out.at[:, k::4].set(((w2 >> (8 * k)) & 0xFF).astype(jnp.uint8))
+    return out.reshape(-1)[:w.shape[0] * 4]
+
+
+_words_to_bytes = jax.jit(_words_to_u8)   # byte-boundary use only
 
 
 @jax.jit
 def _bytes_to_words(b: jnp.ndarray) -> jnp.ndarray:
     """u8 [4N] → u32 [N] little-endian."""
-    return jax.lax.bitcast_convert_type(b.reshape(-1, 4), jnp.uint32)
+    n = b.shape[0] // 4
+    b2 = jnp.pad(b, (0, (-n) % _LANE * 4)).reshape(-1, 4 * _LANE)
+    acc = b2[:, 0::4].astype(jnp.uint32)
+    for k in range(1, 4):
+        acc = acc | (b2[:, k::4].astype(jnp.uint32) << jnp.uint32(8 * k))
+    return acc.reshape(-1)[:n]
 
 
 def _word_plan(layout: RowLayout):
@@ -395,15 +413,21 @@ def _decode_row_columns(layout: RowLayout, word, n: int):
 # validity-matrix build, word compose, interleave, offsets arange — is one
 # jit program and the only transfer is the column payloads already in HBM.
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
 def _to_rows_fixed_full(layout: RowLayout, has_valid: tuple[bool, ...],
+                        lo: int, hi: int,
                         datas: tuple[jnp.ndarray, ...],
                         valids: tuple[jnp.ndarray, ...]):
-    """Fixed-width table → (flat u32 row words, int32 row offsets), one
-    dispatch.  ``valids`` carries arrays only for columns where ``has_valid``
-    is True; all-valid columns get their ones generated (and fused away)
-    on device."""
-    n = datas[0].shape[0]
+    """Rows ``[lo, hi)`` of a fixed-width table → (flat u32 row words, int32
+    row offsets), one dispatch.  The whole columns come in and the batch's
+    rows are cut here, where the cut fuses into the staging: a batch of a
+    table that takes several reads the resident columns in place, and the
+    whole table's (``lo, hi == 0, n``) lowers to no cut at all.  ``valids``
+    carries arrays only for columns where ``has_valid`` is True; all-valid
+    columns get their ones generated (and fused away) on device."""
+    datas = tuple(d[lo:hi] for d in datas)
+    valids = tuple(v[lo:hi] for v in valids)
+    n = hi - lo
     vi = iter(valids)
     cols_valid = [next(vi) if hv else jnp.ones((n,), dtype=jnp.bool_)
                   for hv in has_valid]
@@ -791,23 +815,36 @@ def convert_to_rows(table: Table,
         # mirrored by layout.build_batches): split while the remainder
         # overflows the cap, rounding each split to a 32-row multiple only
         # when more than one multiple fits; the final batch is never rounded.
-        boundaries = [0]
-        while (n - boundaries[-1]) * stride > max_batch_bytes:
-            k = max_batch_bytes // stride
-            if k > BATCH_ROW_MULTIPLE:
-                k = k // BATCH_ROW_MULTIPLE * BATCH_ROW_MULTIPLE
-            boundaries.append(boundaries[-1] + k)
-        boundaries.append(n)
+        with metrics.span("rowconv.fixed.prepare") as prepare:
+            boundaries = [0]
+            while (n - boundaries[-1]) * stride > max_batch_bytes:
+                k = max_batch_bytes // stride
+                if k > BATCH_ROW_MULTIPLE:
+                    k = k // BATCH_ROW_MULTIPLE * BATCH_ROW_MULTIPLE
+                boundaries.append(boundaries[-1] + k)
+            boundaries.append(n)
+            # every batch's program takes the whole resident columns and
+            # cuts its rows itself: one argument list a call, nothing sliced
+            # (so nothing dispatched or copied) out here
+            cols = table.columns
+            has_valid = tuple(c.validity is not None for c in cols)
+            datas = tuple(c.data for c in cols)
+            valids = tuple(c.validity for c in cols if c.validity is not None)
+            if prepare is not None:
+                held = {id(leaf) for c in cols
+                        for leaf in (c.data, c.validity)}
+                prepare.annotate(
+                    batches=len(boundaries) - 1,
+                    eager_ops=sum(id(a) not in held for a in datas + valids))
         out = []
-        has_valid = tuple(c.validity is not None for c in table.columns)
-        for lo, hi in zip(boundaries[:-1], boundaries[1:]):
-            cols = (table.columns if (lo, hi) == (0, n)
-                    else [_slice_column(c, lo, hi) for c in table.columns])
-            data, offsets = _to_rows_fixed_full(
-                layout, has_valid,
-                tuple(c.data for c in cols),
-                tuple(c.validity for c in cols if c.validity is not None))
+        for bi, (lo, hi) in enumerate(zip(boundaries[:-1], boundaries[1:])):
+            with metrics.span("rowconv.fixed.launch", direction="to",
+                              batch=bi, rows=hi - lo,
+                              bytes=(hi - lo) * stride):
+                data, offsets = _to_rows_fixed_full(layout, has_valid, lo, hi,
+                                                    datas, valids)
             out.append(RowBatch(data, offsets))
+        metrics.count("rowconv.fixed.batches.to", len(out))
         _record_transcode("rowconv.to_rows", n, out)
         return out
 
@@ -994,7 +1031,10 @@ def convert_from_rows(batch: RowBatch, schema: Sequence[T.DType]) -> Table:
                 f"describe {n} rows of {layout.fixed_row_size} bytes")
         words = (batch.data if batch.data.dtype == jnp.uint32
                  else _bytes_to_words(batch.data))
-        datas, valids = _from_rows_fixed_full(layout, words)
+        with metrics.span("rowconv.fixed.launch", direction="from", rows=n,
+                          bytes=batch.num_bytes):
+            datas, valids = _from_rows_fixed_full(layout, words)
+        metrics.count("rowconv.fixed.batches.from")
         cols = [Column(dt, datas[ci], validity=valids[ci])
                 for ci, dt in enumerate(schema)]
         return Table(cols)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from .convert import _words_to_u8
 from .layout import RowLayout
 
 LANE = 128
@@ -390,16 +391,6 @@ def _put_strings_major(piece: jnp.ndarray, len_b: jnp.ndarray,
                                      len_b)
     return _place_words_major(_byte_funnel_right_major(piece, at_b % 4),
                               at_b // 4, Wo)
-
-
-def _words_to_u8(w: jnp.ndarray) -> jnp.ndarray:
-    """u32 [N] → u8 [4N] little-endian (elementwise)."""
-    pad = (-w.shape[0]) % LANE
-    w2 = jnp.pad(w, (0, pad)).reshape(-1, LANE)
-    out = jnp.zeros((w2.shape[0], 4 * LANE), jnp.uint8)
-    for k in range(4):
-        out = out.at[:, k::4].set(((w2 >> (8 * k)) & 0xFF).astype(jnp.uint8))
-    return out.reshape(-1)[:w.shape[0] * 4]
 
 
 # ---------------------------------------------------------------------------

@@ -141,20 +141,25 @@ def _fixed_axis(n_cols, n=1_000_000):
 
 @pytest.mark.parametrize("n_cols", [12, 212])
 def test_fixed_to_rows_program(one_chip, n_cols):
-    layout, has_valid, datas, valids, _ = _fixed_axis(n_cols)
+    layout, has_valid, datas, valids, n = _fixed_axis(n_cols)
     _compile(one_chip, convert._to_rows_fixed_full, datas, valids,
-             statics=(layout, has_valid))
+             statics=(layout, has_valid, 0, n))
+
+
+def _cell_config(name):
+    """One of chipbench's fixed-width configurations (the reference
+    benchmark's nine-type cycle): ``(config, schema)``."""
+    import json
+    with open(os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                           "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    return cfg, [getattr(sr, cfg["type_cycle"][i % len(cfg["type_cycle"])])
+                 for i in range(cfg["columns"])]
 
 
 def _cell_layout():
-    """chipbench's fixed155_roundtrip: the reference benchmark's 155-column
-    nine-type cycle, 1<<20 rows."""
-    import json
-    with open(os.path.join(os.path.dirname(__file__), "..", "chipbench",
-                           "configs", "nvbench_fixed155_1m.json")) as f:
-        cfg = json.load(f)
-    schema = [getattr(sr, cfg["type_cycle"][i % len(cfg["type_cycle"])])
-              for i in range(cfg["columns"])]
+    """chipbench's fixed155_roundtrip: 155 columns, 1<<20 rows."""
+    cfg, schema = _cell_config("nvbench_fixed155_1m")
     return compute_row_layout(schema), cfg["rows"]
 
 
@@ -172,6 +177,61 @@ def test_fixed_from_rows_program(one_chip, shape):
     # at the cell's shape before PR 29)
     assert f"[{n},1]{{1,0:T(8,128)" not in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def _batches_cell():
+    """chipbench's fixed212_roundtrip: the 212-column table at 2<<20 rows,
+    in the two batches the reference's rule cuts it into."""
+    cfg, schema = _cell_config("nvbench_fixed212_2m")
+    n = cfg["rows"]
+    datas = tuple(_s((n,), dt.storage) for dt in schema)
+    has_valid = tuple(i % cfg["null_every"] == 0
+                      for i in range(cfg["columns"]))
+    valids = tuple(_s((n,), jnp.bool_) for hv in has_valid if hv)
+    return (compute_row_layout(schema), has_valid, datas, valids,
+            [tuple(b["rows"]) for b in cfg["derived"]["batches"]])
+
+
+# argument + output + temporary bytes of each program (compile rehearsal,
+# PR 34); beside ``from_rows`` of the first batch the cell also keeps the
+# table (1.684 GB) and the second batch (0.285 GB): 10.9 GB of the ~14.4 a
+# v5e leaves a program
+BATCH_PROGRAM_BYTES = {("to", 0): 8_932_038_656, ("to", 1): 2_640_654_336,
+                       ("from", 0): 8_932_339_200, ("from", 1): 1_189_716_480}
+
+
+@pytest.mark.parametrize("direction,batch", list(BATCH_PROGRAM_BYTES))
+def test_fixed_batch_programs_at_the_cells_shapes(one_chip, direction,
+                                                  batch):
+    layout, has_valid, datas, valids, bounds = _batches_cell()
+    lo, hi = bounds[batch]
+    assert (hi - lo) * layout.fixed_row_size < 2**31
+    if direction == "to":
+        c = _compile(one_chip, convert._to_rows_fixed_full, datas, valids,
+                     statics=(layout, has_valid, lo, hi))
+    else:
+        words = _s(((hi - lo) * layout.fixed_row_size // 4,), jnp.uint32)
+        c = _compile(one_chip, convert._from_rows_fixed_full, words,
+                     statics=(layout,))
+    ma = c.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    # the first batch's two are the largest; with the table and the other
+    # batch resident they have to leave room under 14.4 GB
+    assert need + 1_684_013_056 + 285_230_080 < 14.4e9
+    assert abs(need - BATCH_PROGRAM_BYTES[direction, batch]) < 0.05 * need
+
+
+def test_byte_views_of_the_largest_batch(one_chip):
+    """``RowBatch.device_u8`` and ``convert_from_rows`` of a u8 batch at
+    2,147,466,240 B: a bitcast between u32 [N] and u8 [N, 4] pads its
+    minor axis 32x (68.7 GB; refused before PR 34)."""
+    n_words = 1851264 * 290
+    for jitted, arg in ((convert._words_to_bytes, _s((n_words,), jnp.uint32)),
+                        (convert._bytes_to_words,
+                         _s((4 * n_words,), jnp.uint8))):
+        c = _compile(one_chip, jitted, arg)
+        assert c.memory_analysis().temp_size_in_bytes < 6 << 30
 
 
 # --- xpack: the strings engine, strings_mixed12 schema --------------------------
