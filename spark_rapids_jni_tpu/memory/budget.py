@@ -50,7 +50,10 @@ from ..analysis import sanitize
 from ..utils import flight, knobs, metrics
 
 # pair-expansion working set per output pair in ops/join.py: pair_ids,
-# left_idx, within, r_pos, right_idx int64 lanes + the matched mask
+# left_idx, within, r_pos, right_idx int64 lanes + the matched mask.  What
+# finding the pairs' owners holds besides is not a per-pair figure (12 B a
+# probe row and one chunk of gathered rows): ops/select.py temp_bytes, which
+# the join site adds to its reservation.
 PAIR_EXPANSION_BYTES = 40
 _HEADROOM = 4.0
 _FLOOR_BYTES = 64 << 20

@@ -19,6 +19,7 @@ import numpy as np
 
 from .. import types as T
 from ..column import Column, DictColumn, Table, as_dict_column
+from .select import owners
 
 
 def _segment_gather(offs: jnp.ndarray, idx: jnp.ndarray):
@@ -89,15 +90,18 @@ def sized_nonzero(mask: jnp.ndarray, n_keep: int) -> jnp.ndarray:
 
     Every dynamic-size site is two-phase (count sync, then sized
     selection), so by the time this runs the mask is usually concrete —
-    and then a host ``np.flatnonzero`` is a single linear pass, where the
-    XLA sized-nonzero lowering routes through a full sort (~100ms on a
-    2M-row mask on CPU, dwarfing the gathers it feeds).  Under a trace
-    (capture/replay) the mask is a tracer and the jittable lowering is
-    the only option; parity is preserved — same ascending order, same
-    zero padding when the clamped size exceeds the population count.
+    and then a host ``np.flatnonzero`` is a single linear pass.  Under a
+    trace (capture/replay) the mask is a tracer and the selection has to
+    be jittable: "which row owns output j" with the mask as the counts
+    (``select.owners``: dense work), where ``jnp.nonzero(mask, size=)``
+    lowers to a scatter-add of one update a mask row (0.9 s over a
+    10M-row mask on a v5e: PERF.md section 6, PR 35).  Parity is preserved —
+    same ascending order, same zero padding when the clamped size exceeds
+    the population count.
     """
     if isinstance(mask, jax.core.Tracer):
-        return jnp.nonzero(mask, size=n_keep)[0]
+        idx, _ = owners(mask, n_keep)
+        return jnp.where(jnp.arange(n_keep) < jnp.sum(mask), idx, 0)
     idx = np.flatnonzero(np.asarray(mask))
     if idx.shape[0] >= n_keep:
         idx = idx[:n_keep]

@@ -24,6 +24,15 @@ total resolved with one scalar sync per the two-phase discipline — is
 shared, and the engines produce bit-identical join indices.  Build-side
 indexes are cached on column-buffer identity (``join_plan.build_index``).
 
+The expansion turns per-probe-row match counts into one output row per
+pair, probe-row-major and in build order within a row.  Which probe row
+owns pair ``j``, and ``j``'s place in that row's run, is the inverse of the
+counts' running sum: ``select.owners``, a block select made of dense passes,
+a fused compare-count over block firsts and one gather of whole rows (no
+binary search over the probe side, no element gathered from it).  It takes
+the pairs in one chunk or several by the pair count alone and syncs
+nothing; ``join.expand.select.<block|chunked>`` counts which.
+
 Join keys: any fixed-width column, or a LIST of key columns (multi-column
 equi-join — tuple equality, a null in ANY key column never matches).
 Multi-column keys are planned by ``join_plan.plan_keys``: dense-eligible
@@ -43,7 +52,7 @@ from ..column import Column, Table
 from ..memory import arena
 from ..memory.budget import PAIR_EXPANSION_BYTES
 from ..utils import metrics, syncs
-from . import join_plan
+from . import join_plan, select
 from .filter import gather, sized_nonzero
 
 JoinKey = Union[Column, Sequence[Column]]
@@ -142,6 +151,7 @@ def _join_indices(lcols: list, rcols: list, how: str):
         # the ephemeral pair-expansion buffer (~10× input on skewed keys)
         # is the HBM-arena pressure point — ROADMAP open item
         metrics.count("join.expand.calls")
+        metrics.count(f"join.expand.select.{select.form(total)}")
         metrics.observe("join.expand.pair_elements", total)
         metrics.observe("join.match_rows",
                         total if matched_rows is None else matched_rows)
@@ -150,31 +160,38 @@ def _join_indices(lcols: list, rcols: list, how: str):
         "join", engine=ix.kind, how=how, probe=join_plan.probe_kind(ix),
         expand_pairs=total,
         match_rows=total if matched_rows is None else matched_rows)
-    # admission-control the ephemeral expansion working set (the int64
-    # lanes + mask below) before XLA materializes it; under pressure this
-    # spills LRU arena residents first (soft: an admitted query completes)
-    with arena.reserve(total * PAIR_EXPANSION_BYTES, tag="join.expand"):
-        starts = jnp.cumsum(out_counts) - out_counts
-        pair_ids = jnp.arange(total, dtype=jnp.int64)
-        # row of each output pair: inverse of starts (searchsorted right)
-        left_idx = jnp.searchsorted(starts.astype(jnp.int64), pair_ids,
-                                    side="right") - 1
-        within = pair_ids - starts.astype(jnp.int64)[left_idx]
-        matched = within < counts[left_idx]
+    with _expansion(ldata.shape[0], total):
+        left_idx, within = select.owners(out_counts, total)
         if nr == 0:
             right_idx = jnp.full(left_idx.shape, -1, dtype=jnp.int64)
-        else:
+        elif how == "left":
+            matched = within < counts[left_idx]
             r_pos = lo[left_idx] + jnp.where(matched, within, 0)
             right_idx = jnp.where(
                 matched, ix.row_ids[jnp.minimum(r_pos, nr - 1)], -1)
+        else:
+            # inner: every pair is a match, so the gather of its row's
+            # count (an element a pair from the probe side) has no reader;
+            # 2% of star_streams4's sql_qps (PERF.md section 6, PR 35)
+            r_pos = lo[left_idx] + within
+            right_idx = ix.row_ids[jnp.minimum(r_pos, nr - 1)]
         return left_idx, right_idx
+
+
+def _expansion(n: int, total: int):
+    """Admission-control the ephemeral working set of expanding ``total``
+    pairs over n probe rows (the int64 lanes and mask of the tail, and what
+    ``select.owners`` holds) before XLA materializes it; under pressure this
+    spills LRU arena residents first (soft: an admitted query completes)."""
+    return arena.reserve(
+        total * PAIR_EXPANSION_BYTES + select.temp_bytes(n, total),
+        tag="join.expand")
 
 
 def _pair_candidates(ix, lo, counts):
     """Aligned (probe_row, build_row) candidate pairs from probe results —
     the shared inner-pair enumeration: unique-build rows come straight off
-    the scatter LUT, everything else runs the arena-admitted searchsorted
-    expansion."""
+    the scatter LUT, everything else runs the arena-admitted expansion."""
     nr = ix.row_ids.shape[0]
     total = syncs.scalar(jnp.sum(counts))         # scalar sync (pair count)
     if nr == 0 or total == 0:
@@ -186,12 +203,10 @@ def _pair_candidates(ix, lo, counts):
         return left_idx, right_idx
     if metrics.recording():
         metrics.count("join.expand.calls")
+        metrics.count(f"join.expand.select.{select.form(total)}")
         metrics.observe("join.expand.pair_elements", total)
-    with arena.reserve(total * PAIR_EXPANSION_BYTES, tag="join.expand"):
-        starts = (jnp.cumsum(counts) - counts).astype(jnp.int64)
-        pair_ids = jnp.arange(total, dtype=jnp.int64)
-        left_idx = jnp.searchsorted(starts, pair_ids, side="right") - 1
-        within = pair_ids - starts[left_idx]
+    with _expansion(counts.shape[0], total):
+        left_idx, within = select.owners(counts, total)
         r_pos = lo[left_idx].astype(jnp.int64) + within
         right_idx = ix.row_ids[jnp.minimum(r_pos, nr - 1)]
         return left_idx, right_idx

@@ -93,3 +93,59 @@ def test_replay_detects_divergence(tables):
     with pytest.raises(Exception):
         with syncs.replay(list(cq.tape[:1])):
             tpcds.QUERIES["q3"](tables)
+
+
+# --- the star cell's two queries, as it serves them (SQL text -> plan) -----
+
+# The tapes a capture records at this data set, written down at the parent
+# of PR 35 (the pair expansion by binary search): the expansion adds no
+# sync, so both tapes keep their length and every scalar.  Position 3 is
+# the pair count the expansion is sized with.
+_STAR_TAPES = {
+    "q3": (18, 396, 17476, 60, 90, 301, 1050, 1, 7, 142, 474, 55, 8, 5, 47,
+           6, 55, 8, 55),
+    "q42": (212, 4, 19955, 649, 30, 661, 690, 1, 17, 1283, 3900, 87, 11, 6,
+            39, 12, 66, 11, 66),
+}
+_STAR_PARAMS = {"q3": {"manufact_id": 436, "moy": 11},
+                "q42": {"manager_id": 1, "moy": 11, "year": 2000}}
+
+
+@pytest.fixture(scope="module")
+def star_tables():
+    from chipbench import datagen
+    files = datagen.tpcds_star_parquet(60_000, 20_000, 12, 2001, 1098,
+                                       order_seed=35)
+    return tpcds.load_tables(files)
+
+
+@pytest.mark.parametrize("cut", ["block", "chunked", "chunked_narrow_rows"])
+@pytest.mark.parametrize("qname", ["q3", "q42"])
+def test_star_queries_keep_their_tapes(star_tables, monkeypatch, qname, cut):
+    """A sorted 18- / 212-key build probed by counting compares, then the
+    pair expansion: however ``ops.select`` cuts it up (one chunk of pairs
+    as in the star cell, chunks of 17, rows of 16 starts in three levels),
+    capture syncs what it synced and the checked replay answers what the
+    eager run answered."""
+    from spark_rapids_jni_tpu import sql as sql_fe
+    from spark_rapids_jni_tpu.models import tpcds_sql as TS
+    from spark_rapids_jni_tpu.ops import select
+    from spark_rapids_jni_tpu.utils import metrics
+    if cut != "block":
+        monkeypatch.setattr(select, "CHUNK_PAIRS", 17)
+    if cut == "chunked_narrow_rows":
+        monkeypatch.setattr(select, "ROW_WORDS", 16)
+    form = cut.split("_")[0]
+    qfn = sql_fe.compile_sql(TS.SQL[qname], TS.TABLE_SCHEMAS,
+                             _STAR_PARAMS[qname])
+    was = metrics.enabled()
+    metrics.set_enabled(True)
+    try:
+        before = metrics.counter_value(f"join.expand.select.{form}")
+        cq = compile_query(qfn, star_tables)
+        assert metrics.counter_value(
+            f"join.expand.select.{form}") == before + 1
+    finally:
+        metrics.set_enabled(was)
+    assert cq.tape == _STAR_TAPES[qname]
+    _tables_equal(cq.run(star_tables), cq.expected)

@@ -363,6 +363,39 @@ def test_compare_probe_fuses_at_q42_size(one_chip, as_tpu):
     assert " while(" not in text and " gather(" not in text
 
 
+@pytest.mark.parametrize("total,form", [(106_597, "block"),
+                                        (10_000_000, "chunked")])
+def test_pair_expansion_is_a_block_select_at_q42_size(one_chip, as_tpu,
+                                                      total, form):
+    # q42's expansion at the star cell's size, 106597 pairs over the
+    # 10M-row fact: dense passes, a fused compare-count and gathers of
+    # whole rows - no loop over search levels, no element gathered from a
+    # 10M-row table, temporaries inside what the join site reserves.  An FK
+    # join's 10M pairs go by in chunks: one loop over chunks, the same
+    # gathers, temporaries that do not grow with the rows gathered.
+    import time
+    from spark_rapids_jni_tpu.ops import select
+    n = 10_000_000
+    assert select.form(total) == form
+    # (a fresh function: a trace is cached on the function, backend and all)
+    expand = jax.jit(lambda counts: select._owners_block.__wrapped__(
+        counts, total, select.ROW_WORDS, select.COMPARE_TOP,
+        select.CHUNK_PAIRS))
+    t0 = time.perf_counter()
+    c = _compile(one_chip, expand, _s((n,), jnp.int32))
+    assert time.perf_counter() - t0 < 60
+    assert c.memory_analysis().temp_size_in_bytes \
+        <= select.temp_bytes(n, total)
+    text = c.as_text()
+    assert text.count(" while(") == (form == "chunked")
+    pairs = min(total, select.CHUNK_PAIRS)
+    gathers = re.findall(r"= (\S+) gather\(.*slice_sizes=\{([\d,]+)\}", text)
+    assert gathers and all(
+        shape.startswith(f"s32[{pairs},{select.ROW_WORDS}]")
+        and sizes == f"1,{select.ROW_WORDS}" for shape, sizes in gathers), \
+        gathers
+
+
 # --- four chips: the shuffle step as ONE program across the 2x2 mesh ------------
 
 def test_mesh_shuffle_program_four_chips(topo):
