@@ -211,7 +211,13 @@ def phase_c_abi(n_rows: int = TRANSCODE_ROWS, seed: int = 7) -> dict:
     srjt_from_rows_device -> host buffers, compared with the C++ host
     engine's bytes and the input.  The ``_device`` symbols return null on
     ANY failure (the JVM caller then takes the host engine); here null is
-    an error, and bridge.py has logged the Python exception."""
+    an error, and bridge.py has logged the Python exception.
+
+    A smoke at 12 columns, one thread.  The path's guard at a public shape
+    is the benchmark's cell ``fixed155_cabi_t4`` (PR 36): four task threads,
+    the reference's 155-column table, every caller's last round trip
+    compared byte for byte (``python3 -m chipbench --workload
+    fixed155_cabi_t4``; on the CPU ``chipbench/tests/test_cabi_cell.py``)."""
     from spark_rapids_jni_tpu import native
     lib = native.load()
     check(lib is not None, f"libsrjt.so unavailable: {native.build_error}")

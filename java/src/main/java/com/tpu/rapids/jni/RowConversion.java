@@ -53,7 +53,13 @@ public final class RowConversion {
     }
   }
 
-  /** Columnar table -> JCUDF row batches. */
+  /**
+   * Columnar table -> JCUDF row batches.  Served by the device engine
+   * ({@code srjt_to_rows_device}, every batch of the table); the host C++
+   * engine answers only where the device path returns null (no embedded
+   * runtime, {@code SRJT_DEVICE=0}, or a failed call, which
+   * {@code bridge.null.to} counts and the bridge logs).
+   */
   public static RowBatches convertToRows(HostTable table) {
     return new RowBatches(convertToRows(table.getNativeHandle()));
   }
@@ -61,6 +67,15 @@ public final class RowConversion {
   /**
    * One JCUDF row batch -> columnar table.  {@code typeIds}/{@code scales}
    * mirror the reference's schema marshalling (RowConversion.java:110-120).
+   *
+   * <p>Which engine serves which batch: the device engine
+   * ({@code srjt_from_rows_device}) decodes batch 0 only, the reference's
+   * one-batch contract; for {@code batch > 0} the JNI wrapper goes straight
+   * to the host C++ engine ({@code srjt_from_rows}), and no counter of the
+   * bridge sees that call: the wrapper cannot reach the Python metrics
+   * store ({@code bridge.null.from} counts only device calls that failed).
+   * A caller that wants every batch of a table past 2 GB on the device
+   * imports each batch as a handle of its own ({@link #importRows}).
    */
   public static HostTable convertFromRows(RowBatches rows, int batch,
       int[] typeIds, int[] scales) {

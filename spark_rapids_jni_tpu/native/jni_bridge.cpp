@@ -215,7 +215,11 @@ JNIEXPORT jlong JNICALL Java_com_tpu_rapids_jni_RowConversion_convertFromRows(
   ENV(GetIntArrayRegion, type_ids, 0, n, types.data());
   if (scales) ENV(GetIntArrayRegion, scales, 0, n, scl.data());
   void* t = nullptr;
-  if (batch == 0) {   // device engine decodes batch 0 (one-batch contract)
+  // The device engine decodes batch 0 (one-batch contract).  Any other
+  // batch goes to the host engine below without the bridge seeing the call,
+  // so no bridge.* counter ticks for it (RowConversion.java says so to the
+  // caller); a device call that failed ticks bridge.null.from and is logged.
+  if (batch == 0) {
     t = srjt_from_rows_device(reinterpret_cast<void*>(rows_handle),
                               types.data(), scales ? scl.data() : nullptr, n);
   }

@@ -179,6 +179,42 @@ def test_fixed_from_rows_program(one_chip, shape):
     assert c.memory_analysis().temp_size_in_bytes < 3 << 30
 
 
+# argument + output + temporary bytes of the two programs a C-ABI call
+# runs at fixed155_cabi_t4's shape (compile rehearsal, PR 36).  Nothing
+# stays on the chip between calls, so this is all a call holds there; four
+# task threads that launch the same direction at the same instant hold four
+# of them, 14.37 GB for ``from``: what PERF.md §7 says of a gate.
+CABI_PROGRAM_BYTES = {"to": 3_524_081_664, "from": 3_593_147_904}
+
+
+@pytest.mark.parametrize("direction", list(CABI_PROGRAM_BYTES))
+def test_cabi_cell_programs_as_the_bridge_launches_them(one_chip, direction):
+    cfg, schema = _cell_config("cabi_fixed155_1m")
+    layout, n = compute_row_layout(schema), cfg["rows"]
+    if direction == "to":
+        # the bridge uploads payloads in their storage types and validity as
+        # bools, for the nullable columns only
+        has_valid = tuple(i % cfg["null_every"] == 0
+                          for i in range(cfg["columns"]))
+        datas = tuple(_s((n,), dt.storage) for dt in schema)
+        valids = tuple(_s((n,), jnp.bool_) for hv in has_valid if hv)
+        c = _compile(one_chip, convert._to_rows_fixed_full, datas, valids,
+                     statics=(layout, has_valid, 0, n))
+    else:
+        # ... and the batch as uint32 words: the resident cells' program,
+        # no bytes -> words pass (2.05 GB of temporaries of its own)
+        assert cfg["batch_bytes"] == n * layout.fixed_row_size
+        words = _s((cfg["batch_bytes"] // 4,), jnp.uint32)
+        c = _compile(one_chip, convert._from_rows_fixed_full, words,
+                     statics=(layout,))
+    ma = c.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert abs(need - CABI_PROGRAM_BYTES[direction]) < 0.05 * need
+    # two calls in flight always fit; four at the same instant may not
+    assert 2 * need < 14.4e9
+
+
 def _batches_cell():
     """chipbench's fixed212_roundtrip: the 212-column table at 2<<20 rows,
     in the two batches the reference's rule cuts it into."""
