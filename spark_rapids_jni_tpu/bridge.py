@@ -20,11 +20,21 @@ upload), ``bridge.h2d`` (the upload and its wait), the engine's own spans,
 caller's handle outlives the call, so fixed-width buffers are read in place
 and every upload is waited for before anything else runs: no transfer reads
 the handle's memory after the call has returned.
+
+Ownership of what ``to`` returns: the rows handle ADOPTS the arrays the
+download landed in (``srjt_rows_adopt*``), no copy.  They stay in
+``_held`` under a token passed as the release context; the library calls
+``_release`` exactly once a batch, on whichever thread frees the handle
+(ctypes, or the JVM's ``RowConversion.freeRows``), and only then is the
+entry dropped.  A rejected adopt never calls it: the arrays stay ours, and
+the entry goes at once.  ``bridge.adopted`` − ``bridge.released`` is the
+count of batches live handles hold.
 """
 
 from __future__ import annotations
 
 import ctypes as C
+import itertools
 import logging
 
 import numpy as np
@@ -36,6 +46,30 @@ from .rowconv.convert import RowBatch
 from .utils import metrics
 
 _log = logging.getLogger(__name__)
+
+# token -> (data, offsets) of every batch a live rows handle has adopted
+_held: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+_tokens = itertools.count(1)
+
+
+def _make_release():
+    from . import native
+    held, count = _held, metrics.count
+
+    def release(ctx):
+        # the interpreter lock is ctypes' to take; the names are this
+        # closure's, so it also runs while the modules are torn down at
+        # exit (a caller's ``__del__`` freeing its handles)
+        held.pop(ctx, None)
+        count("bridge.released")
+    fn = native.RELEASE_FN(release)
+    # the library holds the bare function pointer for as long as any handle
+    # lives, which may be past this module's teardown: never freed
+    C.pythonapi.Py_IncRef(C.py_object(fn))
+    return fn
+
+
+_release = _make_release()
 
 
 def _load() -> C.CDLL:
@@ -156,33 +190,54 @@ def _to_rows(lib, table_handle: int) -> int:
     host = _download([leaf for b in batches for leaf in (b.data, b.offsets)])
     shape = dict(rows=table.num_rows, cols=len(table.columns),
                  batches=len(batches))
-    del table, batches               # the device's copies go before the host's are made
+    del table, batches               # the device's copies go before the handle is made
     out = None
     try:
         with metrics.span("bridge.marshal_out") as sp:
-            copied = 0
+            adopted = copied = 0
             for data, offs in zip(host[0::2], host[1::2]):
                 data = data.view(np.uint8)
-                offs = np.ascontiguousarray(offs, dtype=np.int32)
-                nrows = offs.shape[0] - 1
-                args = (data.ctypes.data_as(C.c_void_p), data.size,
-                        offs.ctypes.data_as(C.c_void_p), nrows)
-                if out is None:
-                    out = lib.srjt_rows_import(*args)
-                    if not out:
-                        return 0
-                elif not lib.srjt_rows_import_append(out, *args):
-                    return 0
-                copied += data.nbytes + offs.nbytes
+                as_i32 = np.ascontiguousarray(offs, dtype=np.int32)
+                if as_i32 is not offs:
+                    copied += as_i32.nbytes
+                got = _adopt(lib, out, data, as_i32)
+                if not got:
+                    return 0                 # a partial set is freed below
+                out = got
+                adopted += data.nbytes + as_i32.nbytes
             if sp is not None:
-                sp.annotate(bytes=copied, copied_bytes=copied)
+                sp.annotate(bytes=adopted, copied_bytes=copied)
         metrics.count("bridge.host_copied_bytes", copied)
+        metrics.count("bridge.adopted_bytes", adopted)
         metrics.annotate(**shape)
         result, out = int(out or 0), None    # ownership passes to caller
         return result
     finally:
         if out is not None:
-            lib.srjt_rows_free(out)          # don't leak a partial import
+            lib.srjt_rows_free(out)          # don't leak a partial batch set
+
+
+def _adopt(lib, out, data: np.ndarray, offs: np.ndarray) -> int:
+    """``data`` and ``offs`` as one more batch of ``out`` (a new handle when
+    ``out`` is None), taken over without a copy: the handle, or 0 where the
+    library rejected them, and they stay ours."""
+    token = next(_tokens)
+    _held[token] = (data, offs)
+    args = (data.ctypes.data_as(C.c_void_p), data.size,
+            offs.ctypes.data_as(C.c_void_p), offs.shape[0] - 1, _release,
+            token)
+    got = 0
+    try:
+        if out is None:
+            got = lib.srjt_rows_adopt(*args) or 0
+        elif lib.srjt_rows_adopt_append(out, *args):
+            got = out
+    finally:
+        if not got:
+            _held.pop(token, None)
+    if got:
+        metrics.count("bridge.adopted")
+    return got
 
 
 def _from_rows(lib, rows_handle: int, type_ids_ptr: int, scales_ptr: int,

@@ -25,6 +25,10 @@ build_error: Optional[str] = None   # why the last make failed, if it did
 
 _c = ctypes
 
+# ``void (*release)(void* ctx)`` of srjt_rows_adopt*: a ctypes callback of
+# this type takes the interpreter lock itself, on whichever thread calls it
+RELEASE_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p)
+
 
 def _build() -> bool:
     """``make`` the library, one builder at a time: test workers and serving
@@ -113,6 +117,11 @@ def _bind(lib: ctypes.CDLL) -> None:
     _sig(lib, "srjt_debug_set_max_batch_bytes", None, [i64])
     _sig(lib, "srjt_rows_import", vp, [vp, i64, vp, i64])
     _sig(lib, "srjt_rows_import_append", i32, [vp, vp, i64, vp, i64])
+    # adopt: the handle takes the buffers over and hands them back through
+    # ``release(ctx)`` once, when it is freed (RELEASE_FN: the callback type)
+    _sig(lib, "srjt_rows_adopt", vp, [vp, i64, vp, i64, RELEASE_FN, vp])
+    _sig(lib, "srjt_rows_adopt_append", i32,
+         [vp, vp, i64, vp, i64, RELEASE_FN, vp])
     _sig(lib, "srjt_rows_free", None, [vp])
     _sig(lib, "srjt_rows_num_batches", i32, [vp])
     _sig(lib, "srjt_rows_batch_rows", i64, [vp, i32])

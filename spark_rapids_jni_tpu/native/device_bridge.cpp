@@ -7,8 +7,8 @@
 // CPython runtime (a PySpark executor, a JVM that initialized one, or the
 // test harness), libsrjt forwards a host table handle to
 // spark_rapids_jni_tpu.bridge, which reads the table through this same
-// library's C accessors, runs the JAX/TPU engine, and imports the packed
-// JCUDF bytes back through srjt_rows_import — so bytes entering the JNI
+// library's C accessors, runs the JAX/TPU engine, and hands the packed
+// JCUDF bytes back through srjt_rows_adopt — so bytes entering the JNI
 // surface are transcoded by the device engine, with the host C++ engine as
 // the fallback tier.
 //

@@ -4,7 +4,7 @@ JNI drives its device engine directly, RowConversionJni.cpp:24-45).
 
 The pytest process hosts CPython, so ``srjt_device_available()`` is true
 and ``srjt_to_rows_device`` round-trips through
-``spark_rapids_jni_tpu.bridge`` → JAX engine → ``srjt_rows_import``.  The
+``spark_rapids_jni_tpu.bridge`` → JAX engine → ``srjt_rows_adopt``.  The
 host C++ engine output is the byte-exact oracle.
 """
 
@@ -217,7 +217,8 @@ def test_bridge_spans_carry_their_attrs_under_the_callers_root():
         before = {k: metrics.counter_value(k) for k in (
             "bridge.calls.to", "bridge.calls.from", "bridge.bytes.h2d",
             "bridge.bytes.d2h", "bridge.host_copied_bytes",
-            "bridge.null.to", "bridge.null.from")}
+            "bridge.null.to", "bridge.null.from", "bridge.adopted_bytes",
+            "bridge.adopted", "bridge.released")}
         with metrics.span("task", rid="task-7") as root:
             _roundtrip(t, tids)
         tree = root.as_dict()
@@ -249,6 +250,14 @@ def test_bridge_spans_carry_their_attrs_under_the_callers_root():
         assert moved["bridge.host_copied_bytes"] == sum(
             s["attrs"]["copied_bytes"] for c in calls for s in c["children"]
             if "copied_bytes" in s.get("attrs", {}))
+        # the batch that came down is the rows handle's, not a copy of it;
+        # ``_roundtrip`` freed the handle, so the buffer is given back
+        to_out = [s["attrs"] for s in calls[0]["children"]
+                  if s["name"] == "bridge.marshal_out"][0]
+        assert to_out["copied_bytes"] == 0
+        assert moved["bridge.adopted_bytes"] == to_out["bytes"] == (
+            calls[0]["attrs"]["bytes_out"])
+        assert moved["bridge.adopted"] == moved["bridge.released"] == 1
         lib.srjt_table_free(t)
     finally:
         metrics.set_enabled(was)

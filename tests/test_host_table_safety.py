@@ -69,20 +69,34 @@ def _import(data: np.ndarray, offsets: np.ndarray, n: int):
     return lib.srjt_rows_import(_np_ptr(data), len(data), _np_ptr(offsets), n)
 
 
-def test_import_rejects_bad_offsets():
+@pytest.mark.parametrize("how", ["import", "adopt"])
+def test_import_rejects_bad_offsets(how):
+    # srjt_rows_adopt (the device bridge's hand-off) checks as import does;
+    # a rejected adopt never calls its release, an accepted one once
+    released = []
+    release = _native.RELEASE_FN(released.append)
+
+    def make(data, offsets, n):
+        if how == "import":
+            return _import(data, offsets, n)
+        return lib.srjt_rows_adopt(_np_ptr(data), len(data),
+                                   _np_ptr(offsets), n, release, 5)
     data = np.zeros(64, dtype=np.uint8)
     # non-monotonic
-    assert not _import(data, np.array([0, 40, 20, 64], dtype=np.int32), 3)
+    assert not make(data, np.array([0, 40, 20, 64], dtype=np.int32), 3)
     # does not start at zero
-    assert not _import(data, np.array([8, 32, 64], dtype=np.int32), 2)
+    assert not make(data, np.array([8, 32, 64], dtype=np.int32), 2)
     # does not end at data_size
-    assert not _import(data, np.array([0, 32, 48], dtype=np.int32), 2)
+    assert not make(data, np.array([0, 32, 48], dtype=np.int32), 2)
     # negative
-    assert not _import(data, np.array([0, -4, 64], dtype=np.int32), 2)
+    assert not make(data, np.array([0, -4, 64], dtype=np.int32), 2)
+    assert released == []
     # well-formed accepted
-    h = _import(data, np.array([0, 32, 64], dtype=np.int32), 2)
+    offsets = np.array([0, 32, 64], dtype=np.int32)
+    h = make(data, offsets, 2)
     assert h
     lib.srjt_rows_free(h)
+    assert released == ([] if how == "import" else [5])
 
 
 def _from_rows(rows_handle, type_ids):
