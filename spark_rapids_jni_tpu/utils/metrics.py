@@ -28,9 +28,10 @@ Discipline
   Sites that re-trace under ``jax.jit`` replay (``utils.syncs`` replay
   mode) are skipped automatically — a replay trace would otherwise
   double-count the capture run's events and measure trace time instead of
-  run time.  The one deliberate exception is
-  ``count(..., in_trace=True)`` (e.g. ``compiled.recompile``), which
-  records trace-time occurrences on purpose.
+  run time.  The deliberate exceptions are
+  ``count(..., in_trace=True)`` (e.g. ``compiled.recompile``) and the
+  compile listeners (``jax.*`` spans, ``xla:`` ledger entries), which
+  record trace-time occurrences on purpose.
 * No device syncs: values passed in must already be host ints/floats
   (the op library's sizes all flow through ``syncs.scalar`` anyway).
 """
@@ -80,7 +81,9 @@ _rids = itertools.count(1)          # request ids of roots not given one
 # compile-cost ledger: plan fingerprint → summed cost fields (capture_ms,
 # trace_ms, traces, first_dispatch_ms, runs, cache_hits, ...) — the
 # per-plan attribution of where compilation wall time went
-# (``models/compiled.py`` and ``exec/plan_cache.py`` feed it)
+# (``models/compiled.py`` and ``exec/plan_cache.py`` feed it); beside the
+# plans, ``xla:<fun>`` → traces, trace_ms, lowers, lower_ms, compiles,
+# compile_ms, cache_hits for every program JAX compiles (the listeners below)
 _ledger: dict[str, dict[str, float]] = {}
 
 # installed by ``plan/profile.py`` when that module loads; ops-layer
@@ -334,6 +337,9 @@ class Span:
         self._note.__exit__(*exc)
         self._note = None
         _tls.stack.pop()
+        self._attach()
+
+    def _attach(self) -> None:
         if self.parent is None:
             with _lock:
                 _roots.append(self)
@@ -394,6 +400,151 @@ def query_span(name: str, **attrs):
     post = sample_hbm("post")
     if pre is not None and post is not None:
         sp.annotate(hbm_pre_bytes=pre, hbm_post_bytes=post)
+
+
+# --- the compile path: JAX's own compile events ------------------------------
+#
+# JAX reports each of three phases twice, with the program's ``fun_name``: a
+# start event (``record_scalar``, value = wall-clock start) and a time-span
+# event (``record_event_time_span``) at its end.
+#   * ``jaxpr_trace_duration``: Python tracing of a function to a jaxpr;
+#   * ``jaxpr_to_mlir_module_duration``: lowering the jaxpr to MLIR;
+#   * ``backend_compile_duration``: ``compile_or_get_cached``, the XLA
+#     compile or the persistent cache's fetch; a fetch fires
+#     ``/jax/compilation_cache/cache_hits`` inside it, on the same thread.
+# Each outermost phase becomes a span (``jax.trace`` / ``jax.lower`` /
+# ``jax.compile``) under the thread's open span, and adds to the ledger entry
+# ``xla:<fun>``.  Phases nest (the trace of ``f`` holds the traces of the jnp
+# functions it calls): a phase inside one of its own kind on the same thread
+# is neither a span nor a count.  Recorded while metrics are on, replay
+# included, since a plan compiles while ``syncs.replay`` traces it.
+#
+# Memory: span attrs and ledger fields hold only str/bool/int/float, never a
+# jaxpr, module, executable or array.  ``chipbench`` keeps metrics on in every
+# run and the span store keeps 4096 root trees for the life of the process,
+# so whatever these records referenced would live through every window; the
+# ledger is one entry a program name, bounded by the programs, not the calls.
+
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("jax.trace", "traces", "trace_ms"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("jax.lower", "lowers", "lower_ms"),
+    "/jax/core/compile/backend_compile_duration":
+        ("jax.compile", "compiles", "compile_ms"),
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_FAULTS = "metrics.compile_listener.faults"   # a listener that failed
+_JIT_WRAP = re.compile(r"^(?:jit|pjit|pmap|xla_pmap)\((.*)\)$")
+_listening = False
+
+
+def _program_name(fun_name) -> str:
+    """``jit(f)`` (how the lowering and compile name it) → ``f`` (how the
+    trace names it)."""
+    name = str(fun_name)
+    m = _JIT_WRAP.match(name)
+    while m is not None:
+        name = m.group(1)
+        m = _JIT_WRAP.match(name)
+    return name
+
+
+def _phases_open() -> dict:
+    """This thread's open phases: event → [depth, span or None]."""
+    open_ = getattr(_tls, "phases", None)
+    if open_ is None:
+        open_ = _tls.phases = {}
+    return open_
+
+
+def _on_phase_start(event: str, value, **kw) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    try:
+        open_ = _phases_open()
+        frame = open_.get(event)
+        if frame is not None:
+            frame[0] += 1
+            return
+        sp = None
+        if _enabled:
+            attrs = {"fun": _program_name(kw.get("fun_name", ""))}
+            if phase[0] == "jax.compile":
+                attrs["cache_hit"] = False
+            sp = Span(phase[0], attrs)
+            sp.__enter__()
+        open_[event] = [1, sp]
+    except Exception:       # noqa: BLE001 — never raise into JAX
+        count(_FAULTS, in_trace=True)
+
+
+def _on_phase_end(event: str, start: float, end: float, **kw) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    try:
+        open_ = _phases_open()
+        frame = open_.get(event)
+        if frame is not None:
+            frame[0] -= 1
+            if frame[0] > 0:
+                return
+            del open_[event]
+            sp = frame[1]
+            if sp is None:
+                return
+            sp.__exit__(None, None, None)
+        else:
+            # no start event seen: the whole span, moved onto our clock
+            if not _enabled:
+                return
+            attrs = {"fun": _program_name(kw.get("fun_name", ""))}
+            if phase[0] == "jax.compile":
+                attrs["cache_hit"] = bool(getattr(_tls, "cache_hit", False))
+            parent = current_span()
+            sp = Span(phase[0], attrs, parent,
+                      parent.rid if parent is not None else next(_rids))
+            sp.tid = threading.get_ident()
+            sp.dur = float(end) - float(start)
+            sp.t0 = (time.perf_counter() - _EPOCH) - (time.time()
+                                                      - float(start))
+            sp._attach()
+        _tls.cache_hit = False
+        fields = {phase[1]: 1, phase[2]: (float(end) - float(start)) * 1e3}
+        if phase[0] == "jax.compile":
+            fields["cache_hits"] = int(sp.attrs["cache_hit"])
+        ledger_add("xla:" + sp.attrs["fun"], in_trace=True, **fields)
+    except Exception:       # noqa: BLE001 — never raise into JAX
+        count(_FAULTS, in_trace=True)
+
+
+def _on_event(event: str, **kw) -> None:
+    if event != _CACHE_HIT_EVENT:
+        return
+    try:
+        frame = _phases_open().get(
+            "/jax/core/compile/backend_compile_duration")
+        if frame is None:
+            _tls.cache_hit = True       # for a build with no start event
+        elif frame[1] is not None:
+            frame[1].attrs["cache_hit"] = True
+    except Exception:       # noqa: BLE001 — never raise into JAX
+        count(_FAULTS, in_trace=True)
+
+
+def install_compile_listeners() -> None:
+    """Register the compile listeners with ``jax.monitoring``; once a
+    process, however often called."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax.monitoring
+    jax.monitoring.register_scalar_listener(_on_phase_start)
+    jax.monitoring.register_event_time_span_listener(_on_phase_end)
+    jax.monitoring.register_event_listener(_on_event)
 
 
 # --- HBM accounting ---------------------------------------------------------
@@ -692,3 +843,6 @@ def stop_http_server() -> None:
             _http_server.shutdown()
             _http_server.server_close()
             _http_server = None
+
+
+install_compile_listeners()

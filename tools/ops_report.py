@@ -105,7 +105,23 @@ def _ledger_lines(ledger: dict) -> list[str]:
     if not ledger:
         return ["  (no compiled plans this process)"]
     out = []
-    for plan in sorted(ledger):
+    # the programs JAX compiled: one line of sums, then the five costliest
+    xla = {k: e for k, e in ledger.items() if k.startswith("xla:")}
+    if xla:
+        def tot(field):
+            return sum(e.get(field, 0) for e in xla.values())
+        out.append(
+            f"  xla: {len(xla)} programs  compiles {tot('compiles'):.0f} "
+            f"({tot('compile_ms'):.1f} ms, {tot('cache_hits'):.0f} fetched "
+            f"from the persistent cache)  trace+lower "
+            f"{tot('trace_ms') + tot('lower_ms'):.1f} ms")
+        for plan in sorted(xla, key=lambda k: -xla[k].get("compile_ms", 0))[:5]:
+            e = xla[plan]
+            out.append(
+                f"    {plan}  compiles {e.get('compiles', 0):.0f} "
+                f"({e.get('compile_ms', 0):.1f} ms)  cache hits "
+                f"{e.get('cache_hits', 0):.0f}")
+    for plan in sorted(k for k in ledger if k not in xla):
         e = ledger[plan]
         traces = e.get("traces", 0)
         cold_ms = (e.get("capture_ms", 0) + e.get("trace_ms", 0)
